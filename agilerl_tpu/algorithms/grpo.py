@@ -455,14 +455,21 @@ class GRPO(EvolvableAlgorithm):
         # no-grad passes use the fused Pallas lm-head kernel on TPU
         use_pallas = pallas_enabled()
 
+        # base rides as an ARGUMENT, like make_update_fn's: a jit that closes
+        # over the weights bakes them into the program as literals (a second
+        # copy of the model in HBM, and a lowering that never ends at 7B
+        # widths)
         @jax.jit
-        def logprobs(lora, tokens, mask):
+        def logprobs(base, lora, tokens, mask):
             return M.token_logprobs(
                 config, base, tokens, attention_mask=mask, lora=lora,
                 lora_scale=scale, use_pallas=use_pallas, flash=use_pallas,
             )
 
-        return logprobs
+        def bound(lora, tokens, mask):
+            return logprobs(base, lora, tokens, mask)
+
+        return bound
 
     def _update_fn(self):
         base = self.base_params

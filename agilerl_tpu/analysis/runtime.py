@@ -19,7 +19,7 @@ import jax
 from agilerl_tpu.llm.serving import measured_cache_size
 
 #: monitoring event jax records once per backend (XLA) compilation — present
-#: on this image's jax 0.4.37 and current jax; verified by the runtime tests
+#: on the installed jax 0.9.0; verified by the runtime tests
 _COMPILE_EVENT_SUBSTR = "backend_compile"
 
 

@@ -19,9 +19,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from agilerl_tpu.compat import shard_map, axis_size
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from agilerl_tpu.llm.model import (
@@ -126,7 +124,7 @@ def make_sp_logprob_fn(config: GPTConfig, mesh: Mesh, axis_name: str = "sp",
         logp = jax.nn.log_softmax(logits, axis=-1)
         # target for local position t is tokens[t+1]; the last local target
         # lives on the next shard — fetch its first token via ppermute
-        p_size = axis_size(axis_name)
+        p_size = lax.axis_size(axis_name)
         first_next = lax.ppermute(
             tokens[:, :1], axis_name,
             [(j, (j - 1) % p_size) for j in range(p_size)],

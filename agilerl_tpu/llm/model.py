@@ -446,7 +446,7 @@ def forward(
 
                 smesh = _flash_mesh(config)
                 if smesh is not None:
-                    from agilerl_tpu.compat import shard_map
+                    from jax import shard_map
                     from jax.sharding import PartitionSpec as P
 
                     bax, hax = config.flash_shard_axes
@@ -686,9 +686,9 @@ def paged_gather(pool_k: jax.Array, pool_v: jax.Array, block_tables: jax.Array):
 
     pool_*: [nb, bs, KV, hd] (ONE layer — called inside the layer scan so the
     temp is per-layer, not [L, ...]); block_tables: [B, max_blocks] ->
-    ([B, S, KV, hd], ...) with S = max_blocks * bs. This is the gather the
-    on-chip profile target from NOTES_ROUND4/5 meters: a [B, S] temp per
-    layer per step, while the RESIDENT allocation stays the shared pool."""
+    ([B, S, KV, hd], ...) with S = max_blocks * bs. This is the gather
+    ROADMAP S3 wants traced on the chip: a [B, S] temp per layer per step,
+    while the RESIDENT allocation stays the shared pool."""
     bs = pool_k.shape[1]
     B, mb = block_tables.shape
 
@@ -939,7 +939,7 @@ def token_logprobs(
         if bspec is not None:
             # rows shard over the batch axes; the replicated head's dW
             # cotangent is psummed by shard_map's transpose rule
-            from agilerl_tpu.compat import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             lp = shard_map(

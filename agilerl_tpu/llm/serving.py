@@ -122,11 +122,10 @@ def measured_cache_size(*jitted) -> int:
     """Total LIVE compiled-program count across jitted callables, read from
     the jit caches themselves (VERDICT r4 #4: a self-inserted signature set
     asserts a proxy; the measured cache size cannot lie). ``_cache_size`` is
-    private jax API — VERIFIED present and correct on this image's jax
-    0.4.37 (the old comment pinned 0.9.0; compat.py documents the installed
-    version) and on current jax; the getattr guard degrades a future rename
-    into the -1 sentinel instead of crashing generate() (the missing-API
-    path is pinned in tests/test_llm/test_continuous_batching.py).
+    private jax API, present and correct on the installed jax 0.9.0; the
+    getattr guard degrades a future rename into the -1 sentinel instead of
+    crashing generate() (both paths are pinned in
+    tests/test_llm/test_continuous_batching.py).
     Notes: ``jax.clear_caches()`` restarts the count, a change of input
     sharding/dtype is honestly a new program, and an early-exit batch that
     never reached decode counts only its prefill."""

@@ -7,8 +7,8 @@ rides :class:`agilerl_tpu.utils.log_utils.CombineLogs` — host-side weighted
 means reduced over ``process_allgather``, no new collective machinery.
 
 MFU caveats (see docs/observability.md): emitted only when the backend has a
-defined bf16 peak (TPU); an unknown TPU generation falls back to the v5 peak
-and every MFU reading is then tagged ``estimated=true``.
+defined bf16 peak (TPU); a TPU generation missing from ``PEAK_BF16_FLOPS`` is
+an error at construction, never an assumed peak.
 """
 
 from __future__ import annotations
@@ -19,7 +19,7 @@ from typing import Any, Dict, Optional
 from agilerl_tpu.utils.log_utils import CombineLogs
 from agilerl_tpu.utils.profiling import (
     StepTimer,
-    peak_flops_info,
+    peak_flops_per_device,
     transformer_flops_per_token,
 )
 
@@ -76,11 +76,7 @@ class StepTimeline:
         # host memory; aggregate() feeds these into CombineLogs for the
         # cross-host reduce
         self._acc: Dict[str, Any] = {}
-        # pass our registry so an unknown-chip fallback warning lands in THIS
-        # run's event stream, not just the process-default registry
-        peak, estimated = peak_flops_info(registry=registry)
-        self._peak_flops = peak
-        self._peak_estimated = estimated
+        self._peak_flops = peak_flops_per_device()
         self._flops_per_token = (
             transformer_flops_per_token(model_config)
             if model_config is not None else None
@@ -174,7 +170,6 @@ class StepTimeline:
                 event["tokens_per_sec"] = round(tokens / dt, 2)
                 if mfu is not None:
                     event["mfu"] = mfu
-                    event["estimated"] = bool(self._peak_estimated)
             if metrics:
                 event.update({k: float(v) for k, v in metrics.items()})
             if (self.memory_stats_every

@@ -7,9 +7,10 @@ import jax
 
 def pallas_enabled() -> bool:
     """True when the hot paths should route through the Pallas kernels:
-    on the TPU backend, unless AGILERL_TPU_DISABLE_PALLAS is set (safety
-    valve: some remote-compile services cannot build Mosaic kernels — the
-    XLA fallback paths are numerically identical, just less fused)."""
+    on the TPU backend, unless AGILERL_TPU_DISABLE_PALLAS is set (the XLA
+    paths compute the same thing, less fused). The ONE gate: a run that
+    lands on another backend takes the XLA paths, so a result that must
+    come from the kernels has to check the device it ran on."""
     if os.environ.get("AGILERL_TPU_DISABLE_PALLAS"):
         return False
     return jax.default_backend() == "tpu"

@@ -315,25 +315,6 @@ def _keep_dims(mesh, info, keep):
     return NamedSharding(mesh, P(*parts))
 
 
-def _infer_from_q(mesh, arg_infos, result_infos):
-    """Pre-Shardy (``infer_sharding_from_operands``) result inference: every
-    result keeps q's (batch, heads) sharding — the same contract the Shardy
-    rule declares, spelled for the legacy GSPMD pipeline."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    q = arg_infos[0]
-    qspec = list(getattr(q.sharding, "spec", None) or [])
-    qspec = qspec + [None] * (4 - len(qspec))
-    results = (result_infos if isinstance(result_infos, (tuple, list))
-               else (result_infos,))
-    out = tuple(
-        NamedSharding(mesh, P(qspec[0], qspec[1],
-                              *([None] * (len(r.shape) - 2))))
-        for r in results
-    )
-    return out if isinstance(result_infos, (tuple, list)) else out[0]
-
-
 @functools.lru_cache(maxsize=None)
 def _partitioned_fwd(causal, block_q, block_k, interpret, with_mask):
     from jax.experimental.custom_partitioning import custom_partitioning
@@ -356,11 +337,8 @@ def _partitioned_fwd(causal, block_q, block_k, interpret, with_mask):
 
     rule = ("b h t d, b h t d, b h t d" + (", b t" if with_mask else "")
             + " -> b h t d, b h p u")
-    from agilerl_tpu.compat import def_partition
-
-    def_partition(fn, partition=partition, sharding_rule=rule,
-                  need_replication_factors=("t", "d", "p", "u"),
-                  infer_sharding_from_operands=_infer_from_q)
+    fn.def_partition(partition=partition, sharding_rule=rule,
+                     need_replication_factors=("t", "d", "p", "u"))
     return fn
 
 
@@ -388,11 +366,8 @@ def _partitioned_bwd(causal, block_q, block_k, interpret, with_mask):
     rule = ("b h t d, b h t d, b h t d, b h t d, b h t d, b h p u"
             + (", b t" if with_mask else "")
             + " -> b h t d, b h t d, b h t d")
-    from agilerl_tpu.compat import def_partition
-
-    def_partition(fn, partition=partition, sharding_rule=rule,
-                  need_replication_factors=("t", "d", "p", "u"),
-                  infer_sharding_from_operands=_infer_from_q)
+    fn.def_partition(partition=partition, sharding_rule=rule,
+                     need_replication_factors=("t", "d", "p", "u"))
     return fn
 
 

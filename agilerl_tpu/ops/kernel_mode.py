@@ -44,10 +44,9 @@ def resolve_interpret(explicit: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu"
 
 
-# Compile-path kill switches honoured across the framework. ONE list so the
-# bisection probes (benchmarking/grpo_safe_env.py) and every capture labeler
-# (bench.py grpo mode, benchmarking/grpo_mfu_sweep.py) stay in lockstep — a
-# switch added here is automatically reported by all of them.
+# Compile-path kill switches honoured across the framework. ONE list so
+# everything that labels a run (bench.py grpo mode, chip_smoke.py) reports the
+# same set — a switch added here is reported by all of them.
 KILL_SWITCH_ENV_VARS = (
     "AGILERL_TPU_DISABLE_PALLAS",
     "AGILERL_TPU_DISABLE_SCAN_LAYERS",

@@ -15,9 +15,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from agilerl_tpu.compat import shard_map, axis_size
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -50,7 +48,7 @@ def _ring_flash(q, k, v, axis_name, causal, kv_mask, block_q, block_k):
     output is differentiable, so this path serves training too."""
     from agilerl_tpu.ops.flash_attention_vjp import flash_attention_with_lse
 
-    p_size = axis_size(axis_name)
+    p_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     qh = jnp.moveaxis(q, 2, 1)  # [B, H, T, d]
 
@@ -118,7 +116,7 @@ def ring_attention(
     if use_flash:
         return _ring_flash(q, k, v, axis_name, causal, kv_mask,
                            block_q, block_k)
-    p_size = axis_size(axis_name)
+    p_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     B, T, H, d = q.shape
     scale = 1.0 / (d ** 0.5)

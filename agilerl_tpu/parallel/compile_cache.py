@@ -74,6 +74,27 @@ _ENTRY_PREFIX = "exe_"
 
 
 # --------------------------------------------------------------------------- #
+# JAX's own persistent compilation cache — one placement rule
+# --------------------------------------------------------------------------- #
+
+
+def enable_jax_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache for an entry point
+    (``chip_smoke.py``, ``bench.py``) and return the directory set in code.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself: nothing
+    is set here and ``None`` comes back. Otherwise the cache lives at the
+    fixed ``<checkout>/.jax_cache``. The path is part of the cache's key,
+    so it is never derived from a temp name, a pid or the time."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip():
+        return None
+    # the checkout: the directory that holds the agilerl_tpu package
+    path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+# --------------------------------------------------------------------------- #
 # Fingerprint — the strict cache key
 # --------------------------------------------------------------------------- #
 

@@ -356,7 +356,7 @@ def make_pod_generation(
     when a DESERIALIZED executable's multi-device output buffers are
     donated back to it on the next generation (the self-feed pattern);
     the cost is one population copy of transient memory per generation."""
-    from agilerl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if plan is not None:

@@ -5,9 +5,9 @@ persistent executable store unblocks).
 The ``sharding=`` mutation (``hpo/mutation.py``) already swaps a member's
 layout among the registered plans and lets tournament pressure feel the
 difference through :class:`~agilerl_tpu.observability.timeline.StepTimeline`
-step-time telemetry — but on a real TPU up-window every candidate layout
-used to pay a full XLA compile, which made a sweep over even a handful of
-layouts burn most of the window on the compiler. With the
+step-time telemetry — but every candidate layout used to pay a full XLA
+compile, which made a sweep over even a handful of layouts spend most of
+its time in the compiler. With the
 :mod:`~agilerl_tpu.parallel.compile_cache` store wired through
 :func:`~agilerl_tpu.parallel.plan.compile_step_with_plan`, each (plan,
 signature, topology, toolchain) executable is compiled at most once per

@@ -25,8 +25,8 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from agilerl_tpu.compat import shard_map
 
 from agilerl_tpu.llm.model import GPTConfig, _rms, block_apply_dense
 

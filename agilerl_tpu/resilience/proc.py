@@ -80,7 +80,11 @@ class RoleSpec:
     it returns either an object with ``tick()`` (optional ``drain()``) or
     a bare zero-arg tick callable. ``kwargs`` must be JSON-able — object
     graphs are rebuilt child-side from entry points, never pickled across
-    the exec boundary."""
+    the exec boundary. ``env`` must name ``JAX_PLATFORMS``: an accelerator
+    belongs to one process at a time, so each role is TOLD its backend —
+    on one chip one role owns it and the others say ``cpu`` — and a role
+    never inherits it by accident (:meth:`SupervisedProcess.spawn`
+    refuses a spec that does not say)."""
 
     name: str
     target: str
@@ -279,6 +283,11 @@ class SupervisedProcess:
     def spawn(cls, spec: RoleSpec,
               extra_env: Optional[Dict[str, str]] = None
               ) -> "SupervisedProcess":
+        if not (spec.env or {}).get("JAX_PLATFORMS"):
+            raise ValueError(
+                f"role {spec.name!r}: RoleSpec.env does not name "
+                "JAX_PLATFORMS. A chip belongs to one process: say which "
+                "role owns it (e.g. 'tpu') and give every other role 'cpu'")
         root = Path(spec.root)
         for sub in (SPECS_DIR, STATUS_DIR, LOGS_DIR, MEMBERSHIP_DIR,
                     TELEMETRY_DIR):

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Any, Dict, Iterator, Optional, Tuple
+from typing import Any, Dict, Iterator, Optional
 
 import jax
 
@@ -49,47 +49,28 @@ PEAK_BF16_FLOPS = {
 }
 
 
-#: fallback peak (TPU v5 bf16) for TPU generations missing from the table
+#: the v5 peak ``estimate_mfu`` falls back to on a backend with NO defined
+#: peak (CPU/GPU), announced by a warning; never used for a TPU
 _FALLBACK_TPU_PEAK = 197e12
-
-
-def peak_flops_info(device=None, registry=None) -> Tuple[Optional[float], bool]:
-    """``(peak_bf16_flops, estimated)`` for the device's chip generation.
-
-    ``peak`` is None when the backend has no well-defined peak (CPU/GPU) — no
-    fabricated MFU. A TPU generation missing from PEAK_BF16_FLOPS falls back
-    to the v5 peak with ``estimated=True`` and a one-time warning event
-    through ``registry`` (a run's own registry so the event reaches its JSONL
-    stream; the process-default registry otherwise), so the silent-default
-    failure mode (wrong-by-4x MFU on a future chip, nobody notices) cannot
-    recur.
-    """
-    device = device or jax.devices()[0]
-    if device.platform != "tpu":
-        return None, False
-    kind = device.device_kind.lower()
-    peak = PEAK_BF16_FLOPS.get(kind)
-    if peak is not None:
-        return peak, False
-    if registry is None:
-        from agilerl_tpu.observability import get_registry
-
-        registry = get_registry()
-    registry.warn_once(
-        f"peak_flops:{kind}",
-        f"unknown TPU device_kind {kind!r}: no entry in PEAK_BF16_FLOPS — "
-        f"falling back to {_FALLBACK_TPU_PEAK:.0f} FLOPs/s (TPU v5 bf16); "
-        "MFU readings will be tagged estimated=true",
-        device_kind=kind,
-        fallback_peak_flops=_FALLBACK_TPU_PEAK,
-    )
-    return _FALLBACK_TPU_PEAK, True
 
 
 def peak_flops_per_device(device=None) -> Optional[float]:
     """Peak bf16 FLOPs/s for the device's chip generation; None when the
-    backend has no well-defined peak (CPU)."""
-    return peak_flops_info(device)[0]
+    backend has no well-defined peak (CPU/GPU) — no fabricated MFU.
+
+    A TPU whose ``device_kind`` is missing from PEAK_BF16_FLOPS is an error,
+    not a default: a peak assumed for an unknown chip makes every MFU reading
+    wrong by the ratio of the two peaks, and nobody notices."""
+    device = device or jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    kind = device.device_kind.lower()
+    if kind not in PEAK_BF16_FLOPS:
+        raise KeyError(
+            f"unknown TPU device_kind {kind!r}: no entry in PEAK_BF16_FLOPS "
+            f"({sorted(PEAK_BF16_FLOPS)}); add its published bf16 peak "
+            "before reporting a utilisation on it")
+    return PEAK_BF16_FLOPS[kind]
 
 
 def estimate_mfu(
@@ -105,7 +86,7 @@ def estimate_mfu(
     backward compatibility but announced via a one-time warning event — the
     returned figure is an estimate, not a real MFU."""
     if peak_flops is None:
-        peak_flops, _ = peak_flops_info()
+        peak_flops = peak_flops_per_device()
         if peak_flops is None:
             from agilerl_tpu.observability import warn_once
 
@@ -137,10 +118,8 @@ def achieved_flops_metrics(
         return {}
     achieved = flops * calls / elapsed_s
     out: Dict[str, Any] = {"achieved_tflops_per_sec": round(achieved / 1e12, 4)}
-    peak, estimated = peak_flops_info()
+    peak = peak_flops_per_device()
     out["mfu"] = round(achieved / peak, 4) if peak else None
-    if estimated:
-        out["estimated"] = True
     return out
 
 

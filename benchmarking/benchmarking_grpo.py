@@ -2,19 +2,16 @@
 the reference's headline LLM workload: Qwen2.5-0.5B-Instruct, countdown-style
 arithmetic reasoning, pop 4, ctx 1024).
 
-Loads real HF weights when available (llm/hf.load_hf_model; zero-egress images
-fall back to a random-init model of the same architecture class), shards base +
-adapters over a (dp, fsdp, tp) mesh, and reports tokens/sec/chip + MFU — the
-BASELINE.md metric (>=35% MFU target on v5p for the 7B class).
+With ``--model`` (or INIT_HP.MODEL) it loads that model's HF weights through
+llm/hf.load_hf_model and fails if it cannot: a run never reports one model's
+numbers under another's name. With no model asked it runs a random-init toy
+8-layer model. Reports tokens/sec + MFU — the BASELINE.md metric (>=35% MFU
+target on v5p for the 7B class).
 """
 
 # allow running directly as `python <dir>/<script>.py` from a source checkout
 import os as _os, sys as _sys  # noqa: E402
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
-if _os.environ.get("JAX_PLATFORMS"):  # some plugin backends ignore the env var
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", _os.environ["JAX_PLATFORMS"])
 
 import argparse
 import time
@@ -52,17 +49,13 @@ def main(config_path: str, model_name: str = None, steps: int = 10):
     hp = cfg.get("INIT_HP", {})
     model_name = model_name or hp.get("MODEL")
 
-    tok = None
-    base_params = None
     if model_name:
-        try:
-            from agilerl_tpu.llm.hf import load_hf_model, load_hf_tokenizer
+        from agilerl_tpu.llm.hf import load_hf_model, load_hf_tokenizer
 
-            model_cfg, base_params = load_hf_model(model_name)
-            tok = load_hf_tokenizer(model_name)
-        except Exception as e:  # zero-egress fallback
-            print(f"HF load failed ({e}); using random-init model")
-    if base_params is None:
+        model_cfg, base_params = load_hf_model(model_name)
+        tok = load_hf_tokenizer(model_name)
+    else:
+        base_params = None
         tok = CharTokenizer()
         model_cfg = M.GPTConfig(
             vocab_size=tok.vocab_size, n_layer=8, n_head=8, d_model=512,

@@ -21,16 +21,11 @@ import os
 import sys
 import time
 
-# invoked by absolute path from the playbook: sys.path[0] is benchmarking/,
-# not the repo root, so the package import needs an explicit root insert
+# run as a script, sys.path[0] is benchmarking/, not the repo root
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main():
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -40,9 +35,8 @@ def main():
     from agilerl_tpu.llm.serving import BucketedGenerator
 
     on_cpu = jax.default_backend() == "cpu"
-    # BENCH_DECODE_LAYERS: depth knob for compile-service-constrained
-    # up-windows (with the stacked KV cache the decode path scans too, so
-    # compile cost is ~depth-independent; the knob stays for A/B evidence)
+    # BENCH_DECODE_LAYERS: depth knob (with the stacked KV cache the decode
+    # path scans too, so compile cost is ~depth-independent)
     cfg = M.GPTConfig(
         vocab_size=32_000,
         n_layer=int(os.environ.get("BENCH_DECODE_LAYERS",

@@ -1,5 +1,5 @@
 """7B GRPO dress rehearsal (VERDICT r3 next #2): prove the full-scale sharded
-program BUILDS before any TPU up-window, and commit the HBM/MFU plan.
+program BUILDS before it is given a pod, and commit the HBM/MFU plan.
 
 What it does — entirely from abstract shapes (no 7B weights materialised):
 1. builds the llama3-8b preset (the BASELINE.md 7B-class target);
@@ -20,8 +20,9 @@ Run:  XLA_FLAGS=--xla_force_host_platform_device_count=64 JAX_PLATFORMS=cpu \
 The test tier runs it via tests/test_parallel/test_7b_aot.py.
 
 Flash-attention/fused-loss Pallas kernels are OFF in this rehearsal (they
-lower only for a real TPU target; benchmarking/tpu_kernel_validation.py
-covers them on-chip) — the lowered program is the XLA-attention + chunked
+lower only for a real TPU target; chip_smoke.py runs them on the chip and
+tests/test_ops/test_tpu_compile_v5e.py compiles them for one) — the lowered
+program is the XLA-attention + chunked
 loss path, which shares every sharding decision with the flash path.
 """
 
@@ -61,7 +62,6 @@ def _force_cpu(n_devices: int) -> None:
         ).strip()
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     assert len(jax.devices()) >= n_devices, (
         f"need {n_devices} virtual devices, got {len(jax.devices())} — the "
         "backend was initialised before this guard could set the device count"
@@ -349,11 +349,9 @@ def _projection_rows(scen):
 def _closing_prose(go_no_go_label):
     return [
         "BASELINE.md target: >=35% MFU on the 7B-class GRPO workload. "
-        f"{go_no_go_label} is the go/no-go line for the first real "
-        "up-window; the recipe knobs (bf16, per-block remat, flash "
-        "attention, fused loss, chunked decode) are already wired and the "
-        "best single-chip recipe comes from "
-        "`benchmarking/grpo_mfu_sweep.py`.",
+        f"{go_no_go_label} is the go/no-go line for the first run on a "
+        "pod; the recipe knobs (bf16, per-block remat, flash "
+        "attention, fused loss, chunked decode) are already wired.",
         "",
         "An 8B model leaves most of a v5p-64's HBM idle: the headroom "
         "funds a much larger local batch (and/or longer sequences) — raise "
@@ -363,8 +361,8 @@ def _closing_prose(go_no_go_label):
         "Flash-attention/fused-loss Pallas kernels are excluded from the "
         "CPU-backend GSPMD lowering (they lower natively only for a TPU "
         "target); their Mosaic lowering is verified by "
-        "`benchmarking/tpu_aot_compile.py` (compile-only v5p topology) and "
-        "on-chip by `benchmarking/tpu_kernel_validation.py`.",
+        "`benchmarking/tpu_aot_compile.py` (compile-only topology), and "
+        "`chip_smoke.py` runs them on a chip.",
     ]
 
 

@@ -104,11 +104,9 @@ def import_stage(args):
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.setdefault(
         "XLA_FLAGS", "--xla_force_host_platform_device_count=4")
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import dataclasses
 
+    import jax
     import jax.numpy as jnp
     import numpy as np
 

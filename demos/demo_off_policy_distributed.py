@@ -24,9 +24,6 @@ if _os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
         _os.environ["XLA_FLAGS"] = (
             _flags + " --xla_force_host_platform_device_count=8"
         ).strip()
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
 
 import jax
 import numpy as np

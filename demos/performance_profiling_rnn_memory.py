@@ -8,10 +8,6 @@ performance_profiling_lander_rnn.py to see how much the host env costs."""
 # allow running directly as `python <dir>/<script>.py` from a source checkout
 import os as _os, sys as _sys  # noqa: E402
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
-if _os.environ.get("JAX_PLATFORMS"):  # some plugin backends ignore the env var
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", _os.environ["JAX_PLATFORMS"])
 
 import time
 

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from agilerl_tpu import compat
 from agilerl_tpu.envs import classic
 
 
@@ -86,7 +85,7 @@ def test_trajectory_parity_x64(env_id, seed):
     the dynamics, reward function, and termination rule are the SAME
     computation as gymnasium's."""
     cls, to_state, to_action = CASES[env_id]
-    with compat.enable_x64(True):
+    with jax.enable_x64(True):
         steps = _co_step(env_id, cls(), to_state, to_action, seed,
                          horizon=200, rtol=1e-9, x64=True)
     assert steps > 0

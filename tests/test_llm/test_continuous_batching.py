@@ -356,8 +356,8 @@ def test_generate_rejects_empty_row_before_enqueueing_any():
 
 
 def test_measured_cache_size_present_on_installed_jax():
-    """jax 0.4.37 (compat.py documents this image) DOES expose _cache_size;
-    the measured counter must be live, not the sentinel."""
+    """The installed jax (0.9.0) DOES expose _cache_size; the measured
+    counter must be live, not the sentinel."""
     f = jax.jit(lambda x: x + 1)
     assert measured_cache_size(f) == 0
     f(jnp.ones(2))

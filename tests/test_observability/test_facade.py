@@ -149,38 +149,26 @@ def test_env_var_directory_resolution(tmp_path, monkeypatch):
     assert any(e["kind"] == "metrics" for e in read_jsonl(files[0]))
 
 
-def test_unknown_tpu_device_kind_warns_once_and_tags_estimated():
-    """Satellite: peak_flops_per_device no longer silently defaults — the
-    fallback is tagged estimated and announced through the registry."""
-    from agilerl_tpu.observability import get_registry
-    from agilerl_tpu.utils.profiling import peak_flops_info, peak_flops_per_device
+def test_unknown_tpu_device_kind_is_an_error():
+    """A TPU missing from PEAK_BF16_FLOPS raises: no peak is assumed for it,
+    so no MFU can be silently wrong by the ratio of two chips' peaks."""
+    from agilerl_tpu.utils.profiling import peak_flops_per_device
 
     class FakeTPU:
         platform = "tpu"
         device_kind = "tpu v99"
 
-    with pytest.warns(RuntimeWarning):
-        peak, estimated = peak_flops_info(FakeTPU())
-    assert peak == 197e12 and estimated is True
-    # warn-once: second call is silent
-    import warnings
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        peak2, est2 = peak_flops_info(FakeTPU())
-    assert (peak2, est2) == (peak, True)
-    assert get_registry().counter("warnings_total").value >= 1
-    # the compatibility wrapper still returns the bare peak
-    assert peak_flops_per_device(FakeTPU()) == 197e12
+    with pytest.raises(KeyError, match="tpu v99.*PEAK_BF16_FLOPS"):
+        peak_flops_per_device(FakeTPU())
 
     class CPU:
         platform = "cpu"
         device_kind = "cpu"
 
-    assert peak_flops_info(CPU()) == (None, False)
+    assert peak_flops_per_device(CPU()) is None
 
     class KnownTPU:
         platform = "tpu"
         device_kind = "TPU v5p"
 
-    assert peak_flops_info(KnownTPU()) == (459e12, False)
+    assert peak_flops_per_device(KnownTPU()) == 459e12

@@ -29,15 +29,13 @@ def test_step_events_monotone_with_throughput():
 
 def test_mfu_reuses_profiling_flops_accounting(monkeypatch):
     """MFU = transformer_flops_per_token(config) * tokens / (dt * peak):
-    the SAME accounting bench.py uses, tagged estimated=true when the peak
-    was a fallback."""
+    the SAME accounting bench.py uses, against the device's table peak."""
     from agilerl_tpu.llm.model import GPTConfig
     from agilerl_tpu.observability import timeline as T
 
     cfg = GPTConfig(vocab_size=96, n_layer=2, n_head=4, n_kv_head=2,
                     d_model=32, max_seq_len=64, dtype=jnp.float32)
-    monkeypatch.setattr(
-        T, "peak_flops_info", lambda device=None, registry=None: (1e12, True))
+    monkeypatch.setattr(T, "peak_flops_per_device", lambda device=None: 1e12)
     reg = MetricsRegistry(sink=MemorySink())
     tl = StepTimeline(reg, name="llm", model_config=cfg, memory_stats_every=0)
     tl.step(tokens=1024)
@@ -46,7 +44,7 @@ def test_mfu_reuses_profiling_flops_accounting(monkeypatch):
 
     expected = transformer_flops_per_token(cfg) * 1024 / (e["step_time_s"] * 1e12)
     assert e["mfu"] == pytest.approx(expected, rel=1e-3)
-    assert e["estimated"] is True
+    assert "estimated" not in e  # the peak is the table's or the run fails
     assert reg.gauge("llm/mfu").value == e["mfu"]
 
 

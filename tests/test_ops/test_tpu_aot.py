@@ -3,8 +3,10 @@ kernels must keep compiling natively through the REAL XLA:TPU + Mosaic
 pipeline — via libtpu's compile-only PJRT topology, no chip needed.
 
 These are the tiny-dims versions of benchmarking/tpu_aot_compile.py's
-targets; the full-dims run (llama3-8b lm-head/attention shapes, the 7B GSPMD
-pod step) writes benchmarking/tpu_aot_report.json. Skips cleanly when libtpu
+targets, compiled for the chip builders have (a described v5e:2x2); the
+full-dims run (llama3-8b lm-head/attention shapes, the 7B GSPMD pod step)
+writes benchmarking/tpu_aot_report.json, and test_tpu_compile_v5e.py keeps
+the main path's real widths in the fast tier. Skips cleanly when libtpu
 cannot build a topology (non-TPU wheels).
 
 History this tier guards against: interpret mode accepted (1, block)
@@ -33,7 +35,7 @@ def tpu_device():
     try:
         from jax.experimental import topologies
 
-        topo = topologies.get_topology_desc("v5p:2x2x1", platform="tpu")
+        topo = topologies.get_topology_desc("v5e:2x2", platform="tpu")
     except Exception as e:  # pragma: no cover - non-TPU jaxlib
         pytest.skip(f"no compile-only TPU topology available: {e}")
     return topo.devices[0]
@@ -91,7 +93,7 @@ def test_flash_attention_fwd_and_grad_compile_for_tpu(tpu_device):
 def test_fused_grpo_step_compiles_for_tpu(tpu_device):
     """The production GRPO update with BOTH Pallas kernels on (flash
     attention + fused loss, incl. their custom VJPs) compiles natively for
-    one v5p core from abstract shapes."""
+    one v5e chip from abstract shapes."""
     from jax.sharding import SingleDeviceSharding
 
     from agilerl_tpu.algorithms.core.optimizer import OptimizerWrapper

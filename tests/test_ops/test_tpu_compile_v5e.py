@@ -1,0 +1,133 @@
+"""The main path's kernels and serving programs, compiled at qwen2-7b widths
+by the TPU's own compiler for a described ``v5e:2x2`` (no chip attached).
+
+Interpret mode accepts tilings and VMEM footprints that Mosaic refuses, and
+``jax.default_backend()`` is ``cpu`` here, so each test hands the compiler
+the kernel or the jitted step itself with shapes placed on the described
+device. Nothing runs: a pass says the chip's compiler takes the program,
+not that its result is right (``chip_smoke.py`` checks that on the chip).
+Skipped where libtpu cannot describe the topology.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from agilerl_tpu.llm import model as M
+from agilerl_tpu.llm.presets import preset
+from agilerl_tpu.llm.serving import ContinuousGenerator
+from agilerl_tpu.ops.flash_attention_vjp import flash_attention_diff
+from agilerl_tpu.ops.fused_loss import fused_token_logprob_diff
+from agilerl_tpu.ops.kernel_mode import native_kernels
+
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Device 0 of a described v5e 2x2, with JAX's persistent compilation
+    cache off: an entry written for a described device cannot be read back
+    without a chip, and the next compile would warn and compile again."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a described topology touches no device: do not take libtpu's lockfile
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "true")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices[0]
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(device, tree):
+    s = SingleDeviceSharding(device)
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), tree)
+
+
+def _compiled_text(fn, *args, **kwargs) -> str:
+    with native_kernels():
+        return fn.lower(*args, **kwargs).compile().as_text()
+
+
+def test_fused_loss_fwd_and_grad_at_qwen2_7b_head(v5e):
+    # the learn step's lm head: f32 hidden x f32 untied head, 152064 wide
+    N, D, V = 4096, 3584, 152064
+    s = jax.ShapeDtypeStruct
+    h, w, t = _on(v5e, (s((N, D), jnp.float32), s((D, V), jnp.float32),
+                        s((N,), jnp.int32)))
+
+    def loss(hh, ww, tt):
+        return fused_token_logprob_diff(hh, ww, tt, 1.0).sum()
+
+    text = _compiled_text(jax.jit(jax.value_and_grad(loss, argnums=(0, 1))),
+                          h, w, t)
+    assert text.count(KERNEL) >= 3  # forward, dH, dW
+
+
+@pytest.mark.parametrize("spmd", [False, True])
+def test_flash_attention_fwd_and_grad_at_qwen2_7b_heads(v5e, spmd):
+    B, H, T, d = 4, 28, 2048, 128
+    s = jax.ShapeDtypeStruct
+    q, m = _on(v5e, (s((B, H, T, d), jnp.bfloat16), s((B, T), jnp.int32)))
+
+    def loss(qq, kk, vv, mm):
+        return flash_attention_diff(
+            qq, kk, vv, mm, True, spmd=spmd).astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))), q, q, q, m)
+    assert text.count(KERNEL) >= 3  # forward, dQ, dK/dV
+
+
+@pytest.fixture(scope="module")
+def paged_tier(v5e):
+    """qwen2-7b widths, 2 layers: the continuous tier as ``GRPO`` builds it,
+    with its abstract weights, pool and slot state on the described chip."""
+    cfg = preset("qwen2-7b", n_layer=2, max_seq_len=2048)
+    gen = ContinuousGenerator(cfg, max_new_tokens=768, pad_id=0, eos_id=1,
+                              temperature=0.9, speculate=True)
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(lambda k: M.init_params(k, cfg), key)
+    lora = jax.eval_shape(lambda k: M.init_lora(k, cfg, 8), key)
+    pool = jax.eval_shape(
+        lambda: M.init_paged_cache(cfg, gen.n_blocks, gen.block_size))
+    s = jax.ShapeDtypeStruct
+    n, extent = gen.slots, gen.max_blocks * gen.block_size
+    slot_state = (
+        s((n, gen.max_blocks), jnp.int32),  # block tables
+        s((n, extent), jnp.int32),          # slot mask
+        s((n,), jnp.int32),                 # lengths
+        s((n,), jnp.int32),                 # prev_tok
+        s((n,), jnp.bool_),                 # prev_ok
+        s((n,), jnp.int32),                 # pos
+        s((n,), jnp.int32),                 # step_idx
+        s((n,), jnp.bool_),                 # done
+        s((n, 2), jnp.uint32),              # keys
+    )
+    drafts = (s((n, gen.speculate.k), jnp.int32), s((n,), jnp.int32))
+    return (gen,) + _on(v5e, ((params, lora, pool) + slot_state, drafts))
+
+
+def test_paged_decode_chunk_at_qwen2_7b_widths(paged_tier):
+    gen, state, _ = paged_tier
+    text = _compiled_text(gen._decode, *state, greedy=False)
+    # decode attention is the XLA dynamic-trip-count loop, not a kernel
+    assert "while" in text and KERNEL not in text
+
+
+def test_paged_verify_step_at_qwen2_7b_widths(paged_tier):
+    gen, state, drafts = paged_tier
+    text = _compiled_text(gen._verify, *state, *drafts, greedy=False)
+    assert "while" in text and KERNEL not in text

@@ -17,11 +17,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:  # noqa: BLE001 — older jax: option absent, mpi-only, etc.
-    pass
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def main() -> None:

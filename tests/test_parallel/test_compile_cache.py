@@ -336,8 +336,8 @@ class TestPlanStepAndLayoutSearch:
         assert [c.cache_hit for c in res.candidates] == [False, False]
         assert res.best is not None
 
-        # the second sweep — a new process, a mutated member, the next TPU
-        # up-window — loads every candidate: compile once per layout EVER
+        # the second sweep — a new process, a mutated member — loads every
+        # candidate: compile once per layout EVER
         reg2 = MetricsRegistry()
         with CompileGuard(label="warm-layout-sweep"):
             res2 = search_layouts(_loss_step, ("lora", "batch"), _loss_args,

@@ -193,6 +193,18 @@ def _spec(root, name, target, kwargs=None, **over):
     return RoleSpec(**base)
 
 
+def test_spawn_refuses_a_role_that_is_not_told_its_backend(tmp_path):
+    """A chip belongs to one process: a role whose env does not name
+    JAX_PLATFORMS would inherit the launcher's, so no process is started."""
+    sup = ProcessSupervisor(tmp_path, lease_timeout=2.0,
+                            registry=MetricsRegistry())
+    spec = _spec(tmp_path, "idle", "agilerl_tpu.training.launch:idle_role",
+                 env={"PYTHONPATH": REPO_ROOT})
+    with pytest.raises(ValueError, match="JAX_PLATFORMS"):
+        sup.spawn(spec)
+    assert sup.procs == {} and not (tmp_path / "specs").exists()
+
+
 def test_role_harness_runs_idle_role_to_done(tmp_path):
     sup = ProcessSupervisor(tmp_path, lease_timeout=2.0,
                             registry=MetricsRegistry())

@@ -15,9 +15,6 @@ if "host_platform_device_count" not in _flags:
     _os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
 import jax
-
-jax.config.update("jax_platforms", _os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
