@@ -30,7 +30,10 @@ from agilerl_tpu.algorithms.core.registry import (
     OptimizerConfig,
     RLParameter,
 )
-from agilerl_tpu.components.rollout_buffer import RolloutBuffer
+from agilerl_tpu.components.rollout_buffer import (
+    RolloutBuffer,
+    shuffled_minibatches,
+)
 from agilerl_tpu.networks import distributions as D
 from agilerl_tpu.networks.actors import StochasticActor
 from agilerl_tpu.networks.base import EvolvableNetwork
@@ -416,10 +419,7 @@ class PPO(RLAlgorithm):
 
             def epoch(carry, k):
                 params, opt_state = carry
-                perm = jax.random.permutation(k, total)[: n_mb * mb]
-                batches = jax.tree_util.tree_map(
-                    lambda x: x[perm].reshape((n_mb, mb) + x.shape[1:]), data
-                )
+                batches = shuffled_minibatches(k, data, n_mb, mb)
                 (params, opt_state), losses = jax.lax.scan(
                     minibatch, (params, opt_state), batches
                 )

@@ -11,6 +11,7 @@ and BPTT-sequence minibatching are jitted gathers over permuted indices.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Iterator, NamedTuple, Optional, Tuple
 
 import jax
@@ -86,6 +87,52 @@ def _flat_gather(data: PyTree, idx: jax.Array) -> PyTree:
         return flat[idx]
 
     return jax.tree_util.tree_map(g, data)
+
+
+# A row of at most this many elements rides the shuffle's sort, a column an
+# element; a wider one is gathered by the index column the same sort carries.
+# On a TPU v5e a gather pays ~15 ns an index whatever the row holds, and two
+# rounds of the sort move a further column for 1.1-1.4 ns a row, so the two
+# cross near 12 columns (PERF.md section 6, PR 26).
+MAX_SORT_CARRIED_ROW = 8
+
+
+def shuffled_minibatches(
+    key: jax.Array, flat: PyTree, num_minibatches: int, minibatch_size: int
+) -> PyTree:
+    """One epoch's minibatches: every ``[total, ...]`` leaf of ``flat`` as
+    ``[num_minibatches, minibatch_size, ...]``, rows in the order of
+    ``jax.random.permutation(key, total)``, the rows left over dropped. Equals
+    ``x[perm[:num_minibatches * minibatch_size]].reshape(...)`` leaf for leaf,
+    bit for bit, but moves each narrow row once, as payload of the sorts that
+    make the permutation, instead of once a leaf by a gather. Traces under
+    ``vmap`` (one key a member) and ``shard_map``."""
+    leaves, treedef = jax.tree_util.tree_flatten(flat)
+    total = leaves[0].shape[0]
+    widths = [math.prod(x.shape[1:]) for x in leaves]
+    carried = [w <= MAX_SORT_CARRIED_ROW for w in widths]
+    columns = [x.reshape(total, w)[:, j]
+               for x, w, c in zip(leaves, widths, carried) if c for j in range(w)]
+    if not all(carried):
+        columns.append(jnp.arange(total))
+    # jax.random.permutation's own rounds (jax._src.random._shuffle): a stable
+    # sort by fresh 32-bit keys, repeated until ties are improbable
+    rounds = math.ceil(3 * math.log(max(1, total)) / math.log(np.iinfo(np.uint32).max))
+    for _ in range(rounds):
+        key, sub = jax.random.split(key)
+        bits = jax.random.bits(sub, (total,), jnp.uint32)
+        _, *columns = jax.lax.sort((bits, *columns), num_keys=1, is_stable=True)
+    columns = [c[: num_minibatches * minibatch_size] for c in columns]
+
+    out, at = [], 0
+    for x, w, c in zip(leaves, widths, carried):
+        if c:
+            rows = jnp.stack(columns[at:at + w], axis=1)
+            at += w
+        else:
+            rows = x[columns[-1]]
+        out.append(rows.reshape((num_minibatches, minibatch_size) + x.shape[1:]))
+    return jax.tree_util.tree_unflatten(treedef, out)
 
 
 class RolloutBuffer:

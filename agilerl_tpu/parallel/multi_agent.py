@@ -20,6 +20,7 @@ from typing import Any, Callable, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from agilerl_tpu.components.rollout_buffer import shuffled_minibatches
 from agilerl_tpu.envs.core import VecState
 from agilerl_tpu.envs.multi_agent import SimpleSpreadJax, make_ma_autoreset_step
 from agilerl_tpu.networks import distributions as D
@@ -212,13 +213,7 @@ class EvoIPPO:
 
         def epoch(carry, k):
             params, opt_state = carry
-            perm = jax.random.permutation(k, total)[: mb * self.num_minibatches]
-            batches = jax.tree_util.tree_map(
-                lambda x: x[perm].reshape(
-                    (self.num_minibatches, mb) + x.shape[1:]
-                ),
-                flat,
-            )
+            batches = shuffled_minibatches(k, flat, self.num_minibatches, mb)
 
             def minibatch(carry, b):
                 params, opt_state = carry

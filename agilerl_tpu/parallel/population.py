@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from agilerl_tpu.components.rollout_buffer import shuffled_minibatches
 from agilerl_tpu.envs.core import JaxEnv, VecState, make_autoreset_step
 from agilerl_tpu.networks import distributions as D
 from agilerl_tpu.networks.base import EvolvableNetwork
@@ -167,6 +168,10 @@ class EvoPPO:
         return adv, adv + traj["value"]
 
     def _ppo_update(self, actor, critic, opt_state, traj, adv, ret, key):
+        """PPO epochs over the flattened rollout. An epoch's rows ride the
+        sorts that draw its permutation (``shuffled_minibatches``): on a TPU
+        v5e a gather pays ~15 ns an index, the sort moves a payload column
+        for a tenth of that (PERF.md section 6, PR 26)."""
         T, N = traj["reward"].shape
         total = T * N
         mb = total // self.num_minibatches
@@ -180,10 +185,7 @@ class EvoPPO:
 
         def epoch(carry, k):
             params, opt_state = carry
-            perm = jax.random.permutation(k, total)[: mb * self.num_minibatches]
-            batches = jax.tree_util.tree_map(
-                lambda x: x[perm].reshape((self.num_minibatches, mb) + x.shape[1:]), flat
-            )
+            batches = shuffled_minibatches(k, flat, self.num_minibatches, mb)
 
             def minibatch(carry, b):
                 params, opt_state = carry
