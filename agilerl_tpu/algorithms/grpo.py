@@ -352,6 +352,11 @@ class GRPO(EvolvableAlgorithm):
         Pass None to detach (restores the pre-attach ``continuous_decode``
         setting — detaching must not leave the agent on a private bare
         generator it never used before)."""
+        if self.model_config.is_hybrid:
+            raise NotImplementedError(
+                "attach_rollout_fleet over a hybrid stack: the fleet's "
+                "prefill-to-decode transfer carries prompt KV only, not the "
+                "state-space layers' recurrent state; not implemented")
         if fleet is None:
             if self.rollout_fleet is not None:
                 self.continuous_decode = self._pre_fleet_continuous_decode
@@ -585,6 +590,13 @@ class GRPO(EvolvableAlgorithm):
         """(logprobs, update) for the active parallelism mode, with the
         sequence-parallel input contract validated against THIS batch."""
         if self.sequence_parallel_axis is not None:
+            if self.model_config.is_hybrid:
+                raise NotImplementedError(
+                    "sequence_parallel_axis over a hybrid stack: the "
+                    "long-context path splits the sequence across chips for "
+                    "ring attention, and a state-space layer's recurrence "
+                    "would have to hand its state from shard to shard; not "
+                    "implemented")
             mesh, axis = self._require_sp_mesh()
             sp_size = mesh.shape[axis]
             if ids.shape[1] % sp_size:
@@ -781,6 +793,17 @@ class GRPO(EvolvableAlgorithm):
                 raise ValueError("to_mesh needs a mesh or a plan")
             plan = PL.grpo_plan_for_mesh(mesh)
         plan, mesh = PL.resolve_plan_and_mesh(plan, mesh)
+        if self.model_config.is_hybrid:
+            # a plan written for attention stacks would silently replicate
+            # every state-space leaf: refuse unless it names them all
+            try:
+                plan.shardings("params", self.base_params, mesh, strict=True)
+            except PL.UnmatchedLeafError as err:
+                raise ValueError(
+                    "to_mesh over a hybrid stack: the plan's 'params' rules "
+                    "do not cover the state-space layers' leaves (in_proj, "
+                    f"conv_w, x_proj, dt_proj, A_log, out_proj, ...): {err}"
+                ) from err
 
         # cached logprob/update closures capture the OLD base_params (and, for
         # sp fns, the old mesh) — drop them so learn() rebuilds against the

@@ -101,12 +101,16 @@ def _suppress_eos(logits, step, eos_id, min_new_tokens):
 def prefill_head(config, params, prompt, prompt_mask, caches, key, *,
                  lora, lora_scale, temperature, top_k, top_p, eos_id,
                  pad_id, min_new_tokens, row_valid=None,
-                 return_logits=False):
+                 return_logits=False, keep_prev_state=False):
     """Prompt forward + first sampled token. Returns the decode carry and
     the first (token, emit_mask) pair. row_valid marks real rows (bucket
     padding rows are born done); None means every row is real.
     return_logits=True appends the raw last-position logits [B, V] to the
     return (the serving tier's behavior-logprob capture hook).
+    keep_prev_state=True leaves a hybrid stack's recurrent state from BEFORE
+    the last prompt token in the returned cache (the continuous tier
+    snapshots it for prefix-cache hits); otherwise it is dropped, so the
+    decode loops carry the current state alone.
 
     SHARED between generate() and llm/serving.BucketedGenerator so the two
     paths cannot drift (review finding)."""
@@ -116,6 +120,8 @@ def prefill_head(config, params, prompt, prompt_mask, caches, key, *,
         config, params, prompt, attention_mask=prompt_mask,
         positions=positions, cache=caches, lora=lora, lora_scale=lora_scale,
     )
+    if not keep_prev_state and caches.prev_state is not None:
+        caches = caches._replace(prev_state=None)
     last_logits = M.logits_fn(config, params, hidden[:, -1:, :])[:, 0, :]
     pos = prompt_mask.sum(axis=-1)
     key, k0 = jax.random.split(key)
@@ -263,11 +269,12 @@ def paged_decode_step(config, params, carry, *, lora, lora_scale, temperature,
     slot_mask = slot_mask.at[
         jnp.arange(n_slots), jnp.minimum(lengths, S - 1)
     ].set(prev_ok.astype(slot_mask.dtype))
-    hidden, (new_k, new_v) = M.forward_paged(
+    hidden, new = M.forward_paged(
         config, params, prev_tok[:, None], pos, lengths, cache, block_tables,
         slot_mask, lora=lora, lora_scale=lora_scale,
     )
-    cache = M.paged_scatter_tokens(cache, block_tables, lengths, new_k, new_v)
+    # (new_k, new_v), and the new recurrent state too over a hybrid stack
+    cache = M.paged_scatter_tokens(cache, block_tables, lengths, *new)
     logits = M.logits_fn(config, params, hidden)[:, 0, :]
     pos = pos + prev_ok.astype(pos.dtype)
     split = jax.vmap(jax.random.split)(keys)  # [slots, 2, 2]

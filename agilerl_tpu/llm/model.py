@@ -84,9 +84,56 @@ class GPTConfig:
     capacity_factor: float = 1.25
     moe_every: int = 1  # layer i is MoE iff (i + 1) % moe_every == 0
     router_aux_weight: float = 0.01
+    # Positional encoding of the attention layers: rotary, or none at all
+    # (a hybrid stack's state-space layers carry position).
+    rope: bool = True
+    # Hybrid stack: layer i is an ATTENTION layer iff
+    # i % attn_layer_period == attn_layer_offset, every other layer a
+    # state-space (Mamba-1) layer (llm/ssm.py). Period 1 = attention
+    # everywhere, today's uniform stack. Consecutive layers of one kind run
+    # as one lax.scan over stacked weights (_run_layers), so a program holds
+    # one body a RUN of layers, not one a layer.
+    attn_layer_period: int = 1
+    attn_layer_offset: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: Optional[int] = None  # None -> ceil(d_model / 16)
 
     def is_moe_layer(self, i: int) -> bool:
         return self.n_experts > 0 and (i + 1) % self.moe_every == 0
+
+    @property
+    def is_hybrid(self) -> bool:
+        return self.attn_layer_period > 1
+
+    def layer_kind(self, i: int) -> str:
+        if i % self.attn_layer_period == self.attn_layer_offset:
+            return "attn"
+        return "mamba"
+
+    def layer_runs(self):
+        """[(kind, first layer, number of layers)]: the maximal runs of
+        consecutive layers of one kind."""
+        runs = []
+        for i in range(self.n_layer):
+            kind = self.layer_kind(i)
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, i, 1])
+        return [tuple(r) for r in runs]
+
+    def n_layers_of(self, kind: str) -> int:
+        return sum(self.layer_kind(i) == kind for i in range(self.n_layer))
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.d_model
+
+    @property
+    def mamba_rank(self) -> int:
+        return self.mamba_dt_rank or -(-self.d_model // 16)
 
     @property
     def kv_heads(self) -> int:
@@ -116,16 +163,32 @@ class KVCache(NamedTuple):
     length: jax.Array  # [] int32 — filled slots
     mask: jax.Array  # [B, S] int32 — 1 where the slot holds a REAL token
     # (left-padded prompts leave dead slots that must stay masked forever)
+    # Hybrid stacks only (k/v then hold the ATTENTION layers alone): the
+    # state-space layers' recurrent state after the last token, and — where
+    # a multi-token forward just ran — before it (llm/ssm.py):
+    # one (conv [n, B, k-1, d_inner], ssm [n, B, d_state, d_inner] float32)
+    # a run of n state-space layers — a run's scan reads and writes its own
+    # arrays whole, with no slicing or joining of one big array
+    state: Any = None
+    prev_state: Any = None
 
 
 def init_kv_cache(config: GPTConfig, batch: int, max_len: Optional[int] = None) -> KVCache:
     s = max_len or config.max_seq_len
-    shape = (config.n_layer, batch, s, config.kv_heads, config.head_dim)
+    shape = (config.n_layers_of("attn"), batch, s, config.kv_heads,
+             config.head_dim)
+    state = None
+    if config.is_hybrid:
+        from agilerl_tpu.llm import ssm
+
+        state = tuple(ssm.init_state(config, n, batch)
+                      for kind, _, n in config.layer_runs() if kind == "mamba")
     return KVCache(
         k=jnp.zeros(shape, config.dtype),
         v=jnp.zeros(shape, config.dtype),
         length=jnp.zeros((), jnp.int32),
         mask=jnp.zeros((batch, s), jnp.int32),
+        state=state,
     )
 
 
@@ -138,19 +201,22 @@ def _normal(key, shape, std):
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(jnp.float32)
 
 
-def init_params(key: jax.Array, config: GPTConfig) -> Params:
+def init_block(key: jax.Array, config: GPTConfig, i: int) -> Params:
+    """Layer ``i``'s weights from its own key (``init_params`` hands layer i
+    the key ``split(key, n_layer + 3)[i + 1]``): what makes a base run by
+    run, or layer by layer, draw the numbers ``init_params`` draws."""
     d, hd = config.d_model, config.head_dim
     nh, nkv, f = config.n_head, config.kv_heads, config.ff_dim
     std = 0.02
     out_std = std / math.sqrt(2 * config.n_layer)
-    keys = jax.random.split(key, config.n_layer + 3)
-    params: Dict = {
-        "tok_emb": _normal(keys[0], (config.vocab_size, d), std),
-        "blocks": {},
-        "ln_f": jnp.ones((d,), jnp.float32),
-    }
-    for i in range(config.n_layer):
-        ks = jax.random.split(keys[i + 1], 8)
+    ks = jax.random.split(key, 8)
+    if config.layer_kind(i) == "mamba":
+        from agilerl_tpu.llm import ssm
+
+        blk = {"ln1": jnp.ones((d,), jnp.float32),
+               **ssm.init_mamba_mixer(ks[0], config, out_std),
+               "ln2": jnp.ones((d,), jnp.float32)}
+    else:
         blk = {
             "ln1": jnp.ones((d,), jnp.float32),
             "wq": _normal(ks[0], (d, nh * hd), std),
@@ -159,21 +225,45 @@ def init_params(key: jax.Array, config: GPTConfig) -> Params:
             "wo": _normal(ks[3], (nh * hd, d), out_std),
             "ln2": jnp.ones((d,), jnp.float32),
         }
-        if config.is_moe_layer(i):
-            E = config.n_experts
-            blk["router"] = _normal(ks[7], (d, E), std)
-            blk["w_gate"] = _normal(ks[4], (E, d, f), std)
-            blk["w_up"] = _normal(ks[5], (E, d, f), std)
-            blk["w_down"] = _normal(ks[6], (E, f, d), out_std)
-        else:
-            blk["w_gate"] = _normal(ks[4], (d, f), std)
-            blk["w_up"] = _normal(ks[5], (d, f), std)
-            blk["w_down"] = _normal(ks[6], (f, d), out_std)
-        if config.qkv_bias:
-            blk["bq"] = jnp.zeros((nh * hd,), jnp.float32)
-            blk["bk"] = jnp.zeros((nkv * hd,), jnp.float32)
-            blk["bv"] = jnp.zeros((nkv * hd,), jnp.float32)
-        params["blocks"][str(i)] = blk
+    if config.is_moe_layer(i):
+        E = config.n_experts
+        blk["router"] = _normal(ks[7], (d, E), std)
+        blk["w_gate"] = _normal(ks[4], (E, d, f), std)
+        blk["w_up"] = _normal(ks[5], (E, d, f), std)
+        blk["w_down"] = _normal(ks[6], (E, f, d), out_std)
+    else:
+        blk["w_gate"] = _normal(ks[4], (d, f), std)
+        blk["w_up"] = _normal(ks[5], (d, f), std)
+        blk["w_down"] = _normal(ks[6], (f, d), out_std)
+    if config.qkv_bias and "wq" in blk:
+        blk["bq"] = jnp.zeros((nh * hd,), jnp.float32)
+        blk["bk"] = jnp.zeros((nkv * hd,), jnp.float32)
+        blk["bv"] = jnp.zeros((nkv * hd,), jnp.float32)
+    return blk
+
+
+def init_params(key: jax.Array, config: GPTConfig) -> Params:
+    """A uniform stack keeps one tree a layer (``params["blocks"][str(i)]``).
+    A hybrid stack's weights are stored in the layout its programs scan: one
+    stacked tree a RUN of equal layers (``params["runs"][r]``, leading axis =
+    the run's layers, ``config.layer_runs()``'s order) — stacking per-layer
+    trees inside a program would cost a copy of the base in every one."""
+    d = config.d_model
+    std = 0.02
+    keys = jax.random.split(key, config.n_layer + 3)
+    blocks = [init_block(keys[i + 1], config, i)
+              for i in range(config.n_layer)]
+    params: Dict = {
+        "tok_emb": _normal(keys[0], (config.vocab_size, d), std),
+        "ln_f": jnp.ones((d,), jnp.float32),
+    }
+    if config.is_hybrid:
+        params["runs"] = [
+            jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                   *blocks[first:first + n])
+            for _, first, n in config.layer_runs()]
+    else:
+        params["blocks"] = {str(i): blk for i, blk in enumerate(blocks)}
     if not config.tie_embeddings:
         params["lm_head"] = _normal(keys[-1], (d, config.vocab_size), std)
     return params
@@ -210,16 +300,34 @@ def init_lora(
             "LoRA on FFN projections is not supported for MoE layers; "
             f"restrict targets to attention projections {LORA_TARGETS}"
         )
-    lora: Dict = {"blocks": {}}
     target_ids = {name: idx for idx, name in enumerate(sorted(dims))}
+    by_kind = {"attn": dims}
+    if config.is_hybrid:
+        # a state-space layer takes adapters on its own projections (and on
+        # the FFN both kinds share); an attention target names nothing in it
+        from agilerl_tpu.llm import ssm
+
+        mamba_dims = ssm.mamba_lora_dims(config)
+        target_ids.update({name: len(dims) + idx
+                           for idx, name in enumerate(sorted(mamba_dims))})
+        by_kind["mamba"] = {**mamba_dims, **{n: dims[n] for n in ffn_names}}
+    unknown = [t for t in targets if t not in target_ids]
+    if unknown:
+        raise ValueError(
+            f"LoRA targets {unknown} name no projection of this stack; it "
+            f"has {sorted(target_ids)}")
+    lora: Dict = {"blocks": {}}
     for i in range(config.n_layer):
         k = jax.random.fold_in(key, i)
         layer = {}
+        layer_dims = by_kind[config.layer_kind(i)]
         for t in targets:
+            if t not in layer_dims:
+                continue
             # fixed per-name fold (NOT hash(): salted per process, which would
             # desync adapter init across hosts — review finding)
             ka = jax.random.fold_in(k, target_ids[t])
-            din, dout = dims[t]
+            din, dout = layer_dims[t]
             layer[t] = {
                 "A": _normal(ka, (din, rank), 0.02),
                 "B": jnp.zeros((rank, dout), jnp.float32),
@@ -242,6 +350,16 @@ def merge_lora(params: Params, lora: Params, scale: float = 2.0) -> Params:
     needs it — parity contrast: the reference must merge before every vLLM
     weight swap, core/base.py:2772)."""
     out = jax.tree_util.tree_map(lambda x: x, params)
+    if "runs" in params:
+        # a hybrid stack: layer i is row i - first of its run's stacked tree
+        first = 0
+        for run in out["runs"]:
+            n = jax.tree_util.tree_leaves(run)[0].shape[0]
+            for j in range(n):
+                for t, ab in lora["blocks"].get(str(first + j), {}).items():
+                    run[t] = run[t].at[j].add((ab["A"] @ ab["B"]) * scale)
+            first += n
+        return out
     for i, layer in lora["blocks"].items():
         for t, ab in layer.items():
             out["blocks"][i][t] = params["blocks"][i][t] + (ab["A"] @ ab["B"]) * scale
@@ -287,8 +405,9 @@ def _qkv_rope(config: GPTConfig, blk, x, positions, lora_layer, lora_scale):
     q = q.reshape(B, T, config.n_head, config.head_dim)
     k = k.reshape(B, T, config.kv_heads, config.head_dim)
     v = v.reshape(B, T, config.kv_heads, config.head_dim)
-    q = _rope(q, positions, config.rope_theta)
-    k = _rope(k, positions, config.rope_theta)
+    if config.rope:
+        q = _rope(q, positions, config.rope_theta)
+        k = _rope(k, positions, config.rope_theta)
     return q, k, v
 
 
@@ -343,6 +462,63 @@ def _scannable(config: GPTConfig, blocks, lora_layers) -> bool:
         if any(sig(l) != l0 for l in lora_layers[1:]):
             return False
     return True
+
+
+def _split_by_runs(config: GPTConfig, kind: str, tree):
+    """A tree stacked over all layers of ``kind`` -> one slice a run."""
+    out, off = [], 0
+    for k, _, n in config.layer_runs():
+        if k == kind:
+            out.append(jax.tree_util.tree_map(
+                lambda a, o=off, m=n: a[o:o + m], tree))
+            off += n
+    return out
+
+
+def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
+    """The layer loop of a hybrid stack. Every run of consecutive layers of
+    one kind is one ``lax.scan`` over its stacked weights (a lone layer is
+    called directly), so the program holds one body a run.
+
+    fns[kind](h, blk, x_i, lora_i) -> (h, y_i, aux); xs[kind]: None, or a
+    list with one entry a run of that kind, each a tree stacked over the
+    run's layers. Returns (h, {kind: [ys of each run]}, aux)."""
+    import os
+
+    scan = config.scan_layers and not os.environ.get(
+        "AGILERL_TPU_DISABLE_SCAN_LAYERS")
+    stack = lambda *a: jnp.stack(a)  # noqa: E731
+    seen = {"attn": 0, "mamba": 0}
+    ys = {"attn": [], "mamba": []}
+    aux = jnp.zeros((), jnp.float32)
+    for r, (kind, first, n) in enumerate(config.layer_runs()):
+        w = params["runs"][r]
+        lo = None
+        if lora is not None:
+            lo = jax.tree_util.tree_map(
+                stack, *[lora["blocks"].get(str(i), {})
+                         for i in range(first, first + n)])
+        x_run = None if xs.get(kind) is None else xs[kind][seen[kind]]
+        seen[kind] += 1
+        fn = fns[kind]
+        if n > 1 and scan:
+            def body(carry, x, fn=fn):
+                h, aux = carry
+                hn, y, a = fn(h, *x)
+                return (hn, aux + a), y
+
+            (h, aux), y = jax.lax.scan(body, (h, aux), (w, x_run, lo))
+        else:
+            outs = []
+            for j in range(n):
+                pick = lambda t, j=j: jax.tree_util.tree_map(  # noqa: E731
+                    lambda a: a[j], t)
+                h, y, a = fn(h, pick(w), pick(x_run), pick(lo))
+                aux = aux + a
+                outs.append(y)
+            y = jax.tree_util.tree_map(stack, *outs)
+        ys[kind].append(y)
+    return h, ys, aux
 
 
 def forward(
@@ -480,14 +656,44 @@ def forward(
 
     aux_total = jnp.zeros((), jnp.float32)
     fn = jax.checkpoint(block_fn, static_argnums=()) if config.remat else block_fn
-    blocks = [params["blocks"][str(i)] for i in range(config.n_layer)]
-    lora_layers = [
-        lora["blocks"].get(str(i)) if lora is not None else None
-        for i in range(config.n_layer)
-    ]
     new_caches: Optional[KVCache] = None
     new_k = new_v = None  # [L, B, T, KV, hd] new-token projections
-    if _scannable(config, blocks, lora_layers):
+    new_state = prev_state = None
+    blocks = lora_layers = None
+    if not config.is_hybrid:
+        blocks = [params["blocks"][str(i)] for i in range(config.n_layer)]
+        lora_layers = [
+            lora["blocks"].get(str(i)) if lora is not None else None
+            for i in range(config.n_layer)
+        ]
+    if config.is_hybrid:
+        # two kinds of layer: one scan a run of equal layers (_run_layers)
+        from agilerl_tpu.llm import ssm
+
+        def mamba_fn(h, blk, layer_state, lora_layer):
+            """layer_state: (conv, ssm) of this layer or None."""
+            x = _rms(h, blk["ln1"], config.rms_eps)
+            out, new_s, prev_s = ssm.mixer(
+                config, blk, x, attention_mask, layer_state, lora_layer,
+                lora_scale)
+            h, aux = _block_ffn(config, blk, h + out, lora_layer, lora_scale)
+            return h, (None if layer_state is None else (new_s, prev_s)), aux
+
+        mfn = jax.checkpoint(mamba_fn) if config.remat else mamba_fn
+        xs = {}
+        if cache is not None:
+            xs = {"attn": _split_by_runs(config, "attn", (cache.k, cache.v)),
+                  "mamba": list(cache.state)}
+        h, ys, aux_total = _run_layers(
+            config, params, lora, h, {"attn": fn, "mamba": mfn}, xs)
+        if cache is not None:
+            new_k = jnp.concatenate([y[0] for y in ys["attn"]])
+            new_v = jnp.concatenate([y[1] for y in ys["attn"]])
+            new_state = tuple(y[0] for y in ys["mamba"])
+            # a one-token forward has no "before the last token" of its own
+            prev_state = (tuple(y[1] for y in ys["mamba"]) if T > 1
+                          else cache.prev_state)
+    elif _scannable(config, blocks, lora_layers):
         # one scan over the stacked layer axis — cached (pre-update slabs
         # ride as read-only xs, new tokens come back as small ys) and
         # non-cached alike: compile time is constant in n_layer
@@ -533,6 +739,9 @@ def forward(
             jax.lax.dynamic_update_slice(cache.v, new_v, (0, 0, start, 0, 0)),
             start + T, cache_mask,
         )
+        if config.is_hybrid:
+            new_caches = new_caches._replace(state=new_state,
+                                             prev_state=prev_state)
 
     h = _rms(h, params["ln_f"], config.rms_eps).astype(jnp.float32)
     if return_aux:
@@ -664,6 +873,16 @@ class PagedKVCache(NamedTuple):
 
     k: jax.Array  # [L, n_blocks, block_size, KV, hd]
     v: jax.Array  # [L, n_blocks, block_size, KV, hd]
+    # Hybrid stacks only (k/v then hold the attention layers alone) — the
+    # second cache kind, per SLOT and not per block: a state-space layer's
+    # state is one fixed-size record a sequence, whatever its length.
+    # state: one (conv [n, slots, k-1, d_inner], ssm [n, slots, d_state,
+    # d_inner] f32) a run of state-space layers; snap: the same with
+    # snapshot entries in place of slots (the state BEFORE a prompt's last
+    # token, what a prefix-cache hit resumes from; the last entry is a sink
+    # for prefills whose snapshot nobody keeps)
+    state: Any = None
+    snap: Any = None
 
     @property
     def n_blocks(self) -> int:
@@ -674,11 +893,32 @@ class PagedKVCache(NamedTuple):
         return self.k.shape[2]
 
 
-def init_paged_cache(config: GPTConfig, n_blocks: int, block_size: int) -> PagedKVCache:
-    shape = (config.n_layer, n_blocks, block_size, config.kv_heads,
-             config.head_dim)
+def init_paged_cache(config: GPTConfig, n_blocks: int, block_size: int,
+                     slots: Optional[int] = None,
+                     snapshots: int = 0) -> PagedKVCache:
+    shape = (config.n_layers_of("attn"), n_blocks, block_size,
+             config.kv_heads, config.head_dim)
+    state = snap = None
+    if config.is_hybrid:
+        if slots is None:
+            raise ValueError("a hybrid stack's paged cache holds recurrent "
+                             "state per slot: pass slots=")
+        from agilerl_tpu.llm import ssm
+
+        mamba_runs = [n for kind, _, n in config.layer_runs()
+                      if kind == "mamba"]
+        state = tuple(ssm.init_state(config, n, slots) for n in mamba_runs)
+        snap = tuple(ssm.init_state(config, n, snapshots + 1)
+                     for n in mamba_runs)
     return PagedKVCache(k=jnp.zeros(shape, config.dtype),
-                        v=jnp.zeros(shape, config.dtype))
+                        v=jnp.zeros(shape, config.dtype),
+                        state=state, snap=snap)
+
+
+def state_cache_bytes(cache: PagedKVCache) -> int:
+    """Bytes of the recurrent-state cache, slots and snapshots."""
+    return int(sum(x.size * x.dtype.itemsize for x in
+                   jax.tree_util.tree_leaves((cache.state, cache.snap))))
 
 
 def paged_gather(pool_k: jax.Array, pool_v: jax.Array, block_tables: jax.Array):
@@ -713,16 +953,19 @@ def paged_write_index(block_tables: jax.Array, write_pos: jax.Array,
 
 def paged_scatter_tokens(cache: PagedKVCache, block_tables: jax.Array,
                          write_pos: jax.Array, new_k: jax.Array,
-                         new_v: jax.Array) -> PagedKVCache:
+                         new_v: jax.Array, new_state=None) -> PagedKVCache:
     """ONE bulk write of the step's new tokens into the pool across all
     layers (mirrors forward's single dynamic_update_slice after the layer
-    scan). new_k/new_v: [L, B, KV, hd]; write_pos: [B] logical slot index."""
+    scan). new_k/new_v: [L, B, KV, hd]; write_pos: [B] logical slot index.
+    ``new_state`` (hybrid stacks: forward_paged's third result) replaces the
+    per-slot recurrent state whole — rows are slots."""
     L, nb, bs, KV, hd = cache.k.shape
     idx = paged_write_index(block_tables, write_pos, bs)
     flat_k = cache.k.reshape(L, nb * bs, KV, hd).at[:, idx].set(new_k)
     flat_v = cache.v.reshape(L, nb * bs, KV, hd).at[:, idx].set(new_v)
-    return PagedKVCache(k=flat_k.reshape(L, nb, bs, KV, hd),
-                        v=flat_v.reshape(L, nb, bs, KV, hd))
+    cache = cache._replace(k=flat_k.reshape(L, nb, bs, KV, hd),
+                           v=flat_v.reshape(L, nb, bs, KV, hd))
+    return cache if new_state is None else cache._replace(state=new_state)
 
 
 def paged_scatter_multi(cache: PagedKVCache, block_tables: jax.Array,
@@ -750,8 +993,8 @@ def paged_scatter_multi(cache: PagedKVCache, block_tables: jax.Array,
         new_k.reshape(L, B * T, KV, hd))
     flat_v = cache.v.reshape(L, nb * bs, KV, hd).at[:, idx].set(
         new_v.reshape(L, B * T, KV, hd))
-    return PagedKVCache(k=flat_k.reshape(L, nb, bs, KV, hd),
-                        v=flat_v.reshape(L, nb, bs, KV, hd))
+    return cache._replace(k=flat_k.reshape(L, nb, bs, KV, hd),
+                          v=flat_v.reshape(L, nb, bs, KV, hd))
 
 
 def paged_scatter_prompt(cache: PagedKVCache, block_ids: jax.Array,
@@ -760,18 +1003,38 @@ def paged_scatter_prompt(cache: PagedKVCache, block_ids: jax.Array,
     number of blocks) into its assigned physical blocks ([Pb // bs])."""
     L, _, bs, KV, hd = cache.k.shape
     nb_p = k_prompt.shape[1] // bs
-    return PagedKVCache(
+    return cache._replace(
         k=cache.k.at[:, block_ids].set(k_prompt.reshape(L, nb_p, bs, KV, hd)),
         v=cache.v.at[:, block_ids].set(v_prompt.reshape(L, nb_p, bs, KV, hd)),
     )
 
 
-def paged_copy_block(cache: PagedKVCache, src, dst) -> PagedKVCache:
+def paged_write_state(cache: PagedKVCache, slot, snap, state,
+                      prev_state) -> PagedKVCache:
+    """After a prefill of ONE request (states with a batch of 1): the state
+    after the prompt into slot ``slot``, the state before the prompt's last
+    token into snapshot entry ``snap``."""
+    put = lambda dst, i, src: dst.at[:, i].set(src[:, 0])  # noqa: E731
+    return cache._replace(
+        state=jax.tree_util.tree_map(
+            lambda d, s: put(d, slot, s), cache.state, tuple(state)),
+        snap=jax.tree_util.tree_map(
+            lambda d, s: put(d, snap, s), cache.snap, tuple(prev_state)))
+
+
+def paged_copy_block(cache: PagedKVCache, src, dst, snap=None,
+                     slot=None) -> PagedKVCache:
     """Copy one physical block (prefix-cache hit: the last prompt block is
     duplicated into a private block so the first decode write cannot touch
-    the shared original)."""
-    return PagedKVCache(k=cache.k.at[:, dst].set(cache.k[:, src]),
-                        v=cache.v.at[:, dst].set(cache.v[:, src]))
+    the shared original). With ``snap`` and ``slot`` (hybrid stacks) the
+    same program also restores snapshot entry ``snap`` into slot ``slot``:
+    the recurrent state the re-entering last prompt token starts from."""
+    cache = cache._replace(k=cache.k.at[:, dst].set(cache.k[:, src]),
+                           v=cache.v.at[:, dst].set(cache.v[:, src]))
+    if snap is None:
+        return cache
+    return cache._replace(state=jax.tree_util.tree_map(
+        lambda d, s: d.at[:, slot].set(s[:, snap]), cache.state, cache.snap))
 
 
 def forward_paged(
@@ -789,7 +1052,10 @@ def forward_paged(
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """One decode forward over the slot pool: returns (hidden [B, T, D]
     float32, (new_k, new_v)) — the caller scatters the new KV into the pool
-    (paged_scatter_tokens / paged_scatter_multi) exactly once.
+    (paged_scatter_tokens / paged_scatter_multi) exactly once. Over a hybrid
+    stack the second result has a third member, the new per-slot recurrent
+    state, which ``paged_scatter_tokens(cache, tables, pos, *new)`` puts in
+    place of the old.
 
     Per-slot `write_pos` is what distinguishes this from forward-with-cache:
     continuous batching admits slots at different times, so there is no
@@ -859,6 +1125,40 @@ def forward_paged(
         h = h + attn
         h, _ = _block_ffn(config, blk, h, lora_layer, lora_scale)
         return h, ((k, v) if write_pos.ndim == 2 else (k[:, 0], v[:, 0]))
+
+    if config.is_hybrid:
+        # the second cache kind: rows ARE slots, so a state-space layer
+        # reads and writes its slot's record in place — no table, no gather
+        if T > 1:
+            raise NotImplementedError(
+                "a multi-token paged forward (speculative verify) over a "
+                "hybrid stack needs recurrent-state rollback for rejected "
+                "drafts; not implemented")
+        from agilerl_tpu.llm import ssm
+
+        S = slot_mask.shape[1]
+        tok_mask = jnp.take_along_axis(
+            slot_mask, jnp.minimum(wp_start, S - 1)[:, None], axis=1)
+
+        def attn_fn(h, blk, layer_kv, lora_layer):
+            hn, new_kv = block_fn(h, blk, layer_kv, lora_layer)
+            return hn, new_kv, 0.0
+
+        def mamba_fn(h, blk, layer_state, lora_layer):
+            x = _rms(h, blk["ln1"], config.rms_eps)
+            out, new_s, _ = ssm.mixer(config, blk, x, tok_mask, layer_state,
+                                      lora_layer, lora_scale)
+            h, _ = _block_ffn(config, blk, h + out, lora_layer, lora_scale)
+            return h, new_s, 0.0
+
+        xs = {"attn": _split_by_runs(config, "attn", (cache.k, cache.v)),
+              "mamba": list(cache.state)}
+        h, ys, _ = _run_layers(config, params, lora, h,
+                               {"attn": attn_fn, "mamba": mamba_fn}, xs)
+        h = _rms(h, params["ln_f"], config.rms_eps).astype(jnp.float32)
+        return h, (jnp.concatenate([y[0] for y in ys["attn"]]),
+                   jnp.concatenate([y[1] for y in ys["attn"]]),
+                   tuple(ys["mamba"]))
 
     blocks = [params["blocks"][str(i)] for i in range(config.n_layer)]
     lora_layers = [

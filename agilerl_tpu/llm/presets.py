@@ -44,6 +44,18 @@ _PRESETS: Dict[str, Dict[str, Any]] = {
         d_ff=18_944, max_seq_len=32_768, rope_theta=1_000_000.0,
         tie_embeddings=False, qkv_bias=True,
     ),
+    # AI21-Jamba2-3B: a hybrid stack — 26 Mamba-1 layers (d_inner 5120,
+    # state 16, conv 4, dt rank 160) beside 2 attention layers (layers 7 and
+    # 21: i % 14 == 7; 20 query heads on ONE KV head of 128, no positional
+    # encoding), dense SwiGLU everywhere, head tied to the embedding. The
+    # one source of these sizes for tests, the benchmark's runner and docs
+    # (perfbench/configs/jamba2-3b.json repeats the published keys).
+    "jamba2-3b": dict(
+        vocab_size=65_536, n_layer=28, n_head=20, n_kv_head=1, d_model=2_560,
+        d_ff=8_192, max_seq_len=262_144, tie_embeddings=True, rope=False,
+        attn_layer_period=14, attn_layer_offset=7, mamba_d_state=16,
+        mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=160,
+    ),
 }
 
 

@@ -28,7 +28,7 @@ import dataclasses
 import hashlib
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -504,6 +504,16 @@ class BlockAllocator:
         self._hash_of: Dict[int, bytes] = {}
         # refcount-0 cached blocks in eviction order (oldest first)
         self._lru: "collections.OrderedDict[int, None]" = collections.OrderedDict()
+        #: called with every chain hash that leaves the cache (eviction,
+        #: flush): what else is kept per chain — a hybrid stack's recurrent-
+        #: state snapshot — goes with it
+        self.on_forget: Optional[Callable[[bytes], None]] = None
+
+    def _forget(self, bid: int) -> None:
+        h = self._hash_of.pop(bid)
+        del self._by_hash[h]
+        if self.on_forget is not None:
+            self.on_forget(h)
 
     @property
     def free_blocks(self) -> int:
@@ -527,7 +537,7 @@ class BlockAllocator:
                 out.append(self._free.pop())
             else:
                 bid, _ = self._lru.popitem(last=False)
-                del self._by_hash[self._hash_of.pop(bid)]
+                self._forget(bid)
                 del self._ref[bid]
                 out.append(bid)
         return out
@@ -585,13 +595,74 @@ class BlockAllocator:
         referenced by in-flight slots merely forget their hashes, so no
         future admission can hit them and release_shared frees them."""
         for bid in list(self._lru):
-            del self._by_hash[self._hash_of.pop(bid)]
+            self._forget(bid)
             del self._ref[bid]
             self._free.append(bid)
         self._lru.clear()
-        for bid, h in list(self._hash_of.items()):
-            del self._by_hash[h]
-            del self._hash_of[bid]
+        for bid in list(self._hash_of):
+            self._forget(bid)
+
+
+class StateSnapshots:
+    """Host index of the snapshot entries in a hybrid stack's state cache
+    (``PagedKVCache.snap``): chain hash of a prompt's LAST block -> entry.
+
+    An entry holds the recurrent state from before the prompt's last token,
+    written by the prefill that computed it. A prefix-cache hit needs it as
+    much as it needs the chain's KV blocks: the last prompt token re-enters
+    on the first decode step, and a state-space layer must start that step
+    from where the prompt stood, not from zero. So an entry lives and dies
+    with its chain: dropped when the allocator forgets the hash (eviction,
+    weight-epoch flush), and — there being ``n`` entries only — when a newer
+    prompt needs the room (least recently used first); a chain whose entry
+    is gone admits as a miss. Entry ``n`` is the sink for prefills nobody
+    will hit (prefix cache off)."""
+
+    def __init__(self, n: int, metrics):
+        self.n = int(n)
+        self.metrics = metrics
+        self._by_hash: "collections.OrderedDict[bytes, int]" = (
+            collections.OrderedDict())
+        self._free = list(range(self.n - 1, -1, -1))
+
+    @property
+    def sink(self) -> int:
+        return self.n
+
+    def __len__(self) -> int:
+        return len(self._by_hash)
+
+    def get(self, chain_hash: bytes) -> Optional[int]:
+        entry = self._by_hash.get(chain_hash)
+        if entry is not None:
+            self._by_hash.move_to_end(chain_hash)
+        return entry
+
+    def store(self, chain_hash: bytes) -> int:
+        """The entry a prefill writes this chain's snapshot to."""
+        entry = self.get(chain_hash)
+        if entry is None:
+            if not self._free:
+                self.drop(next(iter(self._by_hash)))
+            entry = self._by_hash[chain_hash] = self._free.pop()
+        self.metrics.counter(
+            "serving/state_snapshots_stored_total",
+            help="recurrent-state snapshots written by prefills").inc()
+        return entry
+
+    def drop(self, chain_hash: bytes) -> None:
+        entry = self._by_hash.pop(chain_hash, None)
+        if entry is not None:
+            self._free.append(entry)
+            self.metrics.counter(
+                "serving/state_snapshot_evictions_total",
+                help="recurrent-state snapshots dropped: their chain was "
+                     "evicted or flushed, or a newer prompt took the "
+                     "entry").inc()
+
+    def clear(self) -> None:
+        for h in list(self._by_hash):
+            self.drop(h)
 
 
 @dataclasses.dataclass
@@ -692,6 +763,20 @@ class ContinuousGenerator:
     ):
         self.config = config
         self.metrics = metrics if metrics is not None else observability.get_registry()
+        if config.is_hybrid:
+            # what this tier does not do for a stack with state-space
+            # layers refuses here, by mechanism, before any program exists
+            if as_spec_config(speculate) is not None:
+                raise ValueError(
+                    "speculative decoding over a hybrid stack needs "
+                    "recurrent-state rollback for rejected drafts (the "
+                    "verify step advances the state past them); not "
+                    "implemented: build the generator with speculate=None")
+            if sharding_plan is not None or mesh is not None:
+                raise ValueError(
+                    "a serving plan has no rule for a hybrid stack's "
+                    "recurrent-state cache (per-slot conv and SSM state "
+                    "beside the paged pool); not implemented on a mesh")
         self._tracer = tracer
         # declarative serving layout: the paged pool is placed by the plan's
         # "kv" rules at allocation (kv-heads over tp; the pool has no batch
@@ -753,6 +838,10 @@ class ContinuousGenerator:
         #: flywheel's record — saves RolloutPod the extra behavior_logprobs
         #: forward; see result_logprobs / generate()'s info["logprobs"])
         self.capture_logprobs = bool(capture_logprobs)
+        #: snapshot entries of a hybrid stack's state cache, one a slot; None
+        #: for a stack without state-space layers
+        self._snapshots = (StateSnapshots(self.slots, self.metrics)
+                           if config.is_hybrid else None)
         self._proposer = (NgramProposer(self.speculate)
                           if self.speculate is not None else None)
         self._completions = (
@@ -837,6 +926,8 @@ class ContinuousGenerator:
         self._submit_lock = threading.Lock()
         self._last_shed_span_s = float("-inf")  # shed-span 1/s throttle
         self.allocator = BlockAllocator(self.n_blocks)
+        if self._snapshots is not None:
+            self.allocator.on_forget = self._snapshots.drop
         self._queue: "collections.deque[_Request]" = collections.deque()
         # shed decisions use a ROLLING window of recent TTFTs, not the
         # lifetime histogram — a cold-compile outlier in a cumulative p95
@@ -867,6 +958,8 @@ class ContinuousGenerator:
         self._slot_follow: List[Optional[np.ndarray]] = [None] * self.slots
         # decode-captured logprob results, keyed like _results
         self._result_lps: Dict[int, np.ndarray] = {}
+        # whether the finished request was admitted by a prefix-cache hit
+        self._result_hits: Dict[int, bool] = {}
         # strong refs to the last-served weight trees: cached prompt KV is
         # only valid for the weights that prefilled it
         self._weights: Optional[Tuple[Any, Any]] = None
@@ -881,27 +974,36 @@ class ContinuousGenerator:
         return _sampling_knobs(self, greedy, lora)
 
     def _prefill_admit_impl(self, params, lora, prompt, prompt_mask, key,
-                            cache, block_ids, greedy=False):
+                            cache, block_ids, greedy=False, state_ids=None):
         """Prefill ONE request at its prompt bucket (the SHARED prefill_head
         — dense-parity maths) and scatter its prompt KV into the assigned
-        physical blocks. Compiles once per (prompt bucket, greedy)."""
+        physical blocks. Compiles once per (prompt bucket, greedy).
+        ``state_ids`` (hybrid stacks: int32 [slot, snapshot entry]) says
+        where the recurrent state after the prompt and the one before its
+        last token go."""
         Pb = prompt.shape[1]
+        # keep_prev_state: a hybrid stack's snapshot (below); nothing to
+        # keep, and the same program, for a stack without recurrent state
         # dense-parity extent: the same Pb + chunks*chunk the bucketed/dense
         # paths allocate, so chunked-attention chunking is identical
         dense = M.init_caches(self.config, 1, Pb + self._decode_extent)
         if self.capture_logprobs:
             carry, (tok0, _emit0), last_logits = prefill_head(
                 self.config, params, prompt, prompt_mask, dense, key,
-                return_logits=True, **self._knobs(greedy, lora),
+                return_logits=True, keep_prev_state=True,
+                **self._knobs(greedy, lora),
             )
         else:
             carry, (tok0, _emit0) = prefill_head(
                 self.config, params, prompt, prompt_mask, dense, key,
-                **self._knobs(greedy, lora),
+                keep_prev_state=True, **self._knobs(greedy, lora),
             )
         filled, _tok0, _rv, pos, done0, key_next = carry
         cache = M.paged_scatter_prompt(
             cache, block_ids, filled.k[:, 0, :Pb], filled.v[:, 0, :Pb])
+        if self.config.is_hybrid:
+            cache = M.paged_write_state(cache, state_ids[0], state_ids[1],
+                                        filled.state, filled.prev_state)
         if self.capture_logprobs:
             # raw log p(tok0) — the token_logprobs convention the flywheel's
             # behavior-logprob record uses (temperature 1.0, no EOS floor)
@@ -1099,6 +1201,11 @@ class ContinuousGenerator:
         TTFT includes prefill + transfer latency. Decode-side admission
         control (free-block watermark, queue, TTFT SLO) applies unless
         ``no_shed``."""
+        if self.config.is_hybrid:
+            raise NotImplementedError(
+                "submit_prefilled over a hybrid stack: the prefill worker's "
+                "export carries prompt KV only, not the recurrent state of "
+                "the state-space layers (nor its snapshot); not implemented")
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         if key is None:
             # the raw request key is load-bearing: a prefix-cache HIT on an
@@ -1200,8 +1307,17 @@ class ContinuousGenerator:
 
     def _ensure_pool(self) -> None:
         if self._pool is None:
-            pool = M.init_paged_cache(
-                self.config, self.n_blocks, self.block_size)
+            if self._snapshots is None:
+                pool = M.init_paged_cache(
+                    self.config, self.n_blocks, self.block_size)
+            else:  # both cache kinds in one object, one owner
+                pool = M.init_paged_cache(
+                    self.config, self.n_blocks, self.block_size,
+                    slots=self.slots, snapshots=self._snapshots.n)
+                self.metrics.gauge(
+                    "serving/state_cache_bytes",
+                    help="recurrent-state cache, slots and snapshots",
+                ).set(M.state_cache_bytes(pool))
             if self.sharding_plan is not None:
                 # kv_paged, NOT kv: the pool's axis 1 is global block ids —
                 # the dense rules' (dp,fsdp) batch entry must never touch it
@@ -1285,7 +1401,9 @@ class ContinuousGenerator:
                     a((1, Pb), jnp.int32), a((1, Pb), jnp.int32),
                     a((2,), jnp.uint32), pool_abs,
                     a((Pb // self.block_size,), jnp.int32),
-                    only_cached=only_cached, greedy=g))
+                    only_cached=only_cached, greedy=g,
+                    **({"state_ids": a((2,), jnp.int32)}
+                       if self._snapshots is not None else {})))
         return infos
 
     def _chain_hashes(self, toks_row: np.ndarray,
@@ -1321,6 +1439,15 @@ class ContinuousGenerator:
                 req.hashes = self._chain_hashes(toks_row, mask_row)
             shared = (self.allocator.lookup_chain(req.hashes)
                       if self.prefix_cache else None)
+            snap = None
+            if shared is not None and self._snapshots is not None:
+                # a hybrid stack's hit needs the chain's state snapshot too;
+                # where it is gone (a newer prompt took the entry) the
+                # request prefills again
+                snap = self._snapshots.get(req.hashes[-1])
+                if snap is None:
+                    self.allocator.release_shared(shared)
+                    shared = None
             if shared is not None:
                 private = self.allocator.alloc(1 + n_dec)
                 if private is None:
@@ -1365,8 +1492,22 @@ class ContinuousGenerator:
                 req.prefix_hit = True
                 self.metrics.counter("serving/prefix_cache_hits_total").inc()
                 copy_dst = private[0]
-                self._pool = self._copy_block(
-                    self._pool, jnp.int32(shared[-1]), jnp.int32(copy_dst))
+                if snap is None:
+                    self._pool = self._copy_block(
+                        self._pool, jnp.int32(shared[-1]),
+                        jnp.int32(copy_dst))
+                else:
+                    # one program: the private copy of the last KV block AND
+                    # the state from before the re-entering last token
+                    with PhaseTimer(self.metrics, "sched/state_restore"):
+                        self._pool = self._copy_block(
+                            self._pool, jnp.int32(shared[-1]),
+                            jnp.int32(copy_dst), jnp.int32(snap),
+                            jnp.int32(slot))
+                    self.metrics.counter(
+                        "serving/state_snapshot_restores_total",
+                        help="prefix-cache hits that restored a recurrent-"
+                             "state snapshot into their slot").inc()
                 table[:nb_p - 1] = shared[:-1]
                 table[nb_p - 1] = copy_dst
                 table[nb_p:nb_p + n_dec] = private[1:]
@@ -1395,12 +1536,18 @@ class ContinuousGenerator:
             else:
                 self.metrics.counter("serving/prefix_cache_misses_total").inc()
                 prompt_blocks, dec_blocks = private[:nb_p], private[nb_p:]
+                state_ids = {}
+                if self._snapshots is not None:
+                    entry = (self._snapshots.store(req.hashes[-1])
+                             if self.prefix_cache else self._snapshots.sink)
+                    state_ids = dict(state_ids=jnp.asarray(
+                        np.asarray([slot, entry], np.int32)))
                 out = self._prefill(
                     params, lora, jnp.asarray(toks_row[None]),
                     jnp.asarray(mask_row[None]), jnp.asarray(req.key),
                     self._pool, jnp.asarray(np.asarray(prompt_blocks,
                                                        np.int32)),
-                    greedy=greedy,
+                    greedy=greedy, **state_ids,
                 )
                 if self.capture_logprobs:
                     self._pool, tok0, _pos0, done0, key_next, lp0 = out
@@ -1605,6 +1752,7 @@ class ContinuousGenerator:
         # masked positions are pad (the dense path's post-EOS convention)
         toks = np.where(emits.astype(bool), toks, self.pad_id).astype(np.int32)
         self._results[req.ticket] = (toks, emits)
+        self._result_hits[req.ticket] = req.prefix_hit
         if self.capture_logprobs:
             lps = (np.concatenate(req.lps) if req.lps
                    else np.zeros(0, np.float32))
@@ -1668,6 +1816,11 @@ class ContinuousGenerator:
                 self.metrics.counter(
                     "serving/prefix_cache_invalidations_total",
                     help="prefix-cache flushes on weight updates").inc()
+            if self._snapshots is not None:
+                # state snapshots are a function of the weights like the KV
+                # blocks: the flush above dropped each with its chain; none
+                # may outlive the epoch whatever the allocator still knew
+                self._snapshots.clear()
             stale = 0
             # snapshot: submit() may append from a request thread while
             # the scheduler thread scans (in-place req mutation is fine,
@@ -1887,6 +2040,7 @@ class ContinuousGenerator:
     def result(self, ticket: int) -> Tuple[np.ndarray, np.ndarray]:
         """(tokens [max_new], emit mask [max_new]) for a finished ticket
         (pops it)."""
+        self._result_hits.pop(ticket, None)
         return self._results.pop(ticket)
 
     def result_logprobs(self, ticket: int) -> Optional[np.ndarray]:
@@ -1944,6 +2098,7 @@ class ContinuousGenerator:
             cmask = np.zeros((B, N), np.int32)
             lps = (np.zeros((B, N), np.float32) if self.capture_logprobs
                    else None)
+            hit_rows = [self._result_hits.get(t, False) for t in tickets]
             for i, t in enumerate(tickets):
                 toks, emits = self.result(t)
                 comp[i, :toks.size] = toks
@@ -1962,8 +2117,9 @@ class ContinuousGenerator:
                 "max_new_tokens": N,
             }
             self.metrics.emit("serving", rows=B, **info)
+        # after emit(): telemetry lines carry scalars, not per-row arrays
+        info["prefix_hit_rows"] = hit_rows
         if lps is not None:
-            # after emit(): telemetry lines carry scalars, not [B, N] arrays
             info["logprobs"] = lps
         return comp, cmask, info
 

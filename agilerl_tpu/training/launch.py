@@ -220,7 +220,13 @@ class PodLauncher:
         return summary
 
     def shutdown(self, grace_s: Optional[float] = None) -> Dict[str, Any]:
-        return self.supervisor.shutdown(grace_s)
+        try:
+            return self.supervisor.shutdown(grace_s)
+        finally:
+            # start() installed the guard: its handlers end with the fleet,
+            # or the process keeps answering signals for a launcher that is
+            # gone (``guard.requested`` stays readable)
+            self.guard.uninstall()
 
     def statuses(self) -> Dict[str, Dict[str, Any]]:
         return read_statuses(self.root)

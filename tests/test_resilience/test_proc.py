@@ -266,9 +266,12 @@ def test_launcher_sigterm_drains_fleet_real_subprocesses(tmp_path):
         launcher.add_role(name, "agilerl_tpu.training.launch:idle_role",
                           kwargs={"max_ticks": None}, poll_interval=0.02,
                           env=dict(_ENV))
+    handlers = [signal.getsignal(s) for s in launcher.guard.signals]
     launcher.start()
     pids = {n: p.pid for n, p in launcher.supervisor.procs.items()}
     summary = launcher.shutdown()
+    # the launcher's signal handlers end with its fleet
+    assert [signal.getsignal(s) for s in launcher.guard.signals] == handlers
 
     assert summary["exits"] == {"alpha": EXIT_PREEMPTED,
                                 "beta": EXIT_PREEMPTED}
