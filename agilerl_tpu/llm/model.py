@@ -1225,9 +1225,14 @@ def token_logprobs(
     if use_pallas:
         from agilerl_tpu.ops.fused_loss import fused_token_logprob_diff
 
-        head = params["tok_emb"].T if config.tie_embeddings else params["lm_head"]
+        # operands in the configuration's compute dtype, like every other
+        # matmul of the step (the kernel keeps its softmax statistics and
+        # accumulators in f32); cast here, before the shard_map, so that a
+        # replicated head is gathered at the narrow width
+        head = (params["tok_emb"].T if config.tie_embeddings
+                else params["lm_head"]).astype(config.dtype)
         B, T, D = hidden.shape
-        flat_h = hidden[:, :-1].reshape(-1, D)
+        flat_h = hidden[:, :-1].reshape(-1, D).astype(config.dtype)
         flat_t = tokens[:, 1:].reshape(-1)
         smesh = _shard_mesh(getattr(config, "fused_loss_shard_axes", None))
         bspec = (_axes_in_mesh(config.fused_loss_shard_axes, smesh)

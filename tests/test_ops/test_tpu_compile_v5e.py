@@ -10,6 +10,7 @@ Skipped where libtpu cannot describe the topology.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +21,10 @@ from agilerl_tpu.llm import model as M
 from agilerl_tpu.llm.presets import preset
 from agilerl_tpu.llm.serving import ContinuousGenerator
 from agilerl_tpu.ops.flash_attention_vjp import flash_attention_diff
-from agilerl_tpu.ops.fused_loss import fused_token_logprob_diff
+from agilerl_tpu.ops.fused_loss import (
+    fused_loss_plan,
+    fused_token_logprob_diff,
+)
 from agilerl_tpu.ops.kernel_mode import native_kernels
 
 KERNEL = "tpu_custom_call"
@@ -61,19 +65,44 @@ def _compiled_text(fn, *args, **kwargs) -> str:
         return fn.lower(*args, **kwargs).compile().as_text()
 
 
-def test_fused_loss_fwd_and_grad_at_qwen2_7b_head(v5e):
-    # the learn step's lm head: f32 hidden x f32 untied head, 152064 wide
-    N, D, V = 4096, 3584, 152064
+@pytest.mark.parametrize("head_grad", [False, True],
+                         ids=["frozen_head", "trained_head"])
+@pytest.mark.parametrize("N,dtype", [
+    (8192, jnp.bfloat16),  # grpo_k4_reason: 8 x 1024 positions, bf16 compute
+    (9216, jnp.bfloat16),  # grpo_k4_longprompt: 8 x 1152
+    (4096, jnp.float32),   # a float32 configuration; a chip's rows of fsdp-4
+], ids=["8192-bf16", "9216-bf16", "4096-f32"])
+def test_fused_loss_fwd_and_grad_at_qwen2_7b_head(v5e, N, dtype, head_grad):
+    # the learn step's lm head as token_logprobs hands it over: hidden and
+    # the untied 152064-wide head in the configuration's compute dtype
+    D, V = 3584, 152064
     s = jax.ShapeDtypeStruct
-    h, w, t = _on(v5e, (s((N, D), jnp.float32), s((D, V), jnp.float32),
+    h, w, t = _on(v5e, (s((N, D), dtype), s((D, V), dtype),
                         s((N,), jnp.int32)))
 
     def loss(hh, ww, tt):
         return fused_token_logprob_diff(hh, ww, tt, 1.0).sum()
 
-    text = _compiled_text(jax.jit(jax.value_and_grad(loss, argnums=(0, 1))),
+    argnums = (0, 1) if head_grad else 0
+    text = _compiled_text(jax.jit(jax.value_and_grad(loss, argnums=argnums)),
                           h, w, t)
-    assert text.count(KERNEL) >= 3  # forward, dH, dW
+    # forward, dH, and dW only for a caller that trains the head: XLA drops
+    # the kernel of a cotangent nobody asks for
+    kinds = ("fwd", "dh", "dw") if head_grad else ("fwd", "dh")
+    assert text.count(KERNEL) == len(kinds)
+    for kind in kinds:
+        assert f"fused_loss_{kind}" in text
+        plan = fused_loss_plan(N, D, V, dtype, dtype, kind)
+        print(f"\n  {kind}: {plan.block_n} x {plan.block_v}, head read "
+              f"{plan.head_reads}x, {plan.hbm_bytes / 1e9:.2f} GB through HBM,"
+              f" {plan.vmem_bytes / 2**20:.1f} MiB of VMEM a grid step "
+              f"(limit asked {plan.vmem_limit_bytes / 2**20:.0f} MiB)", end="")
+    # the head goes to the kernels as it is: no padded width anywhere, and
+    # no operation that pads or copies an array of the head's shape
+    assert set(re.findall(r"\[3584,(\d+)\]", text)) == {str(V)}
+    moved = [line for line in text.splitlines()
+             if re.search(r"= \w+\[3584,152064\]\S* (pad|copy)\(", line)]
+    assert not moved, moved
 
 
 @pytest.mark.parametrize("spmd", [False, True])
