@@ -16,7 +16,6 @@ TPU-first deltas vs the reference:
 from __future__ import annotations
 
 import functools
-import os
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -187,29 +186,17 @@ class GRPO(EvolvableAlgorithm):
         # — requires to_mesh() with a mesh containing the axis before learn()
         self.sequence_parallel_axis = sequence_parallel_axis
         # ragged generation with a bounded compile set (llm/serving.py — the
-        # vLLM continuous-batching role); kill switch for exact-RNG parity
-        # with the dense path
-        # AGILERL_TPU_DISABLE_BUCKETED_DECODE is the serving-tier kill
-        # switch (exact-RNG parity with the dense path): it disables BOTH
-        # serving routes. The two flags are otherwise independent —
+        # vLLM continuous-batching role); False = the dense generate, with
+        # its exact RNG stream. The two flags are independent —
         # bucketed_decode=False with continuous_decode=True is a valid
         # continuous-only configuration.
-        serving_killed = os.environ.get(
-            "AGILERL_TPU_DISABLE_BUCKETED_DECODE", ""
-        ).strip().lower() in ("1", "true", "yes")
-        self.bucketed_decode = bool(bucketed_decode) and not serving_killed
+        self.bucketed_decode = bool(bucketed_decode)
         # OPT-IN: rollouts through the continuous/paged serving tier
         # (llm/serving.ContinuousGenerator). Wins when prompts within a
         # learn batch are ragged in OUTPUT length (slots recycle per chunk
         # instead of the whole batch draining together) and group_size
         # repeats hit the prefix cache (one prefill per unique prompt).
-        # Env opt-in AGILERL_TPU_CONTINUOUS_DECODE=1 mirrors the kill-switch
-        # convention in the other direction.
-        self.continuous_decode = (
-            bool(continuous_decode) or os.environ.get(
-                "AGILERL_TPU_CONTINUOUS_DECODE", ""
-            ).strip().lower() in ("1", "true", "yes")
-        ) and not serving_killed
+        self.continuous_decode = bool(continuous_decode)
         # continuous-tier extras (NOT part of _serving_knobs: the bucketed
         # generator takes neither, and attach_rollout_fleet's recipe check
         # compares the SAMPLING contract — speculation never changes the
@@ -390,11 +377,10 @@ class GRPO(EvolvableAlgorithm):
         through llm/serving.BucketedGenerator: compile count is bounded by
         the bucket grid instead of one program per (B, P), and decode stops
         within one chunk of every row hitting EOS (the vLLM continuous-
-        batching role). With ``continuous_decode`` (opt-in, or env
-        AGILERL_TPU_CONTINUOUS_DECODE=1), rollouts route through the paged
-        continuous scheduler instead: short completions free their slot for
-        queued rows per chunk, and group_size repeats of a prompt prefill
-        once via the prefix cache (docs/serving.md). Telemetry lands in
+        batching role). With ``continuous_decode`` (opt-in), rollouts route
+        through the paged continuous scheduler instead: short completions
+        free their slot for queued rows per chunk, and group_size repeats of
+        a prompt prefill once via the prefix cache (docs/serving.md). Telemetry lands in
         ``last_generation_info``."""
         self._rollouts += 1
         with PhaseTimer(get_registry(), "grpo/get_action",

@@ -4,7 +4,7 @@ traffic would (docs/serving.md — the traffic-harness workflow).
 The package half of the repo's benchmarking surface: ``benchmarking/`` at
 the repo root holds standalone scripts (training harnesses, AOT sweeps);
 importable harness *libraries* live here so they are graftcheck-scanned,
-unit-tested, and reusable from ``bench.py``, tests, and the
+unit-tested, and reusable from tests and the
 PBT-over-serving-policies work (ROADMAP item 4)."""
 
 from agilerl_tpu.benchmarking.traffic import (

@@ -148,7 +148,7 @@ def rollout_scan(
 
     policy_fn(params, obs_batch, key) -> actions. Returns (trajectory dict with
     leaves [T, N, ...], final carry). This is the zero-host-sync path used by
-    bench.py and the pure-device training loops.
+    the pure-device training loops.
     """
     vec_step = make_autoreset_step(env)
     reset = jax.vmap(env.reset_fn)

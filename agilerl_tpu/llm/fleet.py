@@ -1298,7 +1298,7 @@ class ServingFleet:
     def open_requests(self) -> int:
         """Fleet tickets submitted but not yet finished (queued, prefilling,
         in transfer, decoding, or parked) — the load-generator drain signal
-        (``benchmarking/traffic.py``)."""
+        (``agilerl_tpu/benchmarking/traffic.py``)."""
         return int(self._open)
 
     @property

@@ -23,7 +23,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from agilerl_tpu.llm.model import (
-    GPTConfig, _maybe_lora, _rms, _rope, _scannable, logits_fn,
+    GPTConfig, _maybe_lora, _rms, _rope, _run_layers, logits_fn,
 )
 from agilerl_tpu.ops.ring_attention import ring_attention
 
@@ -77,36 +77,15 @@ def _forward_local(config: GPTConfig, params, tokens, lora, lora_scale, axis_nam
     sp_idx = lax.axis_index(axis_name)
     positions = sp_idx * T + jnp.arange(T)[None, :] * jnp.ones((B, 1), jnp.int32)
     h = jnp.take(params["tok_emb"], tokens, axis=0).astype(config.dtype)
-    blocks = [params["blocks"][str(i)] for i in range(config.n_layer)]
-    lora_layers = [
-        lora["blocks"].get(str(i)) if lora is not None else None
-        for i in range(config.n_layer)
-    ]
-    if _scannable(config, blocks, lora_layers):
-        # same depth-independent-compile design as model.forward: one scan
-        # over stacked blocks; ring attention's ppermute collectives are
-        # legal inside a scan body under shard_map
-        stack = lambda *xs: jnp.stack(xs)  # noqa: E731
-        stacked = jax.tree_util.tree_map(stack, *blocks)
-        if lora is not None:
-            xs = (stacked, jax.tree_util.tree_map(stack, *lora_layers))
 
-            def body(h, x):
-                return _block_sp(config, x[0], x[1], h, positions,
-                                 axis_name, lora_scale), None
+    # model.forward's layer loop (one scan a run of equal layers); ring
+    # attention's ppermute collectives are legal inside a scan body under
+    # shard_map
+    def block_fn(h, blk, _, lora_layer):
+        return _block_sp(config, blk, lora_layer, h, positions, axis_name,
+                         lora_scale), None, 0.0
 
-        else:
-            xs = stacked
-
-            def body(h, blk):
-                return _block_sp(config, blk, None, h, positions,
-                                 axis_name, lora_scale), None
-
-        h, _ = lax.scan(body, h, xs)
-    else:
-        for i in range(config.n_layer):
-            h = _block_sp(config, blocks[i], lora_layers[i], h, positions,
-                          axis_name, lora_scale)
+    h, _, _ = _run_layers(config, params, lora, h, {"attn": block_fn}, {})
     return _rms(h, params["ln_f"], config.rms_eps).astype(jnp.float32)
 
 

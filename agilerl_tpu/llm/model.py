@@ -26,17 +26,6 @@ import numpy as np
 Params = Any
 
 
-def use_chunked_decode() -> bool:
-    """Gate for the flash-decode cached-attention path (default ON).
-
-    AGILERL_TPU_DISABLE_CHUNKED_DECODE=1 falls back to dense-over-full-cache
-    XLA attention — the numerically-equivalent bisect path, mirroring the
-    AGILERL_TPU_DISABLE_PALLAS convention."""
-    import os
-
-    return not os.environ.get("AGILERL_TPU_DISABLE_CHUNKED_DECODE")
-
-
 @dataclasses.dataclass(frozen=True)
 class GPTConfig:
     vocab_size: int
@@ -52,15 +41,14 @@ class GPTConfig:
     rms_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     remat: bool = False  # jax.checkpoint each block (HBM <-> FLOPs trade)
-    # Roll the layer stack into ONE lax.scan on every path — training/logprob
-    # AND the KV-cached prefill/decode paths (the cache stacks all layers on
-    # a leading axis, so per-layer k/v ride as scan xs/ys): HLO size and
-    # XLA:TPU compile time become ~constant in n_layer instead of linear
-    # (the first live-chip window measured the unrolled 12-layer GRPO
-    # learn-step compile at >15 min against 35s for the rest of the program
-    # set). Layers must be structurally uniform — interleaved dense/MoE
-    # stacks (moe_every > 1) fall back to the unrolled loop automatically.
-    # Kill switch: AGILERL_TPU_DISABLE_SCAN_LAYERS=1.
+    # Roll every run of structurally equal layers into ONE lax.scan on every
+    # path — training/logprob AND the KV-cached prefill/decode paths (the
+    # cache stacks all layers on a leading axis, so per-layer k/v ride as
+    # scan xs/ys): HLO size and XLA:TPU compile time become ~constant in
+    # n_layer instead of linear (the first live-chip window measured the
+    # unrolled 12-layer GRPO learn-step compile at >15 min against 35s for
+    # the rest of the program set). False calls every layer directly: the
+    # reference the tests hold the scan to.
     scan_layers: bool = True
     use_flash_attention: bool = False  # Pallas kernel on the non-cached path
     # ((batch axes...), (head axes...)) mesh-axis names: wrap the flash
@@ -90,9 +78,9 @@ class GPTConfig:
     # Hybrid stack: layer i is an ATTENTION layer iff
     # i % attn_layer_period == attn_layer_offset, every other layer a
     # state-space (Mamba-1) layer (llm/ssm.py). Period 1 = attention
-    # everywhere, today's uniform stack. Consecutive layers of one kind run
-    # as one lax.scan over stacked weights (_run_layers), so a program holds
-    # one body a RUN of layers, not one a layer.
+    # everywhere, today's uniform stack. Consecutive equal layers run as one
+    # lax.scan over stacked weights (_run_layers), so a program holds one
+    # body a RUN of layers, not one a layer.
     attn_layer_period: int = 1
     attn_layer_offset: int = 0
     mamba_d_state: int = 16
@@ -114,14 +102,18 @@ class GPTConfig:
 
     def layer_runs(self):
         """[(kind, first layer, number of layers)]: the maximal runs of
-        consecutive layers of one kind."""
-        runs = []
+        consecutive structurally equal layers — one kind of mixer AND one
+        kind of FFN, so an interleaved dense / expert stack (moe_every > 1)
+        is runs of one layer."""
+        runs, last = [], None
         for i in range(self.n_layer):
             kind = self.layer_kind(i)
-            if runs and runs[-1][0] == kind:
+            structure = (kind, self.is_moe_layer(i))
+            if structure == last:
                 runs[-1][2] += 1
             else:
                 runs.append([kind, i, 1])
+            last = structure
         return [tuple(r) for r in runs]
 
     def n_layers_of(self, kind: str) -> int:
@@ -436,34 +428,6 @@ def _block_ffn(config: GPTConfig, blk, h, lora_layer, lora_scale):
     return h + down, jnp.zeros((), jnp.float32)
 
 
-def _scannable(config: GPTConfig, blocks, lora_layers) -> bool:
-    """True when the layer stack can roll into one lax.scan: scan_layers
-    enabled, >1 layer, and every block (and LoRA layer, if any) structurally
-    identical with identical leaf shapes/dtypes. Mixed dense/MoE stacks
-    (moe_every > 1) fail the uniformity check and unroll."""
-    import os
-
-    if not config.scan_layers or config.n_layer <= 1:
-        return False
-    if os.environ.get("AGILERL_TPU_DISABLE_SCAN_LAYERS"):
-        return False
-
-    def sig(tree):
-        leaves, treedef = jax.tree_util.tree_flatten(tree)
-        return treedef, tuple((x.shape, x.dtype) for x in leaves)
-
-    s0 = sig(blocks[0])
-    if any(sig(b) != s0 for b in blocks[1:]):
-        return False
-    if any(l is not None for l in lora_layers):
-        if any(l is None for l in lora_layers):
-            return False
-        l0 = sig(lora_layers[0])
-        if any(sig(l) != l0 for l in lora_layers[1:]):
-            return False
-    return True
-
-
 def _split_by_runs(config: GPTConfig, kind: str, tree):
     """A tree stacked over all layers of ``kind`` -> one slice a run."""
     out, off = [], 0
@@ -475,33 +439,64 @@ def _split_by_runs(config: GPTConfig, kind: str, tree):
     return out
 
 
+def _join_runs(trees):
+    """The inverse of _split_by_runs: one tree stacked over all layers."""
+    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *trees)
+
+
+def _same_structure(trees) -> bool:
+    def sig(tree):
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        return treedef, tuple((x.shape, x.dtype) for x in leaves)
+
+    first = sig(trees[0])
+    return all(sig(t) == first for t in trees[1:])
+
+
 def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
-    """The layer loop of a hybrid stack. Every run of consecutive layers of
-    one kind is one ``lax.scan`` over its stacked weights (a lone layer is
-    called directly), so the program holds one body a run.
+    """THE layer loop, of every kind of stack. It walks
+    ``config.layer_runs()``: a run of structurally equal layers is one
+    ``lax.scan`` over its stacked weights, so the program holds one body a
+    run — a uniform stack is one run. A run of one, a run whose adapters
+    differ from layer to layer, and ``scan_layers=False`` call the layers
+    directly. Where the weights come from is the one thing that differs
+    between stacks: a hybrid stack stores them stacked a run
+    (``params["runs"][r]``), any other one tree a layer
+    (``params["blocks"][str(i)]``), stacked here inside the program.
 
     fns[kind](h, blk, x_i, lora_i) -> (h, y_i, aux); xs[kind]: None, or a
     list with one entry a run of that kind, each a tree stacked over the
     run's layers. Returns (h, {kind: [ys of each run]}, aux)."""
-    import os
-
-    scan = config.scan_layers and not os.environ.get(
-        "AGILERL_TPU_DISABLE_SCAN_LAYERS")
     stack = lambda *a: jnp.stack(a)  # noqa: E731
-    seen = {"attn": 0, "mamba": 0}
-    ys = {"attn": [], "mamba": []}
+
+    def layer(t, j):
+        """Layer j of a run's tree, stored one tree a layer or stacked."""
+        if isinstance(t, list):
+            return t[j]
+        return jax.tree_util.tree_map(lambda a: a[j], t)
+
+    seen = {kind: 0 for kind in fns}
+    ys = {kind: [] for kind in fns}
     aux = jnp.zeros((), jnp.float32)
     for r, (kind, first, n) in enumerate(config.layer_runs()):
-        w = params["runs"][r]
-        lo = None
+        layers = range(first, first + n)
+        w = (params["runs"][r] if "runs" in params
+             else [params["blocks"][str(i)] for i in layers])
+        lo = [None] * n
         if lora is not None:
-            lo = jax.tree_util.tree_map(
-                stack, *[lora["blocks"].get(str(i), {})
-                         for i in range(first, first + n)])
+            lo = [lora["blocks"].get(str(i)) for i in layers]
         x_run = None if xs.get(kind) is None else xs[kind][seen[kind]]
         seen[kind] += 1
         fn = fns[kind]
-        if n > 1 and scan:
+        uniform_lora = _same_structure(lo)
+        scan = n > 1 and config.scan_layers and uniform_lora
+        if scan and isinstance(w, list):
+            w = jax.tree_util.tree_map(stack, *w)
+        if uniform_lora:
+            # also where the run is not scanned: layer() then picks rows,
+            # which is what a hybrid stack's runs of one have always lowered
+            lo = jax.tree_util.tree_map(stack, *lo)
+        if scan:
             def body(carry, x, fn=fn):
                 h, aux = carry
                 hn, y, a = fn(h, *x)
@@ -511,9 +506,7 @@ def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
         else:
             outs = []
             for j in range(n):
-                pick = lambda t, j=j: jax.tree_util.tree_map(  # noqa: E731
-                    lambda a: a[j], t)
-                h, y, a = fn(h, pick(w), pick(x_run), pick(lo))
+                h, y, a = fn(h, layer(w, j), layer(x_run, j), layer(lo, j))
                 aux = aux + a
                 outs.append(y)
             y = jax.tree_util.tree_map(stack, *outs)
@@ -531,8 +524,6 @@ def forward(
     lora: Optional[Params] = None,
     lora_scale: float = 2.0,
     flash: Optional[bool] = None,  # override config.use_flash_attention
-    # (the Pallas kernel is forward-only: keep flash OFF inside loss grads
-    # until the custom-VJP lands; no-grad logprob/generate paths may enable it)
     return_aux: bool = False,  # also return the MoE router load-balance loss
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Returns (hidden [B, T, D] float32, new cache). With a cache, tokens are
@@ -547,7 +538,6 @@ def forward(
         positions = jnp.maximum(positions, 0)
 
     use_flash = config.use_flash_attention if flash is None else flash
-    chunked_decode = use_chunked_decode()  # read once: trace-time constant
     h = jnp.take(params["tok_emb"], tokens, axis=0).astype(dtype)
 
     # length/mask are layer-invariant: computed ONCE for the whole stack
@@ -568,7 +558,7 @@ def forward(
             # layer_kv = this layer's PRE-update (k_slab, v_slab). Attention
             # sees the locally-updated slab; the function returns only the
             # NEW tokens' post-rope projections — the caller bulk-writes
-            # them into the stacked cache ONCE after the layer loop/scan
+            # them into the stacked cache ONCE after the layer loop
             # (returning full updated slabs as scan ys forced a cache-sized
             # copy per step: +11 GiB temp at 7B decode-chunk dims, and a
             # cache-as-carry variant made XLA double-buffer the carry).
@@ -577,43 +567,29 @@ def forward(
             cv = jax.lax.dynamic_update_slice(
                 layer_kv[1], v, (0, start, 0, 0))
             new_kv = (k, v)
-            cm = cache_mask
-            if not chunked_decode:
-                k_all, v_all = ck, cv
-                S = ck.shape[1]
-                kv_slot = jnp.arange(S)
-                # slot j visible to query t iff j <= start+t AND slot is real
-                causal = (
-                    kv_slot[None, None, :] <= (start + jnp.arange(T))[None, :, None]
-                )
-                mask = jnp.logical_and(causal, cm[:, None, :].astype(bool))
-        else:
-            new_kv = None
-            k_all, v_all = k, v
-            # causal within the block + padding mask
-            t_ids = jnp.arange(T)
-            mask = (t_ids[None, None, :] <= t_ids[None, :, None])  # [1, T, S=T]
-            mask = jnp.logical_and(mask, attention_mask[:, None, :].astype(bool))
-
-        if layer_kv is not None and chunked_decode:
             # flash-decode: online-softmax over KV chunks bounded by the LIVE
             # cache length — never reads the dead cache tail, never
             # materializes GQA-repeated K/V (ops/decode_attention.py)
             from agilerl_tpu.ops.decode_attention import chunked_cached_attention
 
-            attn = chunked_cached_attention(q, ck, cv, cm, start)
+            attn = chunked_cached_attention(q, ck, cv, cache_mask, start)
             attn = attn.reshape(B, T, config.n_head * config.head_dim)
         else:
+            new_kv = None
+            # causal within the block + padding mask
+            t_ids = jnp.arange(T)
+            mask = (t_ids[None, None, :] <= t_ids[None, :, None])  # [1, T, S=T]
+            mask = jnp.logical_and(mask, attention_mask[:, None, :].astype(bool))
             # GQA: repeat kv heads
             rep = config.n_head // config.kv_heads
             if rep > 1:
-                k_all = jnp.repeat(k_all, rep, axis=2)
-                v_all = jnp.repeat(v_all, rep, axis=2)
+                k = jnp.repeat(k, rep, axis=2)
+                v = jnp.repeat(v, rep, axis=2)
 
             qh = jnp.moveaxis(q, 2, 1)  # [B, H, T, d]
-            kh = jnp.moveaxis(k_all, 2, 1)
-            vh = jnp.moveaxis(v_all, 2, 1)
-            if use_flash and layer_kv is None:
+            kh = jnp.moveaxis(k, 2, 1)
+            vh = jnp.moveaxis(v, 2, 1)
+            if use_flash:
                 # Pallas flash attention (causal + padding mask, custom VJP so
                 # it also serves training losses)
                 from agilerl_tpu.ops.flash_attention_vjp import (
@@ -654,20 +630,9 @@ def forward(
         h, aux = _block_ffn(config, blk, h, lora_layer, lora_scale)
         return h, new_kv, aux
 
-    aux_total = jnp.zeros((), jnp.float32)
     fn = jax.checkpoint(block_fn, static_argnums=()) if config.remat else block_fn
-    new_caches: Optional[KVCache] = None
-    new_k = new_v = None  # [L, B, T, KV, hd] new-token projections
-    new_state = prev_state = None
-    blocks = lora_layers = None
-    if not config.is_hybrid:
-        blocks = [params["blocks"][str(i)] for i in range(config.n_layer)]
-        lora_layers = [
-            lora["blocks"].get(str(i)) if lora is not None else None
-            for i in range(config.n_layer)
-        ]
+    fns = {"attn": fn}
     if config.is_hybrid:
-        # two kinds of layer: one scan a run of equal layers (_run_layers)
         from agilerl_tpu.llm import ssm
 
         def mamba_fn(h, blk, layer_state, lora_layer):
@@ -679,69 +644,32 @@ def forward(
             h, aux = _block_ffn(config, blk, h + out, lora_layer, lora_scale)
             return h, (None if layer_state is None else (new_s, prev_s)), aux
 
-        mfn = jax.checkpoint(mamba_fn) if config.remat else mamba_fn
-        xs = {}
-        if cache is not None:
-            xs = {"attn": _split_by_runs(config, "attn", (cache.k, cache.v)),
-                  "mamba": list(cache.state)}
-        h, ys, aux_total = _run_layers(
-            config, params, lora, h, {"attn": fn, "mamba": mfn}, xs)
-        if cache is not None:
-            new_k = jnp.concatenate([y[0] for y in ys["attn"]])
-            new_v = jnp.concatenate([y[1] for y in ys["attn"]])
-            new_state = tuple(y[0] for y in ys["mamba"])
-            # a one-token forward has no "before the last token" of its own
-            prev_state = (tuple(y[1] for y in ys["mamba"]) if T > 1
-                          else cache.prev_state)
-    elif _scannable(config, blocks, lora_layers):
-        # one scan over the stacked layer axis — cached (pre-update slabs
-        # ride as read-only xs, new tokens come back as small ys) and
-        # non-cached alike: compile time is constant in n_layer
-        stack = lambda *xs: jnp.stack(xs)  # noqa: E731
-        stacked_blk = jax.tree_util.tree_map(stack, *blocks)
-        has_lora = lora is not None
-        has_cache = cache is not None
-        xs = [stacked_blk]
-        if has_cache:
-            xs.append((cache.k, cache.v))
-        if has_lora:
-            xs.append(jax.tree_util.tree_map(stack, *lora_layers))
-
-        def body(carry, x):
-            h, aux = carry
-            i = 1
-            layer_kv = x[i] if has_cache else None
-            i += has_cache
-            lora_i = x[i] if has_lora else None
-            hn, new_kv, aux_i = fn(h, x[0], layer_kv, lora_i)
-            return (hn, aux + aux_i), new_kv
-
-        (h, aux_total), new_kvs = jax.lax.scan(
-            body, (h, aux_total), tuple(xs))
-        if has_cache:
-            new_k, new_v = new_kvs
-    else:
-        nk_list, nv_list = [], []
-        for i in range(config.n_layer):
-            layer_kv = (cache.k[i], cache.v[i]) if cache is not None else None
-            h, new_kv, aux = fn(h, blocks[i], layer_kv, lora_layers[i])
-            aux_total = aux_total + aux
-            if new_kv is not None:
-                nk_list.append(new_kv[0])
-                nv_list.append(new_kv[1])
-        if cache is not None:
-            new_k, new_v = jnp.stack(nk_list), jnp.stack(nv_list)
-
+        fns["mamba"] = jax.checkpoint(mamba_fn) if config.remat else mamba_fn
+    # cached: the pre-update slabs (and recurrent states) ride as read-only
+    # xs, the new tokens' k/v come back as small ys
+    xs = {}
     if cache is not None:
-        # ONE bulk write of the new tokens into the (aliasable) cache buffers
+        xs["attn"] = _split_by_runs(config, "attn", (cache.k, cache.v))
+        if config.is_hybrid:
+            xs["mamba"] = list(cache.state)
+    h, ys, aux_total = _run_layers(config, params, lora, h, fns, xs)
+
+    new_caches: Optional[KVCache] = None
+    if cache is not None:
+        # ONE bulk write of the new tokens ([L, B, T, KV, hd]) into the
+        # (aliasable) cache buffers
+        new_k, new_v = _join_runs(ys["attn"])
         new_caches = KVCache(
             jax.lax.dynamic_update_slice(cache.k, new_k, (0, 0, start, 0, 0)),
             jax.lax.dynamic_update_slice(cache.v, new_v, (0, 0, start, 0, 0)),
             start + T, cache_mask,
         )
         if config.is_hybrid:
-            new_caches = new_caches._replace(state=new_state,
-                                             prev_state=prev_state)
+            # a one-token forward has no "before the last token" of its own
+            new_caches = new_caches._replace(
+                state=tuple(y[0] for y in ys["mamba"]),
+                prev_state=(tuple(y[1] for y in ys["mamba"]) if T > 1
+                            else cache.prev_state))
 
     h = _rms(h, params["ln_f"], config.rms_eps).astype(jnp.float32)
     if return_aux:
@@ -1074,7 +1002,6 @@ def forward_paged(
     candidate j sees exactly the prefix plus candidates < j."""
     B, T = tokens.shape
     dtype = config.dtype
-    chunked_decode = use_chunked_decode()
     h = jnp.take(params["tok_emb"], tokens, axis=0).astype(dtype)
     pos2d = positions if positions.ndim == 2 else positions[:, None]
     wp_start = write_pos[:, 0] if write_pos.ndim == 2 else write_pos
@@ -1092,40 +1019,18 @@ def forward_paged(
         else:
             k_slab = k_slab.at[arange_b, write_pos].set(k[:, 0])
             v_slab = v_slab.at[arange_b, write_pos].set(v[:, 0])
-        if chunked_decode:
-            from agilerl_tpu.ops.decode_attention import (
-                chunked_cached_attention,
-            )
+        from agilerl_tpu.ops.decode_attention import chunked_cached_attention
 
-            attn = chunked_cached_attention(q, k_slab, v_slab, slot_mask,
-                                            wp_start)
-        else:
-            # dense fallback — same repeat-heads formulation as forward's
-            # kill-switch branch so the two kill-switch paths match exactly
-            S = k_slab.shape[1]
-            rep = config.n_head // config.kv_heads
-            if rep > 1:
-                k_slab = jnp.repeat(k_slab, rep, axis=2)
-                v_slab = jnp.repeat(v_slab, rep, axis=2)
-            qh = jnp.moveaxis(q, 2, 1)
-            kh = jnp.moveaxis(k_slab, 2, 1)
-            vh = jnp.moveaxis(v_slab, 2, 1)
-            kv_slot = jnp.arange(S)
-            causal = (kv_slot[None, None, :]
-                      <= (wp_start[:, None] + jnp.arange(T)[None, :])[:, :, None])
-            mask = jnp.logical_and(causal, slot_mask[:, None, :].astype(bool))
-            scores = jnp.einsum("bhtd,bhsd->bhts", qh, kh).astype(jnp.float32)
-            scores = scores / math.sqrt(config.head_dim)
-            scores = jnp.where(mask[:, None, :, :], scores, -1e9)
-            probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
-            attn = jnp.einsum("bhts,bhsd->bhtd", probs, vh)
-            attn = jnp.moveaxis(attn, 1, 2)
+        attn = chunked_cached_attention(q, k_slab, v_slab, slot_mask,
+                                        wp_start)
         attn = attn.reshape(B, T, config.n_head * config.head_dim)
         attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale, dtype)
         h = h + attn
         h, _ = _block_ffn(config, blk, h, lora_layer, lora_scale)
-        return h, ((k, v) if write_pos.ndim == 2 else (k[:, 0], v[:, 0]))
+        new_kv = (k, v) if write_pos.ndim == 2 else (k[:, 0], v[:, 0])
+        return h, new_kv, 0.0
 
+    fns = {"attn": block_fn}
     if config.is_hybrid:
         # the second cache kind: rows ARE slots, so a state-space layer
         # reads and writes its slot's record in place — no table, no gather
@@ -1140,10 +1045,6 @@ def forward_paged(
         tok_mask = jnp.take_along_axis(
             slot_mask, jnp.minimum(wp_start, S - 1)[:, None], axis=1)
 
-        def attn_fn(h, blk, layer_kv, lora_layer):
-            hn, new_kv = block_fn(h, blk, layer_kv, lora_layer)
-            return hn, new_kv, 0.0
-
         def mamba_fn(h, blk, layer_state, lora_layer):
             x = _rms(h, blk["ln1"], config.rms_eps)
             out, new_s, _ = ssm.mixer(config, blk, x, tok_mask, layer_state,
@@ -1151,45 +1052,16 @@ def forward_paged(
             h, _ = _block_ffn(config, blk, h + out, lora_layer, lora_scale)
             return h, new_s, 0.0
 
-        xs = {"attn": _split_by_runs(config, "attn", (cache.k, cache.v)),
-              "mamba": list(cache.state)}
-        h, ys, _ = _run_layers(config, params, lora, h,
-                               {"attn": attn_fn, "mamba": mamba_fn}, xs)
-        h = _rms(h, params["ln_f"], config.rms_eps).astype(jnp.float32)
-        return h, (jnp.concatenate([y[0] for y in ys["attn"]]),
-                   jnp.concatenate([y[1] for y in ys["attn"]]),
-                   tuple(ys["mamba"]))
-
-    blocks = [params["blocks"][str(i)] for i in range(config.n_layer)]
-    lora_layers = [
-        lora["blocks"].get(str(i)) if lora is not None else None
-        for i in range(config.n_layer)
-    ]
-    if _scannable(config, blocks, lora_layers):
-        stack = lambda *xs: jnp.stack(xs)  # noqa: E731
-        stacked_blk = jax.tree_util.tree_map(stack, *blocks)
-        has_lora = lora is not None
-        xs = [stacked_blk, (cache.k, cache.v)]
-        if has_lora:
-            xs.append(jax.tree_util.tree_map(stack, *lora_layers))
-
-        def body(h, x):
-            lora_i = x[2] if has_lora else None
-            hn, new_kv = block_fn(h, x[0], x[1], lora_i)
-            return hn, new_kv
-
-        h, (new_k, new_v) = jax.lax.scan(body, h, tuple(xs))
-    else:
-        nk_list, nv_list = [], []
-        for i in range(config.n_layer):
-            h, (nk, nv) = block_fn(h, blocks[i], (cache.k[i], cache.v[i]),
-                                   lora_layers[i])
-            nk_list.append(nk)
-            nv_list.append(nv)
-        new_k, new_v = jnp.stack(nk_list), jnp.stack(nv_list)
-
+        fns["mamba"] = mamba_fn
+    xs = {"attn": _split_by_runs(config, "attn", (cache.k, cache.v))}
+    if config.is_hybrid:
+        xs["mamba"] = list(cache.state)
+    h, ys, _ = _run_layers(config, params, lora, h, fns, xs)
     h = _rms(h, params["ln_f"], config.rms_eps).astype(jnp.float32)
-    return h, (new_k, new_v)
+    new = _join_runs(ys["attn"])
+    if config.is_hybrid:
+        new += (tuple(ys["mamba"]),)
+    return h, new
 
 
 # --------------------------------------------------------------------------- #

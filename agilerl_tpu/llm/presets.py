@@ -20,7 +20,7 @@ from agilerl_tpu.llm.model import GPTConfig
 
 # dims: (vocab, n_layer, n_head, n_kv_head, d_model, d_ff, max_seq_len)
 _PRESETS: Dict[str, Dict[str, Any]] = {
-    # GPT-2 small — the single-chip bench model (bench.py grpo_learn_cell)
+    # GPT-2 small — a model that fits any single chip
     "gpt2-small": dict(
         vocab_size=50_257, n_layer=12, n_head=12, n_kv_head=12, d_model=768,
         d_ff=3_072, max_seq_len=1_024, rope_theta=10_000.0,

@@ -2,7 +2,7 @@
 
 One event per line: ``{"seq": N, "ts": unix_seconds, "kind": ..., **payload}``.
 ``seq`` is a per-sink monotone index — consumers (the evo-PPO smoke test,
-``bench.py`` timeline readers) sort/validate on it rather than wall time,
+timeline readers) sort/validate on it rather than wall time,
 which can repeat at millisecond granularity.
 """
 

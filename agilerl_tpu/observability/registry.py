@@ -6,7 +6,7 @@ treat per-step telemetry as a first-class subsystem (MegaScale, Jiang et al.
 process-local store every layer (training loops, HPO, serving) writes into.
 
 Values export two ways: a structured JSONL event stream (``events.JsonlSink``)
-for timeline consumers (``bench.py``, offline analysis) and Prometheus-style
+for timeline consumers (offline analysis) and Prometheus-style
 text exposition (:meth:`MetricsRegistry.prometheus_text`) for scrapers.
 """
 

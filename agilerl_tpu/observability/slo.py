@@ -20,8 +20,8 @@ DECLARED artifact:
   ``slo_alert`` JSONL event per fire/clear.
 - :meth:`SLOEvaluator.grade` — one scored report per scenario run:
   per-objective attainment over the whole window, pass/fail, a 0-100
-  score, and the alert history. ``BENCH_MODE=traffic`` emits exactly one
-  of these per scenario (``bench.py``).
+  score, and the alert history: one per scenario a
+  ``benchmarking.traffic.TrafficDriver`` runs.
 
 Exactness contract: error fractions come from histogram BUCKET-COUNT
 deltas, which are exact if and only if the objective threshold sits on a
@@ -290,9 +290,9 @@ def registry_source(registry, spec: SLOSpec) -> Callable[[], Dict[str, Any]]:
     the hot-path alternative to ``registry.dump`` for in-process continuous
     evaluation. A fleet registry carries dozens of instruments; dumping all
     of them every scheduler step is where an evaluator's overhead budget
-    (~1%, measured by ``BENCH_MODE=traffic``) actually goes. Reads live
-    instrument state directly (same package-internal access the telemetry
-    aggregator's materializer uses)."""
+    actually goes. Reads live instrument state directly (same
+    package-internal access the telemetry aggregator's materializer
+    uses)."""
     from agilerl_tpu.observability.registry import Counter, Histogram
 
     counter_names, hist_names = spec.metric_names()
@@ -525,8 +525,7 @@ class SLOEvaluator:
     def grade(self, scenario: Optional[str] = None,
               extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
         """One scored report over everything seen between the first and
-        last :meth:`evaluate` — the per-scenario JSON ``BENCH_MODE=traffic``
-        emits. Attainment is computed from cumulative deltas over the full
+        last :meth:`evaluate`. Attainment is computed from cumulative deltas over the full
         run, so a scenario is graded on ALL of its traffic, not on
         whichever alert window happened to be open at the end."""
         if self._first is None:

@@ -2,7 +2,7 @@
 
 Wraps :class:`agilerl_tpu.utils.profiling.StepTimer` and reuses the SAME
 FLOPs accounting (``transformer_flops_per_token`` + ``PEAK_BF16_FLOPS``) so
-the timeline's MFU and ``bench.py``'s MFU cannot drift. Multihost aggregation
+the timeline's MFU and ``utils.profiling``'s cannot drift. Multihost aggregation
 rides :class:`agilerl_tpu.utils.log_utils.CombineLogs` — host-side weighted
 means reduced over ``process_allgather``, no new collective machinery.
 

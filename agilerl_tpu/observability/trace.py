@@ -32,8 +32,7 @@ This module is deliberately tiny and dependency-free:
 - **No-op when unconfigured** — the process-default tracer has no sink:
   ``span()``/``start_span()`` return ONE shared :class:`_NoopSpan` (no
   allocation, every method ``pass``), so instrumented hot paths cost a
-  method call and an ``enabled`` check when tracing is off
-  (``BENCH_MODE=trace`` pins the overhead).
+  method call and an ``enabled`` check when tracing is off.
 
 Ids carry a per-process tag (sha1 of pod name + pid) plus a process-local
 counter — unique across pods with zero coordination and zero randomness.

@@ -16,9 +16,9 @@ def pallas_enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
-from agilerl_tpu.ops.flash_attention import flash_attention  # noqa: E402
+from agilerl_tpu.ops.flash_attention_vjp import flash_attention_diff  # noqa: E402
 from agilerl_tpu.ops.fused_loss import fused_token_logprob
 from agilerl_tpu.ops.ring_attention import make_ring_attention, ring_attention
 
-__all__ = ["flash_attention", "fused_token_logprob", "ring_attention",
+__all__ = ["flash_attention_diff", "fused_token_logprob", "ring_attention",
            "make_ring_attention", "pallas_enabled"]

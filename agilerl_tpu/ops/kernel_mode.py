@@ -44,14 +44,10 @@ def resolve_interpret(explicit: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu"
 
 
-# Compile-path kill switches honoured across the framework. ONE list so
-# everything that labels a run (bench.py grpo mode, chip_smoke.py) reports the
-# same set — a switch added here is reported by all of them.
-KILL_SWITCH_ENV_VARS = (
-    "AGILERL_TPU_DISABLE_PALLAS",
-    "AGILERL_TPU_DISABLE_SCAN_LAYERS",
-    "AGILERL_TPU_DISABLE_CHUNKED_DECODE",
-)
+# The one environment variable that changes what a program compiles to
+# (ops.pallas_enabled: kernels off = the XLA paths every non-TPU backend
+# runs). perfbench/harness.py refuses to measure with it set.
+KILL_SWITCH_ENV_VARS = ("AGILERL_TPU_DISABLE_PALLAS",)
 
 
 def active_kill_switches():

@@ -79,8 +79,9 @@ _ENTRY_PREFIX = "exe_"
 
 
 def enable_jax_cache() -> Optional[str]:
-    """Turn on JAX's persistent compilation cache for an entry point
-    (``chip_smoke.py``, ``bench.py``) and return the directory set in code.
+    """Turn on JAX's persistent compilation cache for an entry point (a
+    training script; the benchmark's harness has its own copy of the rule)
+    and return the directory set in code.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself: nothing
     is set here and ``None`` comes back. Otherwise the cache lives at the
@@ -414,18 +415,29 @@ def _tracer_of(store: Optional[ExecutableStore], tracer):
 def serialize_compiled(compiled) -> Dict[str, Any]:
     """The store payload for one ``jax.stages.Compiled``: the serialized
     executable bytes plus the in/out treedefs ``deserialize_and_load``
-    needs (`jax.experimental.serialize_executable` triple)."""
+    needs (`jax.experimental.serialize_executable` triple), and the ids of
+    the devices it was compiled for, in the order of its device
+    assignment."""
     from jax.experimental import serialize_executable as se
 
     exe, in_tree, out_tree = se.serialize(compiled)
-    return {"exe": exe, "in_tree": in_tree, "out_tree": out_tree}
+    devices = compiled.runtime_executable().local_devices()
+    return {"exe": exe, "in_tree": in_tree, "out_tree": out_tree,
+            "device_ids": [d.id for d in devices]}
 
 
 def deserialize_payload(payload: Dict[str, Any]):
+    """Load a stored executable onto the devices it was compiled for.
+    ``deserialize_and_load`` would otherwise load it onto ALL of the
+    backend's devices, and a one-device program then refuses its arguments
+    in any process that sees more than one. An id this process does not
+    have (or an entry stored without ids) raises: a miss to the caller."""
     from jax.experimental import serialize_executable as se
 
+    by_id = {d.id: d for d in jax.devices()}
     return se.deserialize_and_load(
-        payload["exe"], payload["in_tree"], payload["out_tree"])
+        payload["exe"], payload["in_tree"], payload["out_tree"],
+        execution_devices=[by_id[i] for i in payload["device_ids"]])
 
 
 def load_or_compile(

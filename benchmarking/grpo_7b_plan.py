@@ -20,8 +20,8 @@ Run:  XLA_FLAGS=--xla_force_host_platform_device_count=64 JAX_PLATFORMS=cpu \
 The test tier runs it via tests/test_parallel/test_7b_aot.py.
 
 Flash-attention/fused-loss Pallas kernels are OFF in this rehearsal (they
-lower only for a real TPU target; chip_smoke.py runs them on the chip and
-tests/test_ops/test_tpu_compile_v5e.py compiles them for one) — the lowered
+lower only for a real TPU target; the benchmark's GRPO cells run them on
+the chip and tests/test_ops/test_tpu_compile_v5e.py compiles them for one) — the lowered
 program is the XLA-attention + chunked
 loss path, which shares every sharding decision with the flash path.
 """
@@ -42,13 +42,6 @@ def _force_cpu(n_devices: int) -> None:
     import re
 
     os.environ["JAX_PLATFORMS"] = "cpu"
-    # Lower UNROLLED for this document: XLA's cost analysis counts a
-    # lax.scan body once, so the scanned production program under-reports
-    # per-step FLOPs/HBM ~n_layer-fold (0.17 vs 5.57 PFLOPs at 32 layers).
-    # The plan is the accounting artifact — its numbers must be faithful.
-    # Production training still scans (llm/model.py scan_layers); the AOT
-    # report (tpu_aot_compile.py) covers the scanned program's compile side.
-    os.environ["AGILERL_TPU_DISABLE_SCAN_LAYERS"] = "1"
     flags = os.environ.get("XLA_FLAGS", "")
     m = re.search(r"--xla_force_host_platform_device_count=(\d+)", flags)
     if m and int(m.group(1)) < n_devices:
@@ -136,9 +129,7 @@ SCENARIOS = {
 
 
 def scenarios_main(args):
-    """Build every canonical scenario in ONE process and write ONE markdown;
-    also cross-checks the canonical row against the real TPU compiler's
-    numbers (benchmarking/tpu_aot_report.json) when their configs match."""
+    """Build every canonical scenario in ONE process and write ONE markdown."""
     defaults = dict(devices=64, tp=4, dp=1, batch=64, seq=2048, prompt=1024,
                     new_tokens=512, preset="llama3-8b")
     ignored = [k for k, v in defaults.items() if getattr(args, k) != v]
@@ -152,19 +143,10 @@ def scenarios_main(args):
               flush=True)
         results[name] = plan_one(compile_=args.compile, **cfg)
 
-    aot = None
-    aot_path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "tpu_aot_report.json")
-    try:
-        with open(aot_path) as fh:
-            aot = json.load(fh)["targets"].get("grpo_7b_gspmd")
-    except (OSError, KeyError, json.JSONDecodeError):
-        aot = None
-
     md_path = args.write_md or os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "grpo_7b_plan.md")
     with open(md_path, "w") as fh:
-        fh.write(_render_scenarios_md(results, aot))
+        fh.write(_render_scenarios_md(results))
     print(f"wrote {md_path}", file=sys.stderr)
     out = {name: rep for name, (rep, _) in results.items()}
     print(json.dumps(out), flush=True)
@@ -226,7 +208,13 @@ def plan_one(devices, tp, dp, batch, seq, prompt, new_tokens, preset_name,
     fsdp = devices // (tp * dp)
     plan, mesh_name, plan_src = _load_or_build_plan(dp, fsdp, tp)
     mesh = plan.build_mesh(jax.devices()[:devices])
-    cfg = preset(preset_name, max_seq_len=seq, use_flash_attention=False)
+    # Lower UNROLLED for this document: XLA's cost analysis counts a
+    # lax.scan body once, so the scanned production program under-reports
+    # per-step FLOPs/HBM ~n_layer-fold (0.17 vs 5.57 PFLOPs at 32 layers).
+    # The plan is the accounting artifact — its numbers must be faithful.
+    # Production training still scans (llm/model.py scan_layers).
+    cfg = preset(preset_name, max_seq_len=seq, use_flash_attention=False,
+                 scan_layers=False)
     B, T = batch, seq
     lora_rank = 16
     report = {"preset": preset_name, "mesh": mesh_name,
@@ -362,7 +350,8 @@ def _closing_prose(go_no_go_label):
         "CPU-backend GSPMD lowering (they lower natively only for a TPU "
         "target); their Mosaic lowering is verified by "
         "`benchmarking/tpu_aot_compile.py` (compile-only topology), and "
-        "`chip_smoke.py` runs them on a chip.",
+        "the benchmark's GRPO cells run them on a chip (`python3 "
+        "perfbench/run.py --workload <cell>`, PERF.md).",
     ]
 
 
@@ -412,7 +401,7 @@ def _render_md(report, budget, render_budget_md):
     return "\n".join(lines) + "\n"
 
 
-def _render_scenarios_md(results, aot):
+def _render_scenarios_md(results):
     from agilerl_tpu.utils.hbm_budget import HBM_PER_CHIP, render_budget_md
 
     lines = [
@@ -455,77 +444,6 @@ def _render_scenarios_md(results, aot):
             "",
         ]
 
-    rep = results["canonical_v5p64"][0]
-    aot_matches = (
-        aot is not None and aot.get("ok")
-        # the cross-check is only honest when the AOT target ran the SAME
-        # (mesh, batch, seq) as the canonical scenario — embedding numbers
-        # from a different config would be the exact r4 #6 failure mode
-        and aot.get("mesh") == rep["mesh"]
-        and aot.get("batch") == rep["batch"]
-        and aot.get("seq") == rep["seq"]
-        and aot.get("n_devices") == rep["devices"]
-    )
-    if aot_matches:
-        # The AOT harness compiles the PRODUCTION (scan-over-layers) program.
-        # Its raw cost analysis counts the layer-scan body once, so the
-        # strict cost-analysis-vs-cost-analysis verdict only applies when
-        # the AOT record carries no flops_analytic (pre-scan reports). With
-        # a scanned program, state both accountings transparently instead of
-        # fabricating an equality check across different definitions:
-        # this document's number (XLA cost analysis of the unrolled
-        # lowering) is the canonical per-step figure; the PaLM-style 6N
-        # analytic accounting is a deliberately coarser upper accounting.
-        if aot.get("flops_analytic"):
-            analytic_pflops = aot["flops_analytic"] / 1e15
-            flops_line = (
-                f"- **{rep['train_step_pflops']} PFLOPs/step** (canonical: "
-                "XLA cost analysis of the unrolled lowering); the PaLM-style "
-                f"6N analytic accounting of the same config gives "
-                f"{analytic_pflops:.2f} PFLOPs — a coarser upper accounting, "
-                "quoted for scale, not equality")
-        else:
-            measured_pflops = aot["flops"] * aot["n_devices"] / 1e15
-            delta_pct = abs(measured_pflops - rep["train_step_pflops"]) / max(
-                rep["train_step_pflops"], 1e-9) * 100
-            verdict = (
-                f"agreement within {delta_pct:.1f}% (fusion-level "
-                "differences)" if delta_pct <= 5 else
-                f"**DISAGREEMENT of {delta_pct:.1f}% — investigate before "
-                "trusting either number**")
-            flops_line = (
-                f"- measured cost analysis: **{measured_pflops:.2f} "
-                f"PFLOPs/step** ({aot['flops'] / 1e12:.1f} TFLOPs/chip x "
-                f"{aot['n_devices']}) vs {rep['train_step_pflops']} PFLOPs "
-                f"from the CPU-backend lowering — {verdict}")
-        lines += [
-            "## Cross-check: real TPU compiler (compile-only v5p topology)",
-            "",
-            "`benchmarking/tpu_aot_compile.py` compiled the canonical",
-            "scenario's train step (same mesh/batch/seq, verified) through "
-            "the REAL XLA:TPU pipeline for a "
-            f"`{aot['topology']}` topology ({aot['n_devices']} chips, no "
-            "hardware attached):",
-            "",
-            flops_line,
-            f"- per-chip XLA temp allocation: "
-            f"{aot.get('temp_bytes', 0) / 2**30:.1f} GiB "
-            "(hardware-grade; the budget table above is the analytic bound)",
-            f"- TPU compile time {aot['compile_seconds']}s; executable "
-            f"sha256 `{aot['fingerprint_sha256'][:16]}`",
-            "",
-        ]
-    elif aot is not None and aot.get("ok"):
-        lines += [
-            "## Cross-check: real TPU compiler",
-            "",
-            "`benchmarking/tpu_aot_report.json` holds a grpo_7b_gspmd "
-            f"compile for ({aot.get('mesh')}, batch {aot.get('batch')}, "
-            f"seq {aot.get('seq')}) which does NOT match the canonical "
-            "scenario — re-run `benchmarking/tpu_aot_compile.py` to refresh "
-            "it; its numbers are deliberately not quoted here.",
-            "",
-        ]
     lines += _closing_prose("The 35% projection row of `canonical_v5p64`")
     return "\n".join(lines) + "\n"
 

@@ -17,9 +17,8 @@ memory stats, and a sha256 fingerprint of the serialized TPU executable):
   (ops/fused_loss.py; parity ref: liger fused losses at
   agilerl/algorithms/grpo.py:558) at llama3-8b lm-head dims (D=4096,
   V=128256), forward and custom-VJP backward (dH + dW kernels).
-- ``flash_fwd`` / ``flash_grad`` — Pallas flash attention fwd
-  (ops/flash_attention.py) and its custom VJP (ops/flash_attention_vjp.py)
-  at llama3 head dims (H=32, d=128, T=2048).
+- ``flash_fwd`` / ``flash_grad`` — Pallas flash attention
+  (ops/flash_attention_vjp.py), forward and custom VJP, at llama3 head dims (H=32, d=128, T=2048).
 - ``decode_chunk`` — one BucketedGenerator decode chunk (llm/serving.py, the
   vLLM-role path, ref core/base.py:3101) for the llama3-8b preset.
 - ``paged_verify`` — the speculative-decoding verify step
@@ -37,7 +36,8 @@ memory stats, and a sha256 fingerprint of the serialized TPU executable):
   open question this target answers).
 
 Run:  python benchmarking/tpu_aot_compile.py [--targets a,b,...] [--quick]
-Writes benchmarking/tpu_aot_report.{json,md}. The test tier runs tiny dims
+Writes benchmarking/aot_executable_store/report.{json,md} (git-ignored: a
+run's output, not a record). The test tier runs tiny dims
 via tests/test_ops/test_tpu_aot.py.
 
 Executable store (ISSUE 15): every target the sweep compiles is PUBLISHED
@@ -208,7 +208,8 @@ def main(argv=None):
     ap.add_argument("--pod", default="v5p:4x4x4",
                     help="64-chip topology for the GSPMD targets")
     ap.add_argument("--write", default=None,
-                    help="report path prefix (default benchmarking/tpu_aot_report)")
+                    help="report path prefix (default "
+                         "benchmarking/aot_executable_store/report)")
     ap.add_argument("--cache", default=None,
                     help="executable store dir (default: "
                          "$AGILERL_TPU_COMPILE_CACHE or "
@@ -285,7 +286,6 @@ def main(argv=None):
     from agilerl_tpu.ops.fused_loss import (
         fused_token_logprob, fused_token_logprob_diff,
     )
-    from agilerl_tpu.ops.flash_attention import flash_attention
     from agilerl_tpu.ops.flash_attention_vjp import flash_attention_diff
 
     N, D, V = (256, 512, 4096) if args.quick else (2048, 4096, 128256)
@@ -313,7 +313,7 @@ def main(argv=None):
         q = jax.ShapeDtypeStruct((B, H, T, hd), jnp.bfloat16, sharding=s1)
         m = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=s1)
         fn = jax.jit(functools.partial(
-            flash_attention, causal=True, interpret=False))
+            flash_attention_diff, causal=True, interpret=False))
         return _compile(fn, (q, q, q, m), args.topology, 1)
 
     def flash_grad():
@@ -607,7 +607,9 @@ def main(argv=None):
     run("ring_flash", ring_flash)
 
     prefix = args.write or os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "tpu_aot_report")
+        os.path.dirname(os.path.abspath(__file__)), "aot_executable_store",
+        "report")
+    os.makedirs(os.path.dirname(prefix), exist_ok=True)
     with open(prefix + ".json", "w") as fh:
         json.dump(report, fh, indent=1)
     with open(prefix + ".md", "w") as fh:

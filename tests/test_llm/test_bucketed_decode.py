@@ -3,6 +3,8 @@ role, VERDICT r3 next #3): bounded compile set across ragged sweeps, host
 early-exit on EOS, greedy parity with the dense generate path.
 Ref: /root/reference/agilerl/algorithms/core/base.py:3101 (vLLM glue)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -175,17 +177,18 @@ def test_grpo_get_action_uses_bucketed_path():
     assert comp.shape == (2, 8)
 
 
-def test_grpo_dense_fallback_and_kill_switch(monkeypatch):
+def test_grpo_dense_fallback_by_constructor_flag():
     from agilerl_tpu.algorithms.grpo import GRPO
 
-    monkeypatch.setenv("AGILERL_TPU_DISABLE_BUCKETED_DECODE", "1")
     agent = GRPO(config=CFG, pad_token_id=0, eos_token_id=1, group_size=2,
-                 batch_size=4, max_output_tokens=8, seed=0)
-    assert not agent.bucketed_decode
+                 batch_size=4, max_output_tokens=8, seed=0,
+                 bucketed_decode=False)
+    assert not agent.bucketed_decode and not agent.continuous_decode
     ids = np.random.default_rng(0).integers(3, 95, size=(2, 10)).astype(np.int32)
     comp, cmask = agent.get_action({"input_ids": ids,
                                     "attention_mask": np.ones_like(ids)})
     assert comp.shape == (4, 8)
+    assert agent.last_generation_info is None  # the dense generate ran
 
 
 def test_grpo_row_overflow_falls_back_to_dense():
@@ -211,10 +214,10 @@ def test_grpo_row_overflow_falls_back_to_dense():
     assert agent.last_generation_info is None
 
 
-def test_greedy_parity_under_scan_kill_switch(monkeypatch):
-    """The unrolled layer loop over the STACKED cache (scan kill switch —
-    also the bisection's degraded serving config) must emit exactly the
-    same tokens as the scanned path."""
+def test_greedy_parity_with_scan_layers_off():
+    """The layer loop calling every layer over the STACKED cache
+    (``scan_layers=False``) must emit exactly the same tokens as the
+    scanned path."""
     params = _params()
     rng = np.random.default_rng(3)
     seqs = _ragged(rng, 4, 4, 20)
@@ -223,8 +226,8 @@ def test_greedy_parity_under_scan_kill_switch(monkeypatch):
                             decode_chunk=6)
     comp, cmask, _ = gen.generate(seqs, jax.random.PRNGKey(2), params,
                                   greedy=True)
-    monkeypatch.setenv("AGILERL_TPU_DISABLE_SCAN_LAYERS", "1")
-    gen2 = BucketedGenerator(CFG, max_new_tokens=12, pad_id=0, eos_id=None,
+    gen2 = BucketedGenerator(dataclasses.replace(CFG, scan_layers=False),
+                             max_new_tokens=12, pad_id=0, eos_id=None,
                              prompt_buckets=(32,), row_buckets=(4,),
                              decode_chunk=6)
     comp2, cmask2, _ = gen2.generate(seqs, jax.random.PRNGKey(2), params,

@@ -76,21 +76,24 @@ def test_greedy_parity_with_eos_early_exit():
     np.testing.assert_array_equal(cmask, np.asarray(dcmask))
 
 
-def test_greedy_parity_under_chunked_decode_kill_switch(monkeypatch):
-    """The dense-attention fallback (AGILERL_TPU_DISABLE_CHUNKED_DECODE=1)
-    must match the dense generate path run under the same switch."""
-    monkeypatch.setenv("AGILERL_TPU_DISABLE_CHUNKED_DECODE", "1")
+def test_greedy_parity_with_the_uncached_forward():
+    """The paged tier against a reference that shares NO attention code with
+    it: greedy decode through the continuous scheduler (paged gather +
+    chunked_cached_attention) emits, request by request, the tokens the
+    UNCACHED forward over the bare sequence so far picks."""
     params = _params()
     rng = np.random.default_rng(3)
     seqs = _ragged(rng, 4, 4, 20)
     comp, cmask, _ = _gen().generate(seqs, jax.random.PRNGKey(1), params,
                                      greedy=True)
-    toks, mask = left_pad(seqs, 0, 32)
-    dcomp, dcmask = generate(CFG, params, jnp.asarray(toks),
-                             jnp.asarray(mask), jax.random.PRNGKey(1),
-                             max_new_tokens=8, temperature=0.0)
-    np.testing.assert_array_equal(comp, np.asarray(dcomp))
-    np.testing.assert_array_equal(cmask, np.asarray(dcmask))
+    for row, seq in enumerate(seqs):
+        seq = jnp.asarray(seq)[None]
+        for _ in range(8):
+            logits, _ = M.apply(CFG, params, seq)
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(seq.dtype)
+            seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
+        np.testing.assert_array_equal(comp[row], np.asarray(seq[0, -8:]))
+    np.testing.assert_array_equal(cmask, np.ones_like(cmask))
 
 
 def test_compiled_programs_bounded_by_grid_not_requests():
@@ -402,24 +405,34 @@ def test_grpo_continuous_opt_in_and_group_prefix_hits():
     assert comp.shape == (2, 8)
 
 
-def test_grpo_continuous_env_opt_in(monkeypatch):
+@pytest.mark.parametrize("bucketed, continuous, tier", [
+    (True, False, "bucketed"),  # the defaults
+    (True, True, "continuous"),
+    # continuous-only is a valid config: the bucketed flag does not gate it
+    (False, True, "continuous"),
+    (False, False, "dense"),  # the dense generate, exact RNG stream
+])
+def test_grpo_decode_flags_pick_the_tier(bucketed, continuous, tier):
+    """The two constructor flags are the only switch of the rollout tier
+    (no environment variable is read) and are independent."""
     from agilerl_tpu.algorithms.grpo import GRPO
 
-    monkeypatch.setenv("AGILERL_TPU_CONTINUOUS_DECODE", "1")
+    flags = {}
+    if (bucketed, continuous) != (True, False):
+        flags = dict(bucketed_decode=bucketed, continuous_decode=continuous)
     agent = GRPO(config=CFG, pad_token_id=0, eos_token_id=1, group_size=2,
-                 batch_size=4, max_output_tokens=8, seed=0)
-    assert agent.continuous_decode
-    # continuous-only is a valid config: the bucketed KWARG does not gate it
-    agent1 = GRPO(config=CFG, pad_token_id=0, eos_token_id=1, group_size=2,
-                  batch_size=4, max_output_tokens=8, seed=0,
-                  bucketed_decode=False, continuous_decode=True)
-    assert agent1.continuous_decode and not agent1.bucketed_decode
-    # the serving-tier kill switch (dense RNG parity) disables BOTH paths
-    monkeypatch.setenv("AGILERL_TPU_DISABLE_BUCKETED_DECODE", "1")
-    agent2 = GRPO(config=CFG, pad_token_id=0, eos_token_id=1, group_size=2,
-                  batch_size=4, max_output_tokens=8, seed=0,
-                  continuous_decode=True)
-    assert not agent2.continuous_decode and not agent2.bucketed_decode
+                 batch_size=4, max_output_tokens=8, seed=0, **flags)
+    assert agent.bucketed_decode == bucketed
+    assert agent.continuous_decode == continuous
+    ids = np.random.default_rng(12).integers(3, 95, size=(2, 10)).astype(np.int32)
+    comp, _ = agent.get_action({"input_ids": ids,
+                                "attention_mask": np.ones_like(ids)})
+    assert comp.shape == (4, 8)
+    info = agent.last_generation_info
+    if tier == "dense":
+        assert info is None
+    else:
+        assert ("slots" in info) == (tier == "continuous")
 
 
 def test_grpo_prompt_overflow_falls_back_to_dense():

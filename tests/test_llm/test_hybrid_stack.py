@@ -6,6 +6,7 @@ path, the continuous tier through its recurrent-state cache (prefilled rows
 and prefix-hit rows, on log-probabilities, not tokens), a LoRA gradient on a
 Mamba projection, one scan body a run of layers, and the refusals."""
 
+import dataclasses
 import importlib
 
 import jax
@@ -60,13 +61,12 @@ def test_layer_pattern_and_runs():
 
 
 @pytest.mark.parametrize("loop", ["scanned", "unrolled"])
-def test_forward_logits_match_the_reference_with_left_padding(
-        params, loop, monkeypatch):
-    if loop == "unrolled":  # a run's layers called one by one
-        monkeypatch.setenv("AGILERL_TPU_DISABLE_SCAN_LAYERS", "1")
+def test_forward_logits_match_the_reference_with_left_padding(params, loop):
+    # unrolled: a run's layers called one by one
+    cfg = dataclasses.replace(CFG, scan_layers=loop == "scanned")
     seqs = prompts(15, 11)
     toks, mask = G.left_pad(seqs, 0, 20)
-    logits, _ = M.apply(CFG, params, jnp.asarray(toks),
+    logits, _ = M.apply(cfg, params, jnp.asarray(toks),
                         attention_mask=jnp.asarray(mask))
     for row, seq in enumerate(seqs):
         want = ref.logits(params, seq, **REF)
@@ -250,14 +250,13 @@ def scan_calls(jaxpr) -> int:
     return n
 
 
-def test_the_learn_step_holds_one_mamba_body_a_run_not_one_a_layer(
-        params, monkeypatch):
+def test_the_learn_step_holds_one_mamba_body_a_run_not_one_a_layer(params):
     toks = jnp.ones((2, 16), jnp.int32)
-    trace = lambda: jax.make_jaxpr(  # noqa: E731
-        lambda p: M.token_logprobs(CFG, p, toks))(params).jaxpr
-    assert scan_calls(trace()) == 3  # runs of 1, 3 and 2 Mamba layers
-    monkeypatch.setenv("AGILERL_TPU_DISABLE_SCAN_LAYERS", "1")
-    assert scan_calls(trace()) == 6  # unrolled: one a layer
+    trace = lambda cfg: jax.make_jaxpr(  # noqa: E731
+        lambda p: M.token_logprobs(cfg, p, toks))(params).jaxpr
+    assert scan_calls(trace(CFG)) == 3  # runs of 1, 3 and 2 Mamba layers
+    unrolled = dataclasses.replace(CFG, scan_layers=False)
+    assert scan_calls(trace(unrolled)) == 6  # one a layer
 
 
 def test_what_is_not_hybrid_aware_refuses_by_name(params):

@@ -70,40 +70,38 @@ class TestLoRA:
 
 
 class TestScanLayers:
-    """scan-over-layers (model.py _scannable/forward): the non-cached paths
-    roll the layer stack into one lax.scan — HLO and TPU compile time become
-    ~constant in n_layer (measured via compile-only AOT: 12-layer GRPO update
-    83.5s unrolled vs 48.6s scanned, stablehlo halved). These pin that the
-    rolled program is the same function as the unrolled one."""
+    """scan-over-layers (model.py _run_layers): every run of equal layers
+    rolls into one lax.scan — HLO and TPU compile time become ~constant in
+    n_layer (measured via compile-only AOT: 12-layer GRPO update 83.5s
+    unrolled vs 48.6s scanned, stablehlo halved). These pin that the rolled
+    program is the same function as the one that calls every layer
+    (``scan_layers=False``)."""
 
-    def _unrolled(self, monkeypatch, fn):
-        monkeypatch.setenv("AGILERL_TPU_DISABLE_SCAN_LAYERS", "1")
-        out = fn()
-        monkeypatch.delenv("AGILERL_TPU_DISABLE_SCAN_LAYERS")
-        return out
+    @staticmethod
+    def _unrolled(cfg):
+        return dataclasses.replace(cfg, scan_layers=False)
 
-    def test_forward_parity(self, monkeypatch):
+    def test_forward_parity(self):
         cfg = dataclasses.replace(CFG, n_layer=3)
         params = M.init_params(jax.random.PRNGKey(0), cfg)
         toks = jnp.arange(1, 17)[None] % 64
         scanned, _ = M.apply(cfg, params, toks)
-        unrolled, _ = self._unrolled(
-            monkeypatch, lambda: M.apply(cfg, params, toks))
+        unrolled, _ = M.apply(self._unrolled(cfg), params, toks)
         np.testing.assert_allclose(
             np.asarray(scanned), np.asarray(unrolled), atol=1e-5)
 
-    def test_lora_grad_parity(self, monkeypatch):
+    def test_lora_grad_parity(self):
         cfg = dataclasses.replace(CFG, n_layer=3)
         params = M.init_params(jax.random.PRNGKey(0), cfg)
         lora = M.init_lora(jax.random.PRNGKey(1), cfg, rank=4)
         toks = jnp.arange(1, 17)[None] % 64
 
-        def loss(lo):
+        def loss(lo, cfg):
             h, _ = M.forward(cfg, params, toks, lora=lo)
             return jnp.sum(h * h)
 
-        g_scan = jax.grad(loss)(lora)
-        g_unroll = self._unrolled(monkeypatch, lambda: jax.grad(loss)(lora))
+        g_scan = jax.grad(loss)(lora, cfg)
+        g_unroll = jax.grad(loss)(lora, self._unrolled(cfg))
         for a, b in zip(jax.tree_util.tree_leaves(g_scan),
                         jax.tree_util.tree_leaves(g_unroll)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
@@ -119,23 +117,83 @@ class TestScanLayers:
         assert all(bool(jnp.isfinite(x).all())
                    for x in jax.tree_util.tree_leaves(g))
 
-    def test_moe_uniform_scans_interleaved_falls_back(self, monkeypatch):
-        # uniform MoE stack: scannable, parity vs unrolled
+    def test_moe_uniform_scans_interleaved_falls_back(self):
+        # uniform MoE stack: one run, parity vs unrolled
         cfg = dataclasses.replace(CFG, n_layer=2, n_experts=4)
+        assert cfg.layer_runs() == [("attn", 0, 2)]
         params = M.init_params(jax.random.PRNGKey(0), cfg)
         toks = jnp.arange(1, 17)[None] % 64
         h1, _, aux1 = M.forward(cfg, params, toks, return_aux=True)
-        h2, _, aux2 = self._unrolled(
-            monkeypatch, lambda: M.forward(cfg, params, toks, return_aux=True))
+        h2, _, aux2 = M.forward(self._unrolled(cfg), params, toks,
+                                return_aux=True)
         np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=1e-5)
         np.testing.assert_allclose(float(aux1), float(aux2), atol=1e-6)
-        # interleaved dense/MoE: _scannable must refuse (structures differ)
+        # interleaved dense/MoE: the structures differ, so runs of one
         icfg = dataclasses.replace(CFG, n_layer=2, n_experts=4, moe_every=2)
+        assert icfg.layer_runs() == [("attn", 0, 1), ("attn", 1, 1)]
         ip = M.init_params(jax.random.PRNGKey(0), icfg)
-        blocks = [ip["blocks"][str(i)] for i in range(2)]
-        assert not M._scannable(icfg, blocks, [None, None])
         h3, _ = M.forward(icfg, ip, toks)  # and forward still works
         assert h3.shape == (1, 16, 64)
+
+    @pytest.mark.parametrize("case", ["interleaved_moe", "lora_on_some_layers"])
+    def test_one_loop_walks_every_kind_of_stack(self, case):
+        """Stacks the loop cannot roll into one scan — dense and expert
+        layers interleaved (runs of 1, 2, 1 here), adapters on some layers
+        only — go through the same loop, cached, paged and uncached, and
+        match their ``scan_layers=False`` selves."""
+        if case == "interleaved_moe":
+            cfg = dataclasses.replace(CFG, n_layer=4, n_experts=4, moe_every=3)
+            assert [n for _, _, n in cfg.layer_runs()] == [2, 1, 1]
+            lora, scans = M.init_lora(jax.random.PRNGKey(1), cfg, rank=4), 1
+        else:
+            cfg = dataclasses.replace(CFG, n_layer=3)
+            assert cfg.layer_runs() == [("attn", 0, 3)]
+            lora, scans = M.init_lora(jax.random.PRNGKey(1), cfg, rank=4), 0
+            del lora["blocks"]["1"]
+        lora = jax.tree_util.tree_map(
+            lambda x: x + 0.01 if x.ndim == 2 else x, lora)
+        params = M.init_params(jax.random.PRNGKey(0), cfg)
+        toks = jnp.arange(1, 17)[None] % 64
+        ref = self._unrolled(cfg)
+
+        def uncached(c):
+            return M.forward(c, params, toks, lora=lora, return_aux=True)
+
+        assert str(jax.make_jaxpr(lambda: uncached(cfg))()).count(
+            " scan[") == scans
+        assert " scan[" not in str(jax.make_jaxpr(lambda: uncached(ref))())
+        h, _, aux = uncached(cfg)
+        h_ref, _, aux_ref = uncached(ref)
+        np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref), atol=1e-5)
+        np.testing.assert_allclose(float(aux), float(aux_ref), atol=1e-6)
+
+        def cached(c):
+            return M.forward(c, params, toks, lora=lora,
+                             cache=M.init_caches(c, 1, 32))
+
+        (hc, cache), (hc_ref, cache_ref) = cached(cfg), cached(ref)
+        np.testing.assert_allclose(np.asarray(hc), np.asarray(hc_ref),
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(cache.k), np.asarray(cache_ref.k),
+                                   atol=1e-5)
+        assert cache.k.shape[0] == cfg.n_layer
+
+        def paged(c):  # one decode token on top of the cached prompt
+            pool = M.init_paged_cache(c, 3, 16)
+            tables = jnp.array([[1, 2]], jnp.int32)
+            pool = M.paged_scatter_prompt(
+                pool, tables[0], cache.k[:, 0], cache.v[:, 0])
+            mask = (jnp.arange(32)[None] <= 16).astype(jnp.int32)
+            pos = jnp.array([16], jnp.int32)
+            return M.forward_paged(c, params, jnp.array([[5]]), pos, pos,
+                                   pool, tables, mask, lora=lora)
+
+        (hp, (nk, nv)), (hp_ref, (nk_ref, _)) = paged(cfg), paged(ref)
+        np.testing.assert_allclose(np.asarray(hp), np.asarray(hp_ref),
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(nk), np.asarray(nk_ref),
+                                   atol=1e-5)
+        assert nk.shape[0] == nv.shape[0] == cfg.n_layer
 
     def test_cached_path_scans_with_stacked_kv(self):
         # the cache stacks all layers on a leading axis (length/mask stored
@@ -147,13 +205,7 @@ class TestScanLayers:
         h, new_caches = M.forward(CFG, params, toks, cache=cache)
         assert new_caches.k.shape[0] == CFG.n_layer
         assert int(new_caches.length) == 8
-        import os
-
-        os.environ["AGILERL_TPU_DISABLE_SCAN_LAYERS"] = "1"
-        try:
-            h2, nc2 = M.forward(CFG, params, toks, cache=cache)
-        finally:
-            del os.environ["AGILERL_TPU_DISABLE_SCAN_LAYERS"]
+        h2, nc2 = M.forward(self._unrolled(CFG), params, toks, cache=cache)
         np.testing.assert_allclose(np.asarray(h), np.asarray(h2),
                                    rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(np.asarray(new_caches.k),

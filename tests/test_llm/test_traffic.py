@@ -1,4 +1,4 @@
-"""Traffic harness (benchmarking/traffic.py): deterministic scenario
+"""Traffic harness (agilerl_tpu/benchmarking/traffic.py): deterministic scenario
 generation (same seed ⇒ identical trace), heavy-tail lengths clipped to
 the bucket grid, prefix-skew prompt sharing, record/replay round-trip
 (token-for-token, schema-gated); the TrafficDriver over a real 2-replica
@@ -297,7 +297,7 @@ def test_merged_dump_monotone_across_scale_down(params):
 
 
 def test_slo_grades_degraded_run_and_alert_round_trips(params, tmp_path):
-    """The BENCH_MODE=traffic loop in miniature: continuous evaluation
+    """The graded traffic loop in miniature: continuous evaluation
     over the fleet's merged dump while a kill-under-burst run sheds; the
     shed-rate burn alert fires as a forced span, the objective fails the
     grade, and the alert clears once the burst passes."""

@@ -29,7 +29,7 @@ def test_step_events_monotone_with_throughput():
 
 def test_mfu_reuses_profiling_flops_accounting(monkeypatch):
     """MFU = transformer_flops_per_token(config) * tokens / (dt * peak):
-    the SAME accounting bench.py uses, against the device's table peak."""
+    the SAME accounting utils.profiling uses, against the device's table peak."""
     from agilerl_tpu.llm.model import GPTConfig
     from agilerl_tpu.observability import timeline as T
 

@@ -125,9 +125,10 @@ def test_dead_tail_is_never_read():
 
 
 def test_generate_equivalence_end_to_end():
-    """generate() must produce identical tokens with and without the chunked
-    decode path (greedy, so no RNG sensitivity)."""
-    import os
+    """generate() over the KV cache (prefill + one-token steps through
+    chunked_cached_attention) must emit the tokens that the UNCACHED forward
+    over the whole sequence so far picks (greedy, so no RNG sensitivity) —
+    a reference that shares no attention code with the cached path."""
     from agilerl_tpu.llm import model as M
     from agilerl_tpu.llm.generate import generate
 
@@ -137,23 +138,19 @@ def test_generate_equivalence_end_to_end():
     prompt = jnp.asarray([[0, 0, 5, 9, 11], [0, 3, 1, 4, 1]], jnp.int32)
     mask = jnp.asarray([[0, 0, 1, 1, 1], [0, 1, 1, 1, 1]], jnp.int32)
 
-    assert M.use_chunked_decode()
-    toks_chunked, m1 = generate(cfg, params, prompt, mask,
-                                jax.random.PRNGKey(1), max_new_tokens=8,
-                                temperature=0.0)
-    os.environ["AGILERL_TPU_DISABLE_CHUNKED_DECODE"] = "1"
-    try:
-        # the gate is read at trace time — drop the compiled chunked version
-        # so the dense run actually re-traces
-        jax.clear_caches()
-        toks_dense, m2 = generate(cfg, params, prompt, mask,
-                                  jax.random.PRNGKey(1), max_new_tokens=8,
-                                  temperature=0.0)
-    finally:
-        del os.environ["AGILERL_TPU_DISABLE_CHUNKED_DECODE"]
-        jax.clear_caches()
-    np.testing.assert_array_equal(np.asarray(toks_chunked), np.asarray(toks_dense))
-    np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
+    toks_cached, m1 = generate(cfg, params, prompt, mask,
+                               jax.random.PRNGKey(1), max_new_tokens=8,
+                               temperature=0.0)
+    seq, seq_mask = prompt, mask
+    for _ in range(8):
+        logits, _ = M.apply(cfg, params, seq, attention_mask=seq_mask)
+        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
+        seq_mask = jnp.concatenate([seq_mask, jnp.ones((2, 1), jnp.int32)],
+                                   axis=1)
+    np.testing.assert_array_equal(np.asarray(toks_cached),
+                                  np.asarray(seq[:, prompt.shape[1]:]))
+    np.testing.assert_array_equal(np.asarray(m1), np.ones((2, 8), np.int32))
 
 
 def test_grad_through_cached_attention_matches_dense():

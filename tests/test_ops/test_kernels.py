@@ -3,7 +3,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from agilerl_tpu.ops.flash_attention import flash_attention
+from agilerl_tpu.ops.flash_attention_vjp import flash_attention_diff
 from agilerl_tpu.ops.fused_loss import (
     fused_loss_plan,
     fused_token_logprob,
@@ -341,7 +341,7 @@ class TestFlashAttention:
             jax.random.normal(jax.random.fold_in(key, i), (B, H, T, d))
             for i in range(3)
         )
-        got = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32)
+        got = flash_attention_diff(q, k, v, causal=causal, block_q=32, block_k=32)
         want = self._dense(q, k, v, causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
@@ -352,7 +352,7 @@ class TestFlashAttention:
             jax.random.normal(jax.random.fold_in(key, i), (B, H, T, d))
             for i in range(3)
         )
-        got = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+        got = flash_attention_diff(q, k, v, causal=True, block_q=32, block_k=32)
         want = self._dense(q, k, v, True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
@@ -367,7 +367,7 @@ class TestFlashAttentionMask:
         )
         mask = jnp.ones((B, T), jnp.int32)
         mask = mask.at[0, :8].set(0)  # left padding on row 0
-        got = flash_attention(q, k, v, padding_mask=mask, causal=True,
+        got = flash_attention_diff(q, k, v, padding_mask=mask, causal=True,
                               block_q=16, block_k=16)
         # dense reference with combined causal+padding mask
         scale = 1.0 / np.sqrt(d)

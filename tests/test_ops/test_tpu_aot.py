@@ -5,7 +5,7 @@ pipeline — via libtpu's compile-only PJRT topology, no chip needed.
 These are the tiny-dims versions of benchmarking/tpu_aot_compile.py's
 targets, compiled for the chip builders have (a described v5e:2x2); the
 full-dims run (llama3-8b lm-head/attention shapes, the 7B GSPMD pod step)
-writes benchmarking/tpu_aot_report.json, and test_tpu_compile_v5e.py keeps
+is that script's, and test_tpu_compile_v5e.py keeps
 the main path's real widths in the fast tier. Skips cleanly when libtpu
 cannot build a topology (non-TPU wheels).
 
@@ -71,7 +71,6 @@ def test_fused_loss_fwd_and_grad_compile_for_tpu(tpu_device):
 def test_flash_attention_fwd_and_grad_compile_for_tpu(tpu_device):
     from jax.sharding import SingleDeviceSharding
 
-    from agilerl_tpu.ops.flash_attention import flash_attention
     from agilerl_tpu.ops.flash_attention_vjp import flash_attention_diff
 
     s = SingleDeviceSharding(tpu_device)
@@ -80,7 +79,7 @@ def test_flash_attention_fwd_and_grad_compile_for_tpu(tpu_device):
     B, H, T, d = 2, 4, 256, 128
     q = jax.ShapeDtypeStruct((B, H, T, d), jnp.bfloat16, sharding=s)
     m = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=s)
-    _compile(jax.jit(functools.partial(flash_attention, causal=True,
+    _compile(jax.jit(functools.partial(flash_attention_diff, causal=True,
                                        interpret=False)), q, q, q, m)
 
     def loss(qq, kk, vv, mm):

@@ -5,7 +5,8 @@ Interpret mode accepts tilings and VMEM footprints that Mosaic refuses, and
 ``jax.default_backend()`` is ``cpu`` here, so each test hands the compiler
 the kernel or the jitted step itself with shapes placed on the described
 device. Nothing runs: a pass says the chip's compiler takes the program,
-not that its result is right (``chip_smoke.py`` checks that on the chip).
+not that its result is right (the benchmark's ``correct`` checks that on
+the chip: ``python3 perfbench/run.py --workload <cell>``).
 Skipped where libtpu cannot describe the topology.
 """
 

@@ -7,6 +7,7 @@ CompileGuard proving the warm path compiles zero new XLA programs across
 elastic re-form and layout-search candidate eval."""
 
 import os
+import pathlib
 
 import jax
 import numpy as np
@@ -24,6 +25,7 @@ from agilerl_tpu.parallel import plan as PL
 from agilerl_tpu.parallel.compile_cache import (
     CachedFunction,
     ExecutableStore,
+    enable_jax_cache,
     fingerprint_digest,
     fingerprint_parts,
     load_or_compile,
@@ -166,16 +168,23 @@ class TestStore:
             store=ExecutableStore(tmp_path, metrics=MetricsRegistry()))
         assert w2["hit"]
 
+    @pytest.mark.parametrize("bad", ["junk", "device_this_process_lacks"])
     def test_deserialize_failure_falls_back_and_republishes(self, tmp_path,
-                                                            key):
+                                                            key, bad):
         reg = MetricsRegistry()
         store = ExecutableStore(tmp_path, metrics=reg)
         x = np.ones((4, 4), np.float32)
         _, info = load_or_compile(_jit_double(), (x, key), name="bad",
                                   store=store)
         fp = info["fingerprint"]
-        # a VALID commit whose payload is not a loadable executable
-        store.publish(fp, {"exe": b"junk", "in_tree": None, "out_tree": None})
+        payload = store.get_payload(fp)
+        assert payload["device_ids"] == [jax.devices()[0].id]
+        # a VALID commit whose payload is not a loadable executable here
+        if bad == "junk":
+            payload = {"exe": b"junk", "in_tree": None, "out_tree": None}
+        else:  # compiled for a device id no device of this process has
+            payload = dict(payload, device_ids=[10 ** 6])
+        store.publish(fp, payload)
         fn, winfo = load_or_compile(_jit_double(), (x, key), name="bad",
                                     store=store)
         assert not winfo["hit"] and winfo.get("published")
@@ -542,3 +551,32 @@ class TestPagedVerifyFingerprint:
         assert base != self._verify_fp(tmp_path, temperature=0.7)
         assert base != self._verify_fp(tmp_path, top_k=8)
         assert base != self._verify_fp(tmp_path, top_p=0.9)
+
+
+# --------------------------------------------------------------------------- #
+# jax's own persistent compilation cache: where enable_jax_cache puts it
+# --------------------------------------------------------------------------- #
+
+REPO = pathlib.Path(__file__).resolve().parents[2]
+
+
+@pytest.fixture
+def jax_cache_dir_restored():
+    was = jax.config.jax_compilation_cache_dir
+    yield was
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_cache_helper_sets_no_directory_when_the_variable_is_set(
+        monkeypatch, tmp_path, jax_cache_dir_restored):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_jax_cache() is None
+    assert jax.config.jax_compilation_cache_dir == jax_cache_dir_restored
+
+
+def test_cache_helper_default_is_one_fixed_path_in_the_checkout(
+        monkeypatch, jax_cache_dir_restored):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = str(REPO / ".jax_cache")
+    assert enable_jax_cache() == want == enable_jax_cache()
+    assert jax.config.jax_compilation_cache_dir == want
