@@ -115,17 +115,22 @@ def log_prob(
     dist_extra: Optional[dict] = None,
     mask: Optional[jax.Array] = None,
 ) -> jax.Array:
+    """Log-probability of ``action`` under the head's distribution.
+
+    Discrete heads expect each action index in ``[0, n)`` of its head, as
+    ``sample`` draws them. An index outside that range (negative ones too:
+    nothing wraps) selects no entry and contributes 0 to the result, with a
+    zero gradient. A chosen action that the mask rules out scores
+    ``NEG_INF``-low, not NaN.
+    """
     logits = apply_mask(config, logits, mask)
     if config.kind == "categorical":
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return jnp.take_along_axis(logp, action[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        return _chosen(jax.nn.log_softmax(logits, axis=-1), action)
     if config.kind == "multidiscrete":
         total = 0.0
         for i, (s, n) in enumerate(_md_slices(config)):
             logp = jax.nn.log_softmax(logits[..., s : s + n], axis=-1)
-            total = total + jnp.take_along_axis(
-                logp, action[..., i][..., None].astype(jnp.int32), axis=-1
-            )[..., 0]
+            total = total + _chosen(logp, action[..., i])
         return total
     if config.kind == "bernoulli":
         logp = -jax.nn.softplus(-logits) * action - jax.nn.softplus(logits) * (1 - action)
@@ -174,6 +179,17 @@ def entropy(
         # no closed form)
         base = base + jnp.sum(jnp.log(1.0 - jnp.square(jnp.tanh(logits)) + 1e-6), axis=-1)
     return base
+
+
+def _chosen(logp: jax.Array, index: jax.Array) -> jax.Array:
+    """``logp[..., index]`` as compare-select-reduce over the last axis. XLA
+    fuses it into the log-softmax that made the row; a gather (and in the
+    backward a scatter) pays ~14 ns an index on a TPU v5e whatever it
+    fetches (PERF.md section 6, PR 30). ``where`` and not a one-hot product:
+    a masked entry may be -inf, and -inf * 0 is NaN. Adding exact zeros
+    changes no bit, so this equals the gather for every in-range index."""
+    hit = jnp.arange(logp.shape[-1]) == index[..., None].astype(jnp.int32)
+    return jnp.sum(jnp.where(hit, logp, 0), axis=-1)
 
 
 def _md_slices(config: DistConfig):

@@ -1,3 +1,5 @@
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -200,3 +202,50 @@ def test_evo_dqn_on_device():
     assert np.asarray(fitness).shape == (4,)
     assert np.isfinite(np.asarray(fitness)).all()
     assert int(pop.ring.size[0]) > 0
+
+
+# --------------------------------------------------------------------------- #
+# The generation program picks the chosen action's log-probability without a
+# gather (PERF.md section 6, PR 30). The gather form, as it stood before, is
+# the reference here.
+
+
+def _gather_log_prob(config, logits, action, dist_extra=None, mask=None):
+    assert config.kind == "categorical" and mask is None
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return jnp.take_along_axis(logp, action[..., None].astype(jnp.int32), axis=-1)[..., 0]
+
+
+def _two_generations():
+    """Lowered text of the generation program; fitness of two generations and
+    the actors after them."""
+    evo = make_evo(num_envs=8, rollout_len=8)
+    pop = evo.init_population(jax.random.PRNGKey(0), pop_size=4)
+    gen = evo.make_vmap_generation()
+    text = gen.lower(pop, jax.random.PRNGKey(1)).as_text()
+    fits = []
+    for i in range(2):
+        pop, fitness = gen(pop, jax.random.PRNGKey(100 + i))
+        fits.append(fitness)
+    return text, [np.asarray(x) for x in jax.tree_util.tree_leaves((fits, pop.actor))]
+
+
+def _count_ops(text, op):
+    # the operation, not its `#stablehlo.<op><...>` dimension-numbers attribute
+    return len(re.findall(rf'stablehlo\.{op}"?\(', text))
+
+
+def test_generation_equals_gather_form_and_lowers_without_it(monkeypatch):
+    text, leaves = _two_generations()
+    monkeypatch.setattr(D, "log_prob", _gather_log_prob)
+    ref_text, ref_leaves = _two_generations()
+
+    assert len(leaves) == len(ref_leaves) > 2
+    for ours, ref in zip(leaves, ref_leaves):
+        np.testing.assert_array_equal(ours.view(np.uint32), ref.view(np.uint32))
+
+    # one gather less per call site (_rollout, loss_fn), and loss_fn's
+    # backward loses the scatter the gather transposed to
+    assert "take_along_axis" in ref_text and "take_along_axis" not in text
+    assert _count_ops(ref_text, "gather") - _count_ops(text, "gather") == 2
+    assert _count_ops(ref_text, "scatter") - _count_ops(text, "scatter") == 1
