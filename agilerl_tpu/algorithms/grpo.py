@@ -109,21 +109,27 @@ def make_update_fn(config, tx, lora_scale: float, use_flash: bool,
     scale via its custom partitioning, ops/flash_attention_vjp.py)."""
     if use_fused_loss is None:
         use_fused_loss = use_flash
+    # a dropless expert stack's update has a fifth result: the fullest
+    # expert's rows over the mean, averaged over the expert layers (what
+    # the gauge moe/load_max_over_mean shows); no other stack's program has
+    extra = ({"return_aux": True} if config.is_dropless else {})
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def update(base, lora, opt_state, batch, clip, beta):
         def loss_fn(lo):
-            lp = M.token_logprobs(
+            out = M.token_logprobs(
                 config, base, batch["tokens"], attention_mask=batch["mask"],
                 lora=lo, lora_scale=lora_scale, flash=use_flash,
-                use_pallas=use_fused_loss,
+                use_pallas=use_fused_loss, **extra,
             )
-            return _grpo_loss_core(lp, batch, clip, beta)
+            lp, *aux = out if extra else (out,)
+            loss, kl = _grpo_loss_core(lp, batch, clip, beta)
+            return loss, (kl, *(a[0] / config.n_moe_layers for a in aux))
 
-        (loss, kl), grads = jax.value_and_grad(loss_fn, has_aux=True)(lora)
+        (loss, rest), grads = jax.value_and_grad(loss_fn, has_aux=True)(lora)
         updates, opt_state = tx.update(grads, opt_state, lora)
         lora = optax.apply_updates(lora, updates)
-        return lora, opt_state, loss, kl
+        return (lora, opt_state, loss, *rest)
 
     return update
 
@@ -339,6 +345,11 @@ class GRPO(EvolvableAlgorithm):
         Pass None to detach (restores the pre-attach ``continuous_decode``
         setting — detaching must not leave the agent on a private bare
         generator it never used before)."""
+        if self.model_config.is_mla:
+            raise NotImplementedError(
+                "attach_rollout_fleet over a latent cache: the fleet's "
+                "prefill-to-decode transfer carries K and V arrays, not the "
+                "one latent array; not implemented")
         if self.model_config.is_hybrid:
             raise NotImplementedError(
                 "attach_rollout_fleet over a hybrid stack: the fleet's "
@@ -576,6 +587,12 @@ class GRPO(EvolvableAlgorithm):
         """(logprobs, update) for the active parallelism mode, with the
         sequence-parallel input contract validated against THIS batch."""
         if self.sequence_parallel_axis is not None:
+            if self.model_config.is_mla or self.model_config.is_dropless:
+                raise NotImplementedError(
+                    "sequence_parallel_axis over latent attention or a "
+                    "dropless expert stack: the long-context path runs ring "
+                    "attention over GQA keys and values and shards tokens "
+                    "without an expert exchange; not implemented")
             if self.model_config.is_hybrid:
                 raise NotImplementedError(
                     "sequence_parallel_axis over a hybrid stack: the "
@@ -643,11 +660,17 @@ class GRPO(EvolvableAlgorithm):
                     if rho is not None:
                         batch["rho"] = rho[idx]
                 with PhaseTimer(metrics, "learn/update"):
-                    lora, opt_state, loss, kl = update(
+                    lora, opt_state, loss, kl, *load = update(
                         lora, opt_state, batch, jnp.float32(self.clip_coef),
                         jnp.float32(self.beta),
                     )
                 with PhaseTimer(metrics, "learn/loss_sync"):
+                    if load:  # a dropless expert stack's update alone
+                        metrics.gauge(
+                            "moe/load_max_over_mean",
+                            help="learn: the fullest expert's rows over the "
+                                 "mean, averaged over the expert layers",
+                        ).set(float(load[0]))
                     if not np.isfinite(float(loss)):
                         # the update donated the previous buffers — store the
                         # (live) returned state first so the agent stays
@@ -779,6 +802,15 @@ class GRPO(EvolvableAlgorithm):
                 raise ValueError("to_mesh needs a mesh or a plan")
             plan = PL.grpo_plan_for_mesh(mesh)
         plan, mesh = PL.resolve_plan_and_mesh(plan, mesh)
+        if self.model_config.is_mla or self.model_config.is_dropless:
+            try:
+                plan.shardings("params", self.base_params, mesh, strict=True)
+            except PL.UnmatchedLeafError as err:
+                raise ValueError(
+                    "to_mesh over latent attention or a dropless expert "
+                    "stack: the plan's 'params' rules do not cover their "
+                    "leaves (wkv_a, wkv_b, kv_norm, router_bias, ws_gate, "
+                    f"ws_up, ws_down): {err}") from err
         if self.model_config.is_hybrid:
             # a plan written for attention stacks would silently replicate
             # every state-space leaf: refuse unless it names them all

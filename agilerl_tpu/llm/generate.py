@@ -257,7 +257,9 @@ def paged_decode_step(config, params, carry, *, lora, lora_scale, temperature,
     GRPO flywheel can consume decode-captured behavior logprobs without a
     second forward). Greedy outputs are bit-identical to
     decode_step for a slot whose slab content matches the dense cache (the
-    serving equivalence tests pin this)."""
+    serving equivalence tests pin this). Over a dropless expert stack the
+    ys end with one more member: the distinct experts this step touched,
+    summed over the expert layers (a float32 scalar)."""
     (cache, block_tables, slot_mask, lengths, prev_tok, prev_ok, pos,
      step_idx, done, keys) = carry
     n_slots = prev_tok.shape[0]
@@ -269,10 +271,12 @@ def paged_decode_step(config, params, carry, *, lora, lora_scale, temperature,
     slot_mask = slot_mask.at[
         jnp.arange(n_slots), jnp.minimum(lengths, S - 1)
     ].set(prev_ok.astype(slot_mask.dtype))
-    hidden, new = M.forward_paged(
+    hidden, new, *aux = M.forward_paged(
         config, params, prev_tok[:, None], pos, lengths, cache, block_tables,
         slot_mask, lora=lora, lora_scale=lora_scale,
+        **({"return_aux": True} if config.is_dropless else {}),
     )
+    experts_hit = tuple(a[1] for a in aux)
     # (new_k, new_v), and the new recurrent state too over a hybrid stack
     cache = M.paged_scatter_tokens(cache, block_tables, lengths, *new)
     logits = M.logits_fn(config, params, hidden)[:, 0, :]
@@ -294,5 +298,5 @@ def paged_decode_step(config, params, carry, *, lora, lora_scale, temperature,
     if capture_lp:
         lsm = jax.nn.log_softmax(logits, axis=-1)
         lp = jnp.take_along_axis(lsm, tok[:, None], axis=-1)[:, 0]
-        return carry, (tok, emit, lp)
-    return carry, (tok, emit)
+        return carry, (tok, emit, lp) + experts_hit
+    return carry, (tok, emit) + experts_hit

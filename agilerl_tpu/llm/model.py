@@ -69,7 +69,9 @@ class GPTConfig:
     # the dp/fsdp/tp/sp/ep strategy menu, SURVEY.md §2.8):
     n_experts: int = 0  # 0 = dense FFN everywhere
     expert_top_k: int = 2
-    capacity_factor: float = 1.25
+    # a capacity states the Switch-style bucketed dispatch, which drops what
+    # overflows a bucket; None = the dropless dispatch (llm/moe.py)
+    capacity_factor: Optional[float] = 1.25
     moe_every: int = 1  # layer i is MoE iff (i + 1) % moe_every == 0
     router_aux_weight: float = 0.01
     # Positional encoding of the attention layers: rotary, or none at all
@@ -87,9 +89,71 @@ class GPTConfig:
     mamba_d_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: Optional[int] = None  # None -> ceil(d_model / 16)
+    # Latent attention (llm/mla.py) in place of GQA in every attention layer
+    # iff kv_lora_rank > 0: keys and values are up-projected from a latent
+    # of kv_lora_rank values a token, queries and keys are qk_nope_dim +
+    # qk_rope_dim wide (rotary on the last qk_rope_dim, carried by ONE key
+    # head), values v_head_dim wide. A cache keeps the latent alone.
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # Expert stacks beyond "every moe_every-th layer": the first
+    # n_dense_layers layers keep a dense FFN (d_ff wide) whatever moe_every
+    # says; the experts are d_ff_expert wide (None -> d_ff); the dropless
+    # dispatch's router scores by softmax or sigmoid, may add a frozen
+    # selection bias to the scores for the CHOICE only, renormalises the
+    # chosen weights (norm_topk) and scales them (routed_scale); an
+    # always-on shared expert d_ff_shared wide runs beside the routed ones.
+    n_dense_layers: int = 0
+    d_ff_expert: Optional[int] = None
+    d_ff_shared: int = 0
+    router_score: str = "softmax"
+    router_bias: bool = False
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+
+    def __post_init__(self):
+        routed = (self.router_score != "softmax" or self.router_bias
+                  or not self.norm_topk or self.routed_scale != 1.0
+                  or self.d_ff_shared)
+        if self.n_experts and self.capacity_factor is not None and routed:
+            raise ValueError(
+                "the capacity dispatch (moe.moe_ffn) scores by softmax, "
+                "renormalises and has no shared expert, selection bias or "
+                "scale; state capacity_factor=None for the dropless dispatch")
+        if self.is_mla and (self.is_hybrid or not self.rope or self.qkv_bias):
+            raise ValueError(
+                "latent attention is built for a stack of attention layers "
+                "with rotary positions and no projection bias")
 
     def is_moe_layer(self, i: int) -> bool:
-        return self.n_experts > 0 and (i + 1) % self.moe_every == 0
+        return (self.n_experts > 0 and i >= self.n_dense_layers
+                and (i + 1) % self.moe_every == 0)
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def is_dropless(self) -> bool:
+        return self.n_experts > 0 and self.capacity_factor is None
+
+    @property
+    def n_moe_layers(self) -> int:
+        return sum(self.is_moe_layer(i) for i in range(self.n_layer))
+
+    @property
+    def stores_runs(self) -> bool:
+        """Weights are kept one stacked tree a RUN of equal layers
+        (``params["runs"]``), the layout the programs scan, where stacking
+        per-layer trees inside every program would cost a copy of the base:
+        hybrid stacks, latent attention and dense-then-expert stacks."""
+        return self.is_hybrid or self.is_mla or self.n_dense_layers > 0
+
+    @property
+    def ff_expert(self) -> int:
+        return self.d_ff_expert or self.ff_dim
 
     @property
     def is_hybrid(self) -> bool:
@@ -150,6 +214,8 @@ class KVCache(NamedTuple):
     depth, like the non-cached paths (window-2 finding: the unrolled
     12-layer cached prefill was the repo's last depth-linear program)."""
 
+    # latent attention (GPTConfig.is_mla): k is [L, B, S, latent width],
+    # what llm/mla.py keeps of a token, and v is None
     k: jax.Array  # [L, B, S, KV, hd]
     v: jax.Array  # [L, B, S, KV, hd]
     length: jax.Array  # [] int32 — filled slots
@@ -165,10 +231,20 @@ class KVCache(NamedTuple):
     prev_state: Any = None
 
 
+def _token_shape(config: GPTConfig):
+    """(heads, width) of what a cache keeps of one token in one attention
+    layer, and whether values have an array of their own."""
+    if config.is_mla:
+        # no head axis: [.., block_size, width] tiles whole, where a
+        # second-minor axis of 1 would be padded to a tile's 16 rows
+        return (config.kv_lora_rank + config.qk_rope_dim,), False
+    return (config.kv_heads, config.head_dim), True
+
+
 def init_kv_cache(config: GPTConfig, batch: int, max_len: Optional[int] = None) -> KVCache:
     s = max_len or config.max_seq_len
-    shape = (config.n_layers_of("attn"), batch, s, config.kv_heads,
-             config.head_dim)
+    per_token, has_v = _token_shape(config)
+    shape = (config.n_layers_of("attn"), batch, s, *per_token)
     state = None
     if config.is_hybrid:
         from agilerl_tpu.llm import ssm
@@ -177,7 +253,7 @@ def init_kv_cache(config: GPTConfig, batch: int, max_len: Optional[int] = None) 
                       for kind, _, n in config.layer_runs() if kind == "mamba")
     return KVCache(
         k=jnp.zeros(shape, config.dtype),
-        v=jnp.zeros(shape, config.dtype),
+        v=jnp.zeros(shape, config.dtype) if has_v else None,
         length=jnp.zeros((), jnp.int32),
         mask=jnp.zeros((batch, s), jnp.int32),
         state=state,
@@ -208,6 +284,12 @@ def init_block(key: jax.Array, config: GPTConfig, i: int) -> Params:
         blk = {"ln1": jnp.ones((d,), jnp.float32),
                **ssm.init_mamba_mixer(ks[0], config, out_std),
                "ln2": jnp.ones((d,), jnp.float32)}
+    elif config.is_mla:
+        from agilerl_tpu.llm import mla
+
+        blk = {"ln1": jnp.ones((d,), jnp.float32),
+               **mla.init_mla_mixer(ks[0], config, out_std),
+               "ln2": jnp.ones((d,), jnp.float32)}
     else:
         blk = {
             "ln1": jnp.ones((d,), jnp.float32),
@@ -218,11 +300,20 @@ def init_block(key: jax.Array, config: GPTConfig, i: int) -> Params:
             "ln2": jnp.ones((d,), jnp.float32),
         }
     if config.is_moe_layer(i):
-        E = config.n_experts
+        E, f = config.n_experts, config.ff_expert
         blk["router"] = _normal(ks[7], (d, E), std)
         blk["w_gate"] = _normal(ks[4], (E, d, f), std)
         blk["w_up"] = _normal(ks[5], (E, d, f), std)
         blk["w_down"] = _normal(ks[6], (E, f, d), out_std)
+        extra = jax.random.split(jax.random.fold_in(key, 8), 4)
+        if config.router_bias:
+            # drawn, not zero: a zero bias moves no choice and checks nothing
+            blk["router_bias"] = _normal(extra[0], (E,), std)
+        if config.d_ff_shared:
+            fs = config.d_ff_shared
+            blk["ws_gate"] = _normal(extra[1], (d, fs), std)
+            blk["ws_up"] = _normal(extra[2], (d, fs), std)
+            blk["ws_down"] = _normal(extra[3], (fs, d), out_std)
     else:
         blk["w_gate"] = _normal(ks[4], (d, f), std)
         blk["w_up"] = _normal(ks[5], (d, f), std)
@@ -236,7 +327,8 @@ def init_block(key: jax.Array, config: GPTConfig, i: int) -> Params:
 
 def init_params(key: jax.Array, config: GPTConfig) -> Params:
     """A uniform stack keeps one tree a layer (``params["blocks"][str(i)]``).
-    A hybrid stack's weights are stored in the layout its programs scan: one
+    A hybrid stack's weights (and those of every ``config.stores_runs``
+    stack) are stored in the layout its programs scan: one
     stacked tree a RUN of equal layers (``params["runs"][r]``, leading axis =
     the run's layers, ``config.layer_runs()``'s order) — stacking per-layer
     trees inside a program would cost a copy of the base in every one."""
@@ -249,7 +341,7 @@ def init_params(key: jax.Array, config: GPTConfig) -> Params:
         "tok_emb": _normal(keys[0], (config.vocab_size, d), std),
         "ln_f": jnp.ones((d,), jnp.float32),
     }
-    if config.is_hybrid:
+    if config.stores_runs:
         params["runs"] = [
             jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
                                    *blocks[first:first + n])
@@ -284,13 +376,21 @@ def init_lora(
         "w_down": (config.ff_dim, d),
     }
     ffn_names = ("w_gate", "w_up", "w_down")
+    attention = LORA_TARGETS
+    if config.is_mla:
+        # latent attention has its own projections (no wk / wv) and, in the
+        # stacks that exist, expert layers: no FFN target names anything
+        from agilerl_tpu.llm import mla
+
+        dims = mla.mla_dims(config)
+        attention = tuple(dims)
     if config.n_experts > 0 and any(t in ffn_names for t in targets):
         # MoE FFN weights are expert-stacked [E, ...]; the dense-shaped
         # adapters below would silently never be consulted by the MoE branch
         # of forward (review finding) — refuse loudly instead.
         raise ValueError(
             "LoRA on FFN projections is not supported for MoE layers; "
-            f"restrict targets to attention projections {LORA_TARGETS}"
+            f"restrict targets to attention projections {attention}"
         )
     target_ids = {name: idx for idx, name in enumerate(sorted(dims))}
     by_kind = {"attn": dims}
@@ -410,6 +510,19 @@ def _block_ffn(config: GPTConfig, blk, h, lora_layer, lora_scale):
     B, T = h.shape[:2]
     dtype = h.dtype
     x = _rms(h, blk["ln2"], config.rms_eps)
+    if "router" in blk and config.is_dropless:
+        from agilerl_tpu.llm import moe
+
+        out2d, load = moe.dropless_ffn(
+            x.reshape(B * T, config.d_model), blk,
+            top_k=config.expert_top_k, score=config.router_score,
+            norm_topk=config.norm_topk, scale=config.routed_scale)
+        # what rides the aux channel of a dropless layer is its load, as
+        # [fullest expert's rows over the mean, experts with any row]: no
+        # auxiliary loss exists here (the router is frozen with the base)
+        aux = jnp.stack([load.max() / jnp.maximum(load.mean(), 1.0),
+                         (load > 0).sum()]).astype(jnp.float32)
+        return h + out2d.reshape(B, T, config.d_model), aux
     if "router" in blk:
         from agilerl_tpu.llm.moe import moe_ffn
 
@@ -425,7 +538,13 @@ def _block_ffn(config: GPTConfig, blk, h, lora_layer, lora_scale):
     down = _maybe_lora(
         jax.nn.silu(gate) * up, blk["w_down"], lora_layer, "w_down", lora_scale, dtype
     )
-    return h + down, jnp.zeros((), jnp.float32)
+    return h + down, jnp.zeros(_aux_shape(config), jnp.float32)
+
+
+def _aux_shape(config: GPTConfig):
+    """A layer's aux: the capacity dispatch's balance loss (a scalar), or a
+    dropless layer's [load max over mean, experts hit]."""
+    return (2,) if config.is_dropless else ()
 
 
 def _split_by_runs(config: GPTConfig, kind: str, tree):
@@ -477,7 +596,7 @@ def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
 
     seen = {kind: 0 for kind in fns}
     ys = {kind: [] for kind in fns}
-    aux = jnp.zeros((), jnp.float32)
+    aux = jnp.zeros(_aux_shape(config), jnp.float32)
     for r, (kind, first, n) in enumerate(config.layer_runs()):
         layers = range(first, first + n)
         w = (params["runs"][r] if "runs" in params
@@ -496,13 +615,33 @@ def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
             # also where the run is not scanned: layer() then picks rows,
             # which is what a hybrid stack's runs of one have always lowered
             lo = jax.tree_util.tree_map(stack, *lo)
+        # Where a call's rows cannot touch every expert (a decode step: 8
+        # rows x 6 choices of 128 experts), a dropless expert run's scan
+        # does not slice the experts' weights: a slice of [n, E, d, f]
+        # handed to the grouped matmul is a COPY of all E experts a layer
+        # (1.2 GB a decode step at 128 experts of 2048 x 768, three times
+        # what the step's rows read). They stay whole, closed over, and
+        # layer j addresses its experts as the groups [j E, (j + 1) E) of
+        # one matmul grouped over n E (moe.dropless_experts). A call with
+        # more rows than experts reads a layer's slice whole anyway, and its
+        # backward wants the slice in a layout of its own.
+        held = {}
+        few_rows = h.shape[0] * h.shape[1] * config.expert_top_k \
+            < config.n_experts
+        if scan and few_rows and config.is_dropless and "router" in w:
+            held = {k: w[k] for k in ("w_gate", "w_up", "w_down")}
+            w = {k: v for k, v in w.items() if k not in held}
         if scan:
-            def body(carry, x, fn=fn):
+            def body(carry, x, fn=fn, held=held):
                 h, aux = carry
+                if held:
+                    blk, *x, j = x
+                    x = ({**blk, **held, "expert_layer": j}, *x)
                 hn, y, a = fn(h, *x)
                 return (hn, aux + a), y
 
-            (h, aux), y = jax.lax.scan(body, (h, aux), (w, x_run, lo))
+            xs_run = (w, x_run, lo) + ((jnp.arange(n),) if held else ())
+            (h, aux), y = jax.lax.scan(body, (h, aux), xs_run)
         else:
             outs = []
             for j in range(n):
@@ -528,7 +667,9 @@ def forward(
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Returns (hidden [B, T, D] float32, new cache). With a cache, tokens are
     appended at cache.length (all rows share a length — use left-padding for
-    ragged prompts so positions/masks do the aligning)."""
+    ragged prompts so positions/masks do the aligning). ``return_aux`` adds
+    the layers' summed aux: the capacity dispatch's balance loss, or over a
+    dropless expert stack [sum of load max over mean, experts hit]."""
     B, T = tokens.shape
     dtype = config.dtype
     if attention_mask is None:
@@ -552,6 +693,29 @@ def forward(
     def block_fn(h, blk, layer_kv, lora_layer):
         """layer_kv: (k_cache [B,S,KV,hd], v_cache [B,S,KV,hd]) or None."""
         x = _rms(h, blk["ln1"], config.rms_eps)
+        if config.is_mla:
+            from agilerl_tpu.llm import mla
+
+            q_nope, q_rope, lat = mla.project(
+                config, blk, x, positions, lora_layer, lora_scale)
+            if layer_kv is not None:
+                # the same pre-update discipline as below, on the one array
+                # a latent cache has; every cached forward is absorbed
+                slab = jax.lax.dynamic_update_slice(
+                    layer_kv[0], lat, (0, start, 0))
+                new_kv = (lat, None)
+                attn = mla.attend_absorbed(
+                    config, blk, q_nope, q_rope, slab, cache_mask, start,
+                    lora_layer, lora_scale)
+            else:
+                new_kv = None
+                attn = mla.attend_expanded(
+                    config, blk, q_nope, q_rope, lat, attention_mask,
+                    lora_layer, lora_scale, use_flash)
+            attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale,
+                               dtype)
+            h, aux = _block_ffn(config, blk, h + attn, lora_layer, lora_scale)
+            return h, new_kv, aux
         q, k, v = _qkv_rope(config, blk, x, positions, lora_layer, lora_scale)
 
         if layer_kv is not None:
@@ -659,9 +823,11 @@ def forward(
         # ONE bulk write of the new tokens ([L, B, T, KV, hd]) into the
         # (aliasable) cache buffers
         new_k, new_v = _join_runs(ys["attn"])
+        at = (0, 0, start) + (0,) * (cache.k.ndim - 3)
         new_caches = KVCache(
-            jax.lax.dynamic_update_slice(cache.k, new_k, (0, 0, start, 0, 0)),
-            jax.lax.dynamic_update_slice(cache.v, new_v, (0, 0, start, 0, 0)),
+            jax.lax.dynamic_update_slice(cache.k, new_k, at),
+            None if cache.v is None else jax.lax.dynamic_update_slice(
+                cache.v, new_v, at),
             start + T, cache_mask,
         )
         if config.is_hybrid:
@@ -799,6 +965,9 @@ class PagedKVCache(NamedTuple):
     point their whole block table at it, so masked writes always have a
     legal destination and no compiled program ever branches on occupancy."""
 
+    # latent attention: k is [L, n_blocks, block_size, latent width] and v
+    # is None — one array, 576 values a token a layer where the expanded
+    # keys and values of 32 heads would be 10240
     k: jax.Array  # [L, n_blocks, block_size, KV, hd]
     v: jax.Array  # [L, n_blocks, block_size, KV, hd]
     # Hybrid stacks only (k/v then hold the attention layers alone) — the
@@ -824,8 +993,8 @@ class PagedKVCache(NamedTuple):
 def init_paged_cache(config: GPTConfig, n_blocks: int, block_size: int,
                      slots: Optional[int] = None,
                      snapshots: int = 0) -> PagedKVCache:
-    shape = (config.n_layers_of("attn"), n_blocks, block_size,
-             config.kv_heads, config.head_dim)
+    per_token, has_v = _token_shape(config)
+    shape = (config.n_layers_of("attn"), n_blocks, block_size, *per_token)
     state = snap = None
     if config.is_hybrid:
         if slots is None:
@@ -839,8 +1008,14 @@ def init_paged_cache(config: GPTConfig, n_blocks: int, block_size: int,
         snap = tuple(ssm.init_state(config, n, snapshots + 1)
                      for n in mamba_runs)
     return PagedKVCache(k=jnp.zeros(shape, config.dtype),
-                        v=jnp.zeros(shape, config.dtype),
+                        v=jnp.zeros(shape, config.dtype) if has_v else None,
                         state=state, snap=snap)
+
+
+def paged_block_bytes(cache: PagedKVCache) -> int:
+    """Bytes one physical block holds across all attention layers."""
+    return int(sum(x.size // x.shape[1] * x.dtype.itemsize
+                   for x in (cache.k, cache.v) if x is not None))
 
 
 def state_cache_bytes(cache: PagedKVCache) -> int:
@@ -861,6 +1036,8 @@ def paged_gather(pool_k: jax.Array, pool_v: jax.Array, block_tables: jax.Array):
     B, mb = block_tables.shape
 
     def slab(pool):
+        if pool is None:  # a latent cache has no V array
+            return None
         g = jnp.take(pool, block_tables.reshape(-1), axis=0)
         return g.reshape(B, mb * bs, *pool.shape[2:])
 
@@ -887,12 +1064,15 @@ def paged_scatter_tokens(cache: PagedKVCache, block_tables: jax.Array,
     scan). new_k/new_v: [L, B, KV, hd]; write_pos: [B] logical slot index.
     ``new_state`` (hybrid stacks: forward_paged's third result) replaces the
     per-slot recurrent state whole — rows are slots."""
-    L, nb, bs, KV, hd = cache.k.shape
+    L, nb, bs, *tok = cache.k.shape  # tok: (KV, hd), or (width,) latent
     idx = paged_write_index(block_tables, write_pos, bs)
-    flat_k = cache.k.reshape(L, nb * bs, KV, hd).at[:, idx].set(new_k)
-    flat_v = cache.v.reshape(L, nb * bs, KV, hd).at[:, idx].set(new_v)
-    cache = cache._replace(k=flat_k.reshape(L, nb, bs, KV, hd),
-                           v=flat_v.reshape(L, nb, bs, KV, hd))
+    flat_k = cache.k.reshape(L, nb * bs, *tok).at[:, idx].set(new_k)
+    if cache.v is None:  # a latent cache has no V array
+        cache = cache._replace(k=flat_k.reshape(L, nb, bs, *tok))
+    else:
+        flat_v = cache.v.reshape(L, nb * bs, *tok).at[:, idx].set(new_v)
+        cache = cache._replace(k=flat_k.reshape(L, nb, bs, *tok),
+                               v=flat_v.reshape(L, nb, bs, *tok))
     return cache if new_state is None else cache._replace(state=new_state)
 
 
@@ -929,12 +1109,15 @@ def paged_scatter_prompt(cache: PagedKVCache, block_ids: jax.Array,
                          k_prompt: jax.Array, v_prompt: jax.Array) -> PagedKVCache:
     """Write one request's prefilled prompt KV ([L, Pb, KV, hd], Pb a whole
     number of blocks) into its assigned physical blocks ([Pb // bs])."""
-    L, _, bs, KV, hd = cache.k.shape
+    L, _, bs, *tok = cache.k.shape
     nb_p = k_prompt.shape[1] // bs
-    return cache._replace(
-        k=cache.k.at[:, block_ids].set(k_prompt.reshape(L, nb_p, bs, KV, hd)),
-        v=cache.v.at[:, block_ids].set(v_prompt.reshape(L, nb_p, bs, KV, hd)),
-    )
+
+    def put(pool, prompt):
+        if pool is None:
+            return None
+        return pool.at[:, block_ids].set(prompt.reshape(L, nb_p, bs, *tok))
+
+    return cache._replace(k=put(cache.k, k_prompt), v=put(cache.v, v_prompt))
 
 
 def paged_write_state(cache: PagedKVCache, slot, snap, state,
@@ -957,8 +1140,9 @@ def paged_copy_block(cache: PagedKVCache, src, dst, snap=None,
     the shared original). With ``snap`` and ``slot`` (hybrid stacks) the
     same program also restores snapshot entry ``snap`` into slot ``slot``:
     the recurrent state the re-entering last prompt token starts from."""
-    cache = cache._replace(k=cache.k.at[:, dst].set(cache.k[:, src]),
-                           v=cache.v.at[:, dst].set(cache.v[:, src]))
+    cache = cache._replace(
+        k=cache.k.at[:, dst].set(cache.k[:, src]),
+        v=None if cache.v is None else cache.v.at[:, dst].set(cache.v[:, src]))
     if snap is None:
         return cache
     return cache._replace(state=jax.tree_util.tree_map(
@@ -977,13 +1161,16 @@ def forward_paged(
     # token — including the current token at write_pos (caller pre-sets it)
     lora: Optional[Params] = None,
     lora_scale: float = 2.0,
+    return_aux: bool = False,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """One decode forward over the slot pool: returns (hidden [B, T, D]
     float32, (new_k, new_v)) — the caller scatters the new KV into the pool
     (paged_scatter_tokens / paged_scatter_multi) exactly once. Over a hybrid
     stack the second result has a third member, the new per-slot recurrent
     state, which ``paged_scatter_tokens(cache, tables, pos, *new)`` puts in
-    place of the old.
+    place of the old. Over a latent cache ``new_k`` is the new latent and
+    ``new_v`` None. ``return_aux`` (dropless expert stacks) adds a third
+    result, the layers' summed aux as ``forward`` returns it.
 
     Per-slot `write_pos` is what distinguishes this from forward-with-cache:
     continuous batching admits slots at different times, so there is no
@@ -1007,6 +1194,25 @@ def forward_paged(
     wp_start = write_pos[:, 0] if write_pos.ndim == 2 else write_pos
     arange_b = jnp.arange(B)
 
+    def mla_block_fn(h, blk, layer_kv, lora_layer):
+        from agilerl_tpu.llm import mla
+
+        x = _rms(h, blk["ln1"], config.rms_eps)
+        q_nope, q_rope, lat = mla.project(config, blk, x, pos2d, lora_layer,
+                                          lora_scale)
+        slab, _ = paged_gather(layer_kv[0], None, block_tables)
+        if write_pos.ndim == 2:
+            slab = slab.at[arange_b[:, None], write_pos].set(lat)
+        else:
+            slab = slab.at[arange_b, write_pos].set(lat[:, 0])
+        attn = mla.attend_absorbed(config, blk, q_nope, q_rope, slab,
+                                   slot_mask, wp_start, lora_layer,
+                                   lora_scale)
+        attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale, dtype)
+        h, aux = _block_ffn(config, blk, h + attn, lora_layer, lora_scale)
+        new_kv = (lat if write_pos.ndim == 2 else lat[:, 0], None)
+        return h, new_kv, (aux if config.is_dropless else 0.0)
+
     def block_fn(h, blk, layer_kv, lora_layer):
         x = _rms(h, blk["ln1"], config.rms_eps)
         q, k, v = _qkv_rope(config, blk, x, pos2d, lora_layer, lora_scale)
@@ -1026,11 +1232,11 @@ def forward_paged(
         attn = attn.reshape(B, T, config.n_head * config.head_dim)
         attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale, dtype)
         h = h + attn
-        h, _ = _block_ffn(config, blk, h, lora_layer, lora_scale)
+        h, aux = _block_ffn(config, blk, h, lora_layer, lora_scale)
         new_kv = (k, v) if write_pos.ndim == 2 else (k[:, 0], v[:, 0])
-        return h, new_kv, 0.0
+        return h, new_kv, (aux if config.is_dropless else 0.0)
 
-    fns = {"attn": block_fn}
+    fns = {"attn": mla_block_fn if config.is_mla else block_fn}
     if config.is_hybrid:
         # the second cache kind: rows ARE slots, so a state-space layer
         # reads and writes its slot's record in place — no table, no gather
@@ -1056,11 +1262,13 @@ def forward_paged(
     xs = {"attn": _split_by_runs(config, "attn", (cache.k, cache.v))}
     if config.is_hybrid:
         xs["mamba"] = list(cache.state)
-    h, ys, _ = _run_layers(config, params, lora, h, fns, xs)
+    h, ys, aux = _run_layers(config, params, lora, h, fns, xs)
     h = _rms(h, params["ln_f"], config.rms_eps).astype(jnp.float32)
     new = _join_runs(ys["attn"])
     if config.is_hybrid:
         new += (tuple(ys["mamba"]),)
+    if return_aux:
+        return h, new, aux
     return h, new
 
 
@@ -1083,8 +1291,11 @@ def token_logprobs(
     chunk_size: int = 128,
     use_pallas: bool = False,
     flash: Optional[bool] = None,
+    return_aux: bool = False,
 ) -> jax.Array:
-    """log p(tokens[:, t] | tokens[:, <t]) for t>=1, shape [B, T-1].
+    """log p(tokens[:, t] | tokens[:, <t]) for t>=1, shape [B, T-1]; with
+    ``return_aux`` (log-probabilities, the layers' summed aux as ``forward``
+    returns it).
 
     use_pallas=True routes the lm-head+log-softmax through the fused Pallas
     kernel (ops/fused_loss.py, the Liger replacement). The kernel carries a
@@ -1092,8 +1303,17 @@ def token_logprobs(
     no-grad logprob passes and the differentiable GRPO/DPO training losses
     (Liger parity: its fused losses are differentiable, ref grpo.py:558);
     flash likewise enables the Pallas attention kernel (own VJP)."""
-    hidden, _ = forward(config, params, tokens, attention_mask=attention_mask,
-                        lora=lora, lora_scale=lora_scale, flash=flash)
+    hidden, _, *aux = forward(
+        config, params, tokens, attention_mask=attention_mask, lora=lora,
+        lora_scale=lora_scale, flash=flash, return_aux=return_aux)
+    lp = _hidden_logprobs(config, params, hidden, tokens, temperature,
+                          chunk_size, use_pallas)
+    return (lp, aux[0]) if return_aux else lp
+
+
+def _hidden_logprobs(config, params, hidden, tokens, temperature, chunk_size,
+                     use_pallas):
+    """``token_logprobs`` from the final hidden states [B, T, D]."""
     if use_pallas:
         from agilerl_tpu.ops.fused_loss import fused_token_logprob_diff
 

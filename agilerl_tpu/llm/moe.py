@@ -2,7 +2,16 @@
 the reference has no MoE or expert-parallel path at all, SURVEY.md §2.8 row
 "Expert parallelism: n/a"; this completes the dp/fsdp/tp/sp/ep strategy menu).
 
-TPU-first design: dense capacity-bucketed dispatch — routing is expressed as
+Two dispatches. A configuration that states a capacity
+(``GPTConfig.capacity_factor``) takes ``moe_ffn``, the Switch-style bucketed
+dispatch below: it drops what overflows a bucket, and GSPMD shards it over
+an ``ep`` axis. A configuration that states none takes the dropless path —
+``route`` (score -> choice -> weights), ``dropless_experts`` (sort the
+(token, choice) pairs by expert, one grouped matmul a projection, un-sort
+and weight) and an always-on ``shared`` expert beside it — which serves
+every token whatever the imbalance (``dropless_ffn`` is the whole layer).
+
+The bucketed dispatch: dense capacity-bucketed dispatch — routing is expressed as
 one-hot einsums over static shapes ([tokens, E, C] dispatch/combine tensors),
 so the whole layer is three big MXU matmuls plus elementwise gating. No
 scatter/gather, no dynamic shapes, nothing XLA can't tile. With the stacked
@@ -81,3 +90,107 @@ def moe_ffn(
     mean_prob = probs.mean(axis=0)
     aux = (E * jnp.sum(frac * mean_prob)).astype(jnp.float32)
     return out, aux
+
+
+# --------------------------------------------------------------------------- #
+# The dropless path
+# --------------------------------------------------------------------------- #
+
+ROUTE_SCOPE = "moe/route"
+EXPERTS_SCOPE = "moe/experts"
+SHARED_SCOPE = "moe/shared"
+COMBINE_SCOPE = "moe/combine"
+
+
+def route(x, router_w, select_bias=None, *, top_k: int, score: str = "softmax",
+          norm_topk: bool = True, scale: float = 1.0):
+    """Score -> choice -> weights, all in float32 whatever ``x`` is.
+
+    ``s = score(x @ router_w)`` (``sigmoid`` or ``softmax``); the choice is
+    the top-k of ``s + select_bias`` — the bias moves the CHOICE only —; the
+    weights are ``s`` at the chosen experts, renormalised to sum to one
+    where ``norm_topk``, times ``scale``. Returns (choice [N, k] int32,
+    weights [N, k] float32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"router score {score!r}: sigmoid or softmax")
+    biased = s if select_bias is None else s + select_bias.astype(jnp.float32)
+    _, choice = jax.lax.top_k(biased, top_k)
+    w = jnp.take_along_axis(s, choice, axis=-1)
+    if norm_topk:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return choice.astype(jnp.int32), w * scale
+
+
+def expert_load(choice, n_experts: int):
+    """Rows each expert serves, [E] int32."""
+    return jnp.zeros((n_experts,), jnp.int32).at[choice.reshape(-1)].add(1)
+
+
+def dropless_experts(x, choice, weights, w_gate, w_up, w_down, layer=None):
+    """``out[n] = sum_k weights[n, k] * E_choice[n, k](x[n])`` with every
+    ``E`` a SwiGLU, for ALL N x k pairs: the pairs are sorted by expert, each
+    projection is one grouped matmul over the experts (``lax.ragged_dot``:
+    XLA:TPU tiles the rows by group and reads an expert's weights only where
+    its group is not empty), and the result is un-sorted by the inverse
+    permutation (a gather, no scatter-add) and weighted. x [N, d]; choice,
+    weights [N, k]; w_gate, w_up [E, d, f]; w_down [E, f, d].
+
+    With ``layer`` (a traced index) the weights are a whole run's,
+    ``[n, E, ...]``, and this layer's experts are the groups ``[layer E,
+    (layer + 1) E)`` of a matmul grouped over ``n E``: every other group is
+    empty, so nothing of the other layers is read and nothing is sliced out
+    of the stacked array (``model._run_layers`` says why)."""
+    N, k = choice.shape
+    E = w_gate.shape[-3]
+    dtype = x.dtype
+    with jax.named_scope(ROUTE_SCOPE):
+        flat = choice.reshape(-1)
+        order = jnp.argsort(flat, stable=True)  # pair p = token p // k
+        sizes = expert_load(choice, E)
+        if layer is not None:
+            n = w_gate.shape[0]
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n * E,), jnp.int32), sizes, (layer * E,))
+            w_gate, w_up, w_down = (
+                w.reshape(n * E, *w.shape[2:]) for w in (w_gate, w_up, w_down))
+        xs = jnp.take(x, order // k, axis=0)  # [N k, d] sorted by expert
+    with jax.named_scope(EXPERTS_SCOPE):
+        g = jax.lax.ragged_dot(xs, w_gate.astype(dtype), sizes)
+        u = jax.lax.ragged_dot(xs, w_up.astype(dtype), sizes)
+        y = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_down.astype(dtype), sizes)
+    with jax.named_scope(COMBINE_SCOPE):
+        back = jnp.argsort(order)  # where pair p went
+        y = jnp.take(y, back, axis=0).reshape(N, k, -1)
+        return jnp.einsum("nkd,nk->nd", y, weights.astype(dtype))
+
+
+def shared_expert(x, w_gate, w_up, w_down):
+    """The always-on expert: a plain SwiGLU on every token."""
+    dtype = x.dtype
+    with jax.named_scope(SHARED_SCOPE):
+        return (jax.nn.silu(x @ w_gate.astype(dtype))
+                * (x @ w_up.astype(dtype))) @ w_down.astype(dtype)
+
+
+def dropless_ffn(x, blk, *, top_k: int, score: str, norm_topk: bool,
+                 scale: float):
+    """The whole expert layer on ``x`` [N, d] from a block's weights
+    (``router``, optional ``router_bias``, ``w_gate/w_up/w_down`` stacked
+    over experts, optional ``ws_gate/ws_up/ws_down``). Returns (out [N, d],
+    load [E] int32: the rows each expert served)."""
+    with jax.named_scope(ROUTE_SCOPE):
+        choice, weights = route(
+            x, blk["router"], blk.get("router_bias"), top_k=top_k,
+            score=score, norm_topk=norm_topk, scale=scale)
+    out = dropless_experts(x, choice, weights, blk["w_gate"], blk["w_up"],
+                           blk["w_down"], layer=blk.get("expert_layer"))
+    if "ws_gate" in blk:
+        out = out + shared_expert(x, blk["ws_gate"], blk["ws_up"],
+                                  blk["ws_down"])
+    return out, expert_load(choice, blk["router"].shape[-1])

@@ -56,6 +56,18 @@ _PRESETS: Dict[str, Dict[str, Any]] = {
         attn_layer_period=14, attn_layer_offset=7, mamba_d_state=16,
         mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=160,
     ),
+    # A DeepSeek-V3-class stack at toy sizes, for the CPU tests: latent
+    # attention (no query latent), 1 leading dense layer, then 2 expert
+    # layers of 8 sigmoid-routed experts, 2 a token and none dropped, a
+    # drawn selection bias, one shared expert. Nothing of it is a model.
+    "tiny-mla-moe": dict(
+        vocab_size=256, n_layer=3, n_head=4, d_model=64, d_ff=128,
+        max_seq_len=256, rope_theta=10_000.0, tie_embeddings=False,
+        kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+        n_dense_layers=1, n_experts=8, expert_top_k=2, capacity_factor=None,
+        d_ff_expert=32, d_ff_shared=64, router_score="sigmoid",
+        router_bias=True, norm_topk=True, routed_scale=2.5,
+    ),
 }
 
 

@@ -763,6 +763,22 @@ class ContinuousGenerator:
     ):
         self.config = config
         self.metrics = metrics if metrics is not None else observability.get_registry()
+        if config.is_mla or config.is_dropless:
+            # what this tier does not do over a latent cache or a dropless
+            # expert stack refuses here, by mechanism
+            if as_spec_config(speculate) is not None:
+                raise ValueError(
+                    "speculative decoding over a latent cache or a dropless "
+                    "expert stack: the multi-token verify step "
+                    "(speculate.paged_verify_step, model.paged_scatter_multi) "
+                    "is not carried over to them; not implemented: build "
+                    "the generator with speculate=None")
+            if sharding_plan is not None or mesh is not None:
+                raise ValueError(
+                    "a serving plan has no rule for a latent cache's pool "
+                    "(one array, one head) nor for the leaves of a latent-"
+                    "attention or dropless expert layer (wkv_a, wkv_b, "
+                    "kv_norm, router_bias, ws_*); not implemented on a mesh")
         if config.is_hybrid:
             # what this tier does not do for a stack with state-space
             # layers refuses here, by mechanism, before any program exists
@@ -1000,7 +1016,8 @@ class ContinuousGenerator:
             )
         filled, _tok0, _rv, pos, done0, key_next = carry
         cache = M.paged_scatter_prompt(
-            cache, block_ids, filled.k[:, 0, :Pb], filled.v[:, 0, :Pb])
+            cache, block_ids, filled.k[:, 0, :Pb],
+            None if filled.v is None else filled.v[:, 0, :Pb])
         if self.config.is_hybrid:
             cache = M.paged_write_state(cache, state_ids[0], state_ids[1],
                                         filled.state, filled.prev_state)
@@ -1026,11 +1043,17 @@ class ContinuousGenerator:
         carry = (cache, tables, slot_mask, lengths, prev_tok, prev_ok, pos,
                  step_idx, done, keys)
         carry, ys = jax.lax.scan(step, carry, None, length=self.decode_chunk)
+        hit = ()
+        if self.config.is_dropless:
+            # distinct experts the chunk's steps touched, summed over the
+            # expert layers and the steps: one small integer beside the ys
+            *ys, hits = ys
+            hit = (hits.sum().astype(jnp.int32),)
         if self.capture_logprobs:
             toks, emits, lps = ys
-            return carry, (toks.T, emits.T, lps.T)  # [slots, chunk]
+            return carry, (toks.T, emits.T, lps.T) + hit  # [slots, chunk]
         toks, emits = ys
-        return carry, (toks.T, emits.T)  # [slots, chunk]
+        return carry, (toks.T, emits.T) + hit  # [slots, chunk]
 
     def _verify_impl(self, params, lora, cache, tables, slot_mask, lengths,
                      prev_tok, prev_ok, pos, step_idx, done, keys, drafts,
@@ -1201,6 +1224,12 @@ class ContinuousGenerator:
         TTFT includes prefill + transfer latency. Decode-side admission
         control (free-block watermark, queue, TTFT SLO) applies unless
         ``no_shed``."""
+        if self.config.is_mla:
+            raise NotImplementedError(
+                "submit_prefilled over a latent cache: the prefill worker's "
+                "export and this tier's import (model.paged_scatter_prompt "
+                "through _admit_import) carry K and V arrays, not the one "
+                "latent array; not implemented")
         if self.config.is_hybrid:
             raise NotImplementedError(
                 "submit_prefilled over a hybrid stack: the prefill worker's "
@@ -1318,6 +1347,11 @@ class ContinuousGenerator:
                     "serving/state_cache_bytes",
                     help="recurrent-state cache, slots and snapshots",
                 ).set(M.state_cache_bytes(pool))
+            self.metrics.gauge(
+                "serving/pool_block_bytes",
+                help="bytes one physical block holds across the attention "
+                     "layers, from the pool's own arrays",
+            ).set(M.paged_block_bytes(pool))
             if self.sharding_plan is not None:
                 # kv_paged, NOT kv: the pool's axis 1 is global block ids —
                 # the dense rules' (dp,fsdp) batch entry must never touch it
@@ -1889,6 +1923,9 @@ class ContinuousGenerator:
             del mirrors
         (self._pool, _tables, slot_mask, lengths, prev_tok, prev_ok, pos,
          step_idx, done, keys) = carry
+        experts_hit = None
+        if self.config.is_dropless:
+            *ys, experts_hit = ys
         with PhaseTimer(metrics, "sched/decode_wait"):
             if self.capture_logprobs:
                 toks, emits, lps = ys
@@ -1910,6 +1947,18 @@ class ContinuousGenerator:
             self._step_idx = np.array(step_idx)
             self._done = np.array(done)
             self._keys = np.array(keys)
+            if experts_hit is not None:
+                metrics.counter(
+                    "serving/moe_experts_hit_total",
+                    help="distinct experts decode steps touched, summed "
+                         "over expert layers and steps",
+                ).inc(int(np.asarray(experts_hit)))
+                metrics.counter(
+                    "serving/moe_expert_slots_total",
+                    help="expert layers x experts x decode steps: what the "
+                         "hits are a share of",
+                ).inc(self.config.n_moe_layers * self.config.n_experts
+                      * self.decode_chunk)
         with PhaseTimer(metrics, "sched/account"):
             delivered = 0
             now = time.perf_counter()

@@ -39,25 +39,32 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 
-def _dense_reference(q, k_cache, v_cache, valid, start):
+def _dense_reference(q, k_cache, v_cache, valid, start, scale=None,
+                     v_width=None):
     """Differentiable dense formulation of the same visibility rule — used
     only as the backward path (custom VJP): the chunked forward's
     dynamic-trip-count while_loop is not reverse-differentiable, but its
     output is bit-equal to this dense one, so the VJP of this function AT
     THE SAME INPUTS is the correct gradient."""
     B, T, Hq, d = q.shape
+    if v_width is not None:  # a latent cache [B, S, d]: one head, no axis
+        k_cache = k_cache[:, :, None, :]
     S, Hkv = k_cache.shape[1], k_cache.shape[2]
     rep = Hq // Hkv
     qr = q.reshape(B, T, Hkv, rep, d)
     scores = jnp.einsum(
         "bthrd,bshd->bhrts", qr, k_cache, preferred_element_type=jnp.float32
-    ) / math.sqrt(d)
+    )
+    scores = scores / math.sqrt(d) if scale is None else scores * scale
+    if v_width is not None:
+        v_cache = k_cache[..., :v_width]
     slot = jnp.arange(S)
     # start may be [] (all rows aligned) or [B] (paged slots at
     # heterogeneous depths) — broadcast to per-row either way
@@ -72,11 +79,15 @@ def _dense_reference(q, k_cache, v_cache, valid, start):
         "bhrts,bshd->bhrtd", probs.astype(v_cache.dtype), v_cache,
         preferred_element_type=jnp.float32,
     )
-    return jnp.moveaxis(out, 3, 1).reshape(B, T, Hq, d).astype(q.dtype)
+    return jnp.moveaxis(out, 3, 1).reshape(
+        B, T, Hq, v_cache.shape[-1]).astype(q.dtype)
 
 
 @functools.lru_cache(maxsize=None)
-def _make_chunked(block: int):
+def _make_chunked(block: int, scale=None, v_width=None):
+    if v_width is not None:
+        return _make_chunked_latent(block, scale, v_width)
+
     @jax.custom_vjp
     def f(q, k_cache, v_cache, valid, start):
         return _chunked_impl(q, k_cache, v_cache, valid, start, block)
@@ -101,7 +112,32 @@ def _make_chunked(block: int):
     return f
 
 
-@functools.partial(jax.jit, static_argnames=("block",))
+def _make_chunked_latent(block: int, scale: float, v_width: int):
+    """The same op for a cache whose value is the first ``v_width`` columns
+    of its key (latent attention): one array, read once a chunk."""
+
+    @jax.custom_vjp
+    def f(q, k_cache, valid, start):
+        return _chunked_impl(q, k_cache, None, valid, start, block, scale,
+                             v_width)
+
+    def fwd(q, k_cache, valid, start):
+        return f(q, k_cache, valid, start), (q, k_cache, valid, start)
+
+    def bwd(res, g):
+        q, k_cache, valid, start = res
+        _, vjp = jax.vjp(
+            lambda q_, k_: _dense_reference(q_, k_, None, valid, start, scale,
+                                            v_width), q, k_cache)
+        f0 = jax.dtypes.float0
+        return (*vjp(g), np.zeros(np.shape(valid), f0),
+                np.zeros(np.shape(start), f0))
+
+    f.defvjp(fwd, bwd)
+    return lambda q, k_cache, v_cache, valid, start: f(q, k_cache, valid, start)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "scale", "v_width"))
 def chunked_cached_attention(
     q: jax.Array,        # [B, T, Hq, d] RoPE'd queries (absolute pos start..start+T)
     k_cache: jax.Array,  # [B, S, Hkv, d] cache AFTER inserting this step's K
@@ -112,21 +148,33 @@ def chunked_cached_attention(
     #                      sit at heterogeneous depths
     *,
     block: int = 512,
+    scale: Optional[float] = None,
+    v_width: Optional[int] = None,
 ) -> jax.Array:
     """Returns attention output [B, T, Hq, d] (same visibility rule as the
     dense path: slot j visible to query t iff j <= start[b] + t and valid[j]).
     Reverse-differentiable: grads route through a dense backward (custom
-    VJP) since the dynamic-bound forward loop cannot be transposed."""
-    return _make_chunked(min(block, k_cache.shape[1]))(
+    VJP) since the dynamic-bound forward loop cannot be transposed.
+
+    ``scale`` (default ``1 / sqrt(d)``) multiplies the scores. With
+    ``v_width`` the cache is ``[B, S, d]`` — one head and no head axis —,
+    the value of a slot is the first ``v_width`` columns of its key,
+    ``v_cache`` is None and the output is ``[B, T, Hq, v_width]``: the latent
+    cache of llm/mla.py, where ``d`` is not the published head size."""
+    return _make_chunked(min(block, k_cache.shape[1]), scale, v_width)(
         q, k_cache, v_cache, valid, jnp.asarray(start)
     )
 
 
-def _chunked_impl(q, k_cache, v_cache, valid, start, block):
+def _chunked_impl(q, k_cache, v_cache, valid, start, block, scale=None,
+                  v_width=None):
     B, T, Hq, d = q.shape
-    S, Hkv = k_cache.shape[1], k_cache.shape[2]
+    S = k_cache.shape[1]
+    Hkv = 1 if v_width is not None else k_cache.shape[2]
     rep = Hq // Hkv
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    dv = d if v_width is None else v_width
 
     qr = q.reshape(B, T, Hkv, rep, d)
     t_ids = jnp.arange(T)
@@ -142,7 +190,7 @@ def _chunked_impl(q, k_cache, v_cache, valid, start, block):
 
     m0 = jnp.full((B, Hkv, rep, T), -1e30, jnp.float32)
     l0 = jnp.zeros((B, Hkv, rep, T), jnp.float32)
-    acc0 = jnp.zeros((B, Hkv, rep, T, d), jnp.float32)
+    acc0 = jnp.zeros((B, Hkv, rep, T, dv), jnp.float32)
 
     def chunk_step(i, carry):
         m, l, acc = carry
@@ -152,7 +200,11 @@ def _chunked_impl(q, k_cache, v_cache, valid, start, block):
         # re-read slots below `off` are masked out so nothing double-counts
         off_c = jnp.minimum(off, S - block)
         ks = jax.lax.dynamic_slice_in_dim(k_cache, off_c, block, axis=1)
-        vs = jax.lax.dynamic_slice_in_dim(v_cache, off_c, block, axis=1)
+        if v_width is not None:  # [B, BK, d] -> one head; v a slice of k
+            ks = ks[:, :, None, :]
+            vs = ks[..., :v_width]
+        else:
+            vs = jax.lax.dynamic_slice_in_dim(v_cache, off_c, block, axis=1)
         vm = jax.lax.dynamic_slice_in_dim(valid, off_c, block, axis=1)
 
         scores = jnp.einsum(
@@ -182,4 +234,4 @@ def _chunked_impl(q, k_cache, v_cache, valid, start, block):
     _, l, acc = jax.lax.fori_loop(0, n_chunks, chunk_step, (m0, l0, acc0))
     out = acc / jnp.maximum(l, 1e-30)[..., None]   # [B, Hkv, rep, T, d]
     out = jnp.moveaxis(out, 3, 1)                  # [B, T, Hkv, rep, d]
-    return out.reshape(B, T, Hq, d).astype(q.dtype)
+    return out.reshape(B, T, Hq, dv).astype(q.dtype)

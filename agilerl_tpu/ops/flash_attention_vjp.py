@@ -239,17 +239,18 @@ def _fwd(q, k, v, padding_mask, causal, block_q, block_k, interpret):
     if pltpu is None:  # pragma: no cover
         raise RuntimeError("pallas tpu module unavailable")
     B, H, T, d = q.shape
+    dv = v.shape[-1]  # values may be narrower than queries and keys
     scale = 1.0 / math.sqrt(d)
     block_q, block_k, pad = _prep(q, T, block_q, block_k)
     Tp = T + pad
     qf = _pad_t(q, pad).reshape(B * H, Tp, d)
     kf = _pad_t(k, pad).reshape(B * H, Tp, d)
-    vf = _pad_t(v, pad).reshape(B * H, Tp, d)
+    vf = _pad_t(v, pad).reshape(B * H, Tp, dv)
     with_mask = padding_mask is not None
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
     ]
     args = [qf, kf, vf]
     if with_mask:
@@ -270,21 +271,21 @@ def _fwd(q, k, v, padding_mask, causal, block_q, block_k, interpret):
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tp, d), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Tp, dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, Tp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
-    out4 = out.reshape(B, H, Tp, d)[:, :, :T, :]
+    out4 = out.reshape(B, H, Tp, dv)[:, :, :T, :]
     # lse rides as [B, H, Tp, 1] so the GSPMD partitioning rule can map its
     # leading dims 1:1 onto q's (batch, heads) axes
     return out4, lse.reshape(B, H, Tp, 1)
@@ -447,14 +448,15 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
                 block_k, interpret, dlse=None):
     interpret = resolve_interpret(interpret)
     B, H, T, d = q.shape
+    dv = v.shape[-1]
     scale = 1.0 / math.sqrt(d)
     block_q, block_k, pad = _prep(q, T, block_q, block_k)
     Tp = T + pad
     bh = B * H
     qf = _pad_t(q, pad).reshape(bh, Tp, d)
     kf = _pad_t(k, pad).reshape(bh, Tp, d)
-    vf = _pad_t(v, pad).reshape(bh, Tp, d)
-    dof = _pad_t(do, pad).reshape(bh, Tp, d)
+    vf = _pad_t(v, pad).reshape(bh, Tp, dv)
+    dof = _pad_t(do, pad).reshape(bh, Tp, dv)
     lse = lse.reshape(bh, Tp, 1)  # arrives [B, H, Tp, 1] (partition layout)
     # D_i = rowsum(dO * O); dd sublane-oriented like lse. An lse cotangent
     # (flash_attention_with_lse) enters as dS += p * dlse == dd -= dlse.
@@ -472,8 +474,8 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
     common_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # q by qi
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),  # k by kj
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),  # v by kj
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # do by qi
+        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),  # v by kj
+        pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),  # do by qi
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),  # lse by qi
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),  # dd by qi
     ]
@@ -494,8 +496,8 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
     dkv_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_q, dv), lambda b, j, i: (b, i, 0)),
         pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
         pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
     ]
@@ -503,27 +505,27 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
         dkv_specs.append(
             pl.BlockSpec((1, 1, block_k), lambda b, j, i, H=H: (b // H, 0, j))
         )
-    dk, dv = pl.pallas_call(
+    dk, dvv = pl.pallas_call(
         _dkv_kernel(scale, causal, block_q, block_k, T, with_mask),
         grid=(bh, Tp // block_k, Tp // block_q),
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, Tp, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, Tp, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, Tp, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf, dof, lse, dd, *mask_args)
 
-    unpad = lambda x: x.reshape(B, H, Tp, d)[:, :, :T, :]  # noqa: E731
-    return unpad(dq), unpad(dk), unpad(dv)
+    unpad = lambda x: x.reshape(B, H, Tp, -1)[:, :, :T, :]  # noqa: E731
+    return unpad(dq), unpad(dk), unpad(dvv)
 
 
 flash_attention_diff.defvjp(_fwd_rule, _bwd_rule)

@@ -1,0 +1,22 @@
+"""``learn_mfu`` for a stack of expert layers: useful FLOPs of a
+``GRPO.learn`` call (``counts_moe.grpo_learn_flops`` — ACTIVE parameters
+only: a token's ``k`` routed experts and the shared one, latent attention's
+published mathematics, the untied head, the adapters; a frozen base, remat's
+second forward not counted) over its wall time and the chips' bf16 peak.
+Median over the steps: the share of the whole learn call."""
+
+import statistics
+
+from perfbench import counts_moe
+
+
+def read(ctx):
+    steps = [r for r in ctx.records if "learn_s" in r]
+    if not steps:
+        return None
+    agent = ctx.cell.config["agent"]
+    peak = ctx.cell.chips * ctx.peaks["bf16_flops_per_s"]
+    return 100.0 * statistics.median(
+        counts_moe.grpo_learn_flops(
+            ctx.cell.config, r["row_lengths"], int(agent["lora_rank"]),
+            agent["lora_targets"]) / r["learn_s"] / peak for r in steps)

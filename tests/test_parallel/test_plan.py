@@ -72,10 +72,15 @@ def _assert_spec_trees_equal(got, want):
 # --------------------------------------------------------------------------- #
 
 
-# the hand-built specs know attention stacks; a hybrid preset's state-space
-# leaves have no rule yet (next test)
+def _has_leaves_without_a_rule(name):
+    return preset(name).is_hybrid or preset(name).is_mla
+
+
+# the hand-built specs know GQA attention stacks; a hybrid preset's
+# state-space leaves and a latent-attention / dropless expert preset's have
+# no rule yet (next test)
 @pytest.mark.parametrize(
-    "name", [n for n in preset_names() if not preset(n).is_hybrid])
+    "name", [n for n in preset_names() if not _has_leaves_without_a_rule(n)])
 def test_plan_params_specs_match_handbuilt_for_every_preset(name):
     cfg = preset(name, max_seq_len=128)
     shapes = jax.eval_shape(lambda k: M.init_params(k, cfg),
@@ -86,15 +91,16 @@ def test_plan_params_specs_match_handbuilt_for_every_preset(name):
 
 
 @pytest.mark.parametrize(
-    "name", [n for n in preset_names() if preset(n).is_hybrid])
+    "name", [n for n in preset_names() if _has_leaves_without_a_rule(n)])
 def test_plan_names_the_hybrid_leaves_it_has_no_rule_for(name):
     """What GRPO.to_mesh's refusal rests on: resolved strictly, the GRPO
-    plan lists a hybrid stack's state-space leaves instead of silently
-    replicating them."""
+    plan lists a hybrid stack's state-space leaves (and a latent-attention
+    or dropless expert stack's) instead of silently replicating them."""
     cfg = preset(name, max_seq_len=128)
     shapes = jax.eval_shape(lambda k: M.init_params(k, cfg),
                             jax.random.PRNGKey(0))
-    with pytest.raises(UnmatchedLeafError, match="in_proj|conv_w|A_log"):
+    with pytest.raises(UnmatchedLeafError,
+                       match="in_proj|conv_w|A_log|wkv_a|kv_norm|ws_gate"):
         make_grpo_plan(fsdp=4, tp=2).resolve("params", shapes, strict=True)
 
 
