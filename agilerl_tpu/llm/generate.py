@@ -256,7 +256,7 @@ def paged_decode_step(config, params, carry, *, lora, lora_scale, temperature,
     1.0, no EOS floor: exactly the model.token_logprobs convention, so the
     GRPO flywheel can consume decode-captured behavior logprobs without a
     second forward). Greedy outputs are bit-identical to
-    decode_step for a slot whose slab content matches the dense cache (the
+    decode_step for a slot whose blocks hold what the dense cache holds (the
     serving equivalence tests pin this). Over a dropless expert stack the
     ys end with one more member: the distinct experts this step touched,
     summed over the expert layers (a float32 scalar)."""

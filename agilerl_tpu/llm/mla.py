@@ -20,8 +20,9 @@ no V array. Two forms of one mathematics:
   tier): with ``Wkv_b`` parted a head into ``W_uk`` and ``W_uv``,
   ``q_lat = q_n W_uk^T`` scores straight against the cached ``c``
   (``q_lat . c + q_r . k_r``), the probabilities average ``c`` itself, and
-  ``W_uv`` projects that average: ``chunked_cached_attention`` with H query
-  heads on one latent head whose value is the first ``kv_lora_rank``
+  ``W_uv`` projects that average: ``ops.decode_attention``'s loop (its
+  contiguous or its paged entry, as the caller's cache is laid out) with H
+  query heads on one latent head whose value is the first ``kv_lora_rank``
   columns of its key. An adapter on ``wkv_b`` adds its low-rank part to both
   products; no weight is merged.
 """
@@ -137,12 +138,21 @@ def _lora_ab(lora_layer, dtype, config: GPTConfig):
                         config.qk_nope_dim + config.v_head_dim)
 
 
-def attend_absorbed(config: GPTConfig, blk, q_nope, q_rope, slab, valid,
+def attend_absorbed(config: GPTConfig, blk, q_nope, q_rope, cache, valid,
                     start, lora_layer, lora_scale):
-    """Attention of T queries over a latent slab [B, S, rank + rope] that
-    already holds this call's positions (query t sees slot j iff j <=
-    start[b] + t and valid[b, j]). Returns [B, T, H * v_head_dim]."""
-    from agilerl_tpu.ops.decode_attention import chunked_cached_attention
+    """Attention of T queries over a latent cache (query t sees slot j iff
+    j <= start[b] + t and valid[b, j]). ``cache`` is a slab [B, S, rank +
+    rope] that already holds this call's positions (``model.forward``), or
+    — a paged cache, ``model.forward_paged`` — the tuple (one layer's pool
+    [nb, bs, rank + rope] as it was before this call, block tables [B,
+    max_blocks], this call's latent [B, T, rank + rope], its logical slots
+    ``write_pos`` [B] or [B, T]): the pool is then read through the table a
+    live chunk at a time and no slab is built. Returns [B, T, H *
+    v_head_dim]."""
+    from agilerl_tpu.ops.decode_attention import (
+        chunked_cached_attention,
+        chunked_paged_attention,
+    )
 
     B, T, H = q_nope.shape[:3]
     dtype = q_nope.dtype
@@ -158,9 +168,15 @@ def attend_absorbed(config: GPTConfig, blk, q_nope, q_rope, slab, valid,
                 ab[0]) * lora_scale
         q = jnp.concatenate([q_lat, q_rope], axis=-1)
     with jax.named_scope(ATTEND_SCOPE):
-        o_lat = chunked_cached_attention(
-            q, slab, None, valid, start,
-            scale=1.0 / math.sqrt(nope + config.qk_rope_dim), v_width=r)
+        kw = dict(scale=1.0 / math.sqrt(nope + config.qk_rope_dim), v_width=r)
+        if isinstance(cache, tuple):
+            pool, block_tables, lat, write_pos = cache
+            o_lat = chunked_paged_attention(
+                q, pool, None, block_tables, lat, None, write_pos, valid,
+                start, **kw)
+        else:
+            o_lat = chunked_cached_attention(q, cache, None, valid, start,
+                                             **kw)
     with jax.named_scope(ABSORB_SCOPE):
         out = jnp.einsum("bthc,chv->bthv", o_lat, w[..., nope:])
         if ab is not None:

@@ -1024,26 +1024,6 @@ def state_cache_bytes(cache: PagedKVCache) -> int:
                    jax.tree_util.tree_leaves((cache.state, cache.snap))))
 
 
-def paged_gather(pool_k: jax.Array, pool_v: jax.Array, block_tables: jax.Array):
-    """Materialise per-slot contiguous KV slabs from the pool.
-
-    pool_*: [nb, bs, KV, hd] (ONE layer — called inside the layer scan so the
-    temp is per-layer, not [L, ...]); block_tables: [B, max_blocks] ->
-    ([B, S, KV, hd], ...) with S = max_blocks * bs. This is the gather
-    ROADMAP S3 wants traced on the chip: a [B, S] temp per layer per step,
-    while the RESIDENT allocation stays the shared pool."""
-    bs = pool_k.shape[1]
-    B, mb = block_tables.shape
-
-    def slab(pool):
-        if pool is None:  # a latent cache has no V array
-            return None
-        g = jnp.take(pool, block_tables.reshape(-1), axis=0)
-        return g.reshape(B, mb * bs, *pool.shape[2:])
-
-    return slab(pool_k), slab(pool_v)
-
-
 def paged_write_index(block_tables: jax.Array, write_pos: jax.Array,
                       block_size: int) -> jax.Array:
     """Flat pool index [B] for each slot's write position. Positions past the
@@ -1174,25 +1154,29 @@ def forward_paged(
 
     Per-slot `write_pos` is what distinguishes this from forward-with-cache:
     continuous batching admits slots at different times, so there is no
-    shared scalar cache length. Attention sees the locally-updated slab
-    (gather + in-slab insert), the same pre-update discipline as forward's
-    block_fn; greedy outputs are bit-identical to the dense path because the
-    projection/FFN maths is the SAME code (_qkv_rope/_block_ffn) and masked
-    slab positions contribute exact zeros to the softmax.
+    shared scalar cache length. Attention reads the pool as it was BEFORE
+    this call, through the block table and one live chunk at a time
+    (ops/decode_attention.chunked_paged_attention), with this call's new
+    K/V put into the chunk they fall in — the same pre-update discipline
+    as forward's block_fn, and no array of a slot's whole extent
+    (max_blocks * block_size) is built in any layer. Greedy outputs are
+    bit-identical to the dense path because the projection/FFN maths is the
+    SAME code (_qkv_rope/_block_ffn), the attention loop is the same code
+    too, and masked positions contribute exact zeros to the softmax.
 
     T == 1 is the per-token decode step (positions/write_pos [B]; new KV
     [L, B, KV, hd]). T > 1 is the speculative verify window (positions and
     write_pos [B, T], consecutive per row with write_pos[:, 0] = lengths;
-    new KV [L, B, T, KV, hd]). The in-slab insert places all T candidate
-    K/Vs, and visibility is the SAME rule both ways: query t attends to
-    logical slots <= write_pos[:, 0] + t that slot_mask marks valid, so
-    candidate j sees exactly the prefix plus candidates < j."""
+    new KV [L, B, T, KV, hd]). All T candidate K/Vs go into their chunks
+    (one past the extent goes nowhere), and visibility is the SAME rule
+    both ways: query t attends to logical slots <= write_pos[:, 0] + t that
+    slot_mask marks valid, so candidate j sees exactly the prefix plus
+    candidates < j."""
     B, T = tokens.shape
     dtype = config.dtype
     h = jnp.take(params["tok_emb"], tokens, axis=0).astype(dtype)
     pos2d = positions if positions.ndim == 2 else positions[:, None]
     wp_start = write_pos[:, 0] if write_pos.ndim == 2 else write_pos
-    arange_b = jnp.arange(B)
 
     def mla_block_fn(h, blk, layer_kv, lora_layer):
         from agilerl_tpu.llm import mla
@@ -1200,14 +1184,10 @@ def forward_paged(
         x = _rms(h, blk["ln1"], config.rms_eps)
         q_nope, q_rope, lat = mla.project(config, blk, x, pos2d, lora_layer,
                                           lora_scale)
-        slab, _ = paged_gather(layer_kv[0], None, block_tables)
-        if write_pos.ndim == 2:
-            slab = slab.at[arange_b[:, None], write_pos].set(lat)
-        else:
-            slab = slab.at[arange_b, write_pos].set(lat[:, 0])
-        attn = mla.attend_absorbed(config, blk, q_nope, q_rope, slab,
-                                   slot_mask, wp_start, lora_layer,
-                                   lora_scale)
+        attn = mla.attend_absorbed(
+            config, blk, q_nope, q_rope,
+            (layer_kv[0], block_tables, lat, write_pos), slot_mask,
+            wp_start, lora_layer, lora_scale)
         attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale, dtype)
         h, aux = _block_ffn(config, blk, h + attn, lora_layer, lora_scale)
         new_kv = (lat if write_pos.ndim == 2 else lat[:, 0], None)
@@ -1216,19 +1196,11 @@ def forward_paged(
     def block_fn(h, blk, layer_kv, lora_layer):
         x = _rms(h, blk["ln1"], config.rms_eps)
         q, k, v = _qkv_rope(config, blk, x, pos2d, lora_layer, lora_scale)
-        k_slab, v_slab = paged_gather(layer_kv[0], layer_kv[1], block_tables)
-        if write_pos.ndim == 2:
-            # multi-token insert: out-of-extent rows (a released slot whose
-            # lengths ran past S) drop — jax scatter OOB semantics
-            k_slab = k_slab.at[arange_b[:, None], write_pos].set(k)
-            v_slab = v_slab.at[arange_b[:, None], write_pos].set(v)
-        else:
-            k_slab = k_slab.at[arange_b, write_pos].set(k[:, 0])
-            v_slab = v_slab.at[arange_b, write_pos].set(v[:, 0])
-        from agilerl_tpu.ops.decode_attention import chunked_cached_attention
+        from agilerl_tpu.ops.decode_attention import chunked_paged_attention
 
-        attn = chunked_cached_attention(q, k_slab, v_slab, slot_mask,
-                                        wp_start)
+        attn = chunked_paged_attention(q, layer_kv[0], layer_kv[1],
+                                       block_tables, k, v, write_pos,
+                                       slot_mask, wp_start)
         attn = attn.reshape(B, T, config.n_head * config.head_dim)
         attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale, dtype)
         h = h + attn

@@ -729,7 +729,7 @@ class ContinuousGenerator:
     Greedy decode is token-for-token identical to ``llm/generate.generate``
     at the same prompt bucket: prefill is the SAME prefill_head at the same
     cache extent, and the paged decode runs the same projection/FFN code
-    with masked slab positions contributing exact zeros."""
+    with masked positions contributing exact zeros."""
 
     def __init__(
         self,

@@ -14,6 +14,27 @@ and materialized a GQA-repeated copy of K/V. This op fixes both:
 - GQA folded into the einsum (q reshaped [B,T,Hkv,rep,d]) so K/V are never
   repeated in HBM.
 
+Two fetches, one loop. The loop (``_online_softmax``) is handed a function
+that fetches chunk ``i``'s keys and values, and that is all its two entries
+differ in:
+
+- ``chunked_cached_attention`` — a contiguous cache [B, S, ...] that already
+  holds this call's K/V (the dense and bucketed tiers, ``model.forward``):
+  chunk ``i`` is a ``dynamic_slice`` of it. Reverse-differentiable (custom
+  VJP through the dense formulation).
+- ``chunked_paged_attention`` — a block pool [nb, bs, ...] and a block table
+  [B, max_blocks] (the continuous tier, ``model.forward_paged``): chunk
+  ``i`` is ``block // bs`` pool blocks taken through the table, with this
+  call's new K/V put into the chunk by a select. No array of a slot's full
+  extent ``max_blocks * bs`` is ever built: a gather in front of the op
+  would undo the dynamic bound (it is as large as the layer's whole pool
+  at the benchmark's sizes). Forward-only. Its loop runs under the scope
+  ``paged/attend``.
+
+Both layouts of a cache go through both entries: K and V with a head axis
+(``[.., Hkv, d]``), or one latent array without one whose value is the first
+``v_width`` columns of its key (llm/mla.py).
+
 Two callers share this op with different window shapes, both covered by the
 same visibility rule (slot j visible to query t iff j <= start[b] + t and
 valid[j]):
@@ -21,18 +42,23 @@ valid[j]):
 - plain decode: T = 1, ``start`` = per-row cache depth before the step;
 - speculative verify (llm/speculate.py): T = K + 1 — the committed last token
   plus K draft tokens are scored in ONE forward, with ``start`` = per-row
-  depth of the committed prefix and the window's K/V already inserted at
-  slots start[b]..start[b]+T-1. Query t attends to the committed prefix plus
+  depth of the committed prefix and the window's K/V at slots
+  start[b]..start[b]+T-1. Query t attends to the committed prefix plus
   the first t window tokens, exactly as if the drafts had been decoded one
   step at a time — which is what makes accept/reject token-exact.
 
 Numerics match the dense masked-softmax path bit-for-bit at f32 accumulation
 (tests/test_ops/test_decode_attention.py, incl. the per-row-start T>1
-verify-window case). A Pallas kernel is deliberately NOT
-used here: with BlockSpec pipelining the operand fetch for a grid step happens
-whether or not ``pl.when`` skips the compute, so a static-grid Pallas kernel
-cannot skip the dead cache tail — the dynamic-bound XLA loop can, and the
-per-chunk math (two matmuls + exp) is already fused by XLA.
+verify-window case), and the paged entry equals gather + insert + the
+contiguous entry bit for bit (same file). A Pallas kernel is deliberately
+NOT used here: with BlockSpec pipelining the operand fetch for a grid step
+happens whether or not ``pl.when`` skips the compute, so a static-grid
+Pallas kernel cannot skip the dead cache tail — the dynamic-bound XLA loop
+can, and the per-chunk math (two matmuls + exp) is already fused by XLA. A
+paged kernel would have to take the block table as a scalar-prefetch
+operand and the live length as its grid bound; what it could still win is
+the chunk written out once between the pool and the matmuls
+(``paged_attend_share`` says what that is worth before anyone writes it).
 """
 
 from __future__ import annotations
@@ -44,6 +70,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+PAGED_SCOPE = "paged/attend"
 
 
 def _dense_reference(q, k_cache, v_cache, valid, start, scale=None,
@@ -168,9 +196,98 @@ def chunked_cached_attention(
 
 def _chunked_impl(q, k_cache, v_cache, valid, start, block, scale=None,
                   v_width=None):
-    B, T, Hq, d = q.shape
+    """The contiguous fetch: chunk i is a slice of a cache that already
+    holds this call's K/V."""
     S = k_cache.shape[1]
     Hkv = 1 if v_width is not None else k_cache.shape[2]
+
+    def fetch(off_c):
+        ks = jax.lax.dynamic_slice_in_dim(k_cache, off_c, block, axis=1)
+        if v_cache is None:
+            return ks, None
+        return ks, jax.lax.dynamic_slice_in_dim(v_cache, off_c, block, axis=1)
+
+    return _online_softmax(q, fetch, S, Hkv, valid, start, None, block,
+                           scale, v_width)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "scale", "v_width"))
+def chunked_paged_attention(
+    q: jax.Array,             # [B, T, Hq, d] RoPE'd queries
+    pool_k: jax.Array,        # [nb, bs, Hkv, d] ONE layer's pool, BEFORE this
+    pool_v: jax.Array,        # call's write; latent: [nb, bs, d] and None
+    block_tables: jax.Array,  # [B, max_blocks] logical block -> pool block
+    new_k: jax.Array,         # [B, T, Hkv, d] this call's K (latent [B, T, d])
+    new_v: jax.Array,         # [B, T, Hkv, d], or None (latent)
+    write_pos: jax.Array,     # [B] or [B, T] logical slot of each new token
+    valid: jax.Array,         # [B, S] 1 = slot holds a real token, S =
+    #                           max_blocks * bs; the new tokens' slots set
+    start,                    # [B] (or []) depth before this call
+    *,
+    block: int = 512,
+    scale: Optional[float] = None,
+    v_width: Optional[int] = None,
+) -> jax.Array:
+    """``chunked_cached_attention`` over a block pool: the same loop, chunk
+    boundaries, masks and order of accumulation, with chunk ``i`` taken
+    from the pool through ``block_tables`` (``block // bs`` entries at the
+    chunk's clamped offset) and this call's new K/V put into it where
+    ``write_pos`` falls inside — a position in no chunk (a released slot's
+    runaway length, a verify window past the extent) is dropped. Bit-equal
+    to gathering every slot's whole extent, inserting the new K/V and
+    calling the contiguous entry with the same ``block``, on every row that
+    has a valid slot; no array of the whole extent is built, and a chunk
+    past the deepest such row is neither gathered nor read.
+
+    The loop's bound is the deepest LIVE row: a released slot's length keeps
+    advancing (``generate.paged_decode_step``) and its mask row is all zero,
+    so it is left out of the bound — its own output is then an average over
+    the sink block for as many chunks as the live rows need, which nobody
+    reads. ``block`` is cut to a whole number of pool blocks (at least one,
+    at most the table). Forward-only: no caller differentiates a paged
+    forward (the learn step runs ``model.forward`` without a cache), and the
+    dynamic-bound loop has no transpose."""
+    bs = pool_k.shape[1]
+    B, mb = block_tables.shape
+    T = q.shape[1]
+    per = max(1, min(block // bs, mb))  # pool blocks a chunk
+    slot = jnp.arange(per * bs)
+    if write_pos.ndim == 1:
+        write_pos = write_pos[:, None]
+
+    def fetch(off_c):
+        # off_c is a whole number of pool blocks: block is, and so is S
+        rows = jax.lax.dynamic_slice_in_dim(
+            block_tables, off_c // bs, per, axis=1).reshape(-1)
+        here = slot[None, :, None] == (write_pos - off_c)[:, None, :]
+
+        def chunk(pool, new):
+            # clip, not jnp.take's default fill: a table holds pool blocks,
+            # and the fill's select is a pass of its own over the chunk
+            got = jnp.take(pool, rows, axis=0, mode="clip").reshape(
+                B, per * bs, *pool.shape[2:])
+            for t in range(T):  # T is 1, or a verify window of a few tokens
+                at = jnp.expand_dims(here[:, :, t], range(2, got.ndim))
+                got = jnp.where(at, new[:, t][:, None], got)
+            return got
+
+        return chunk(pool_k, new_k), (None if pool_v is None
+                                      else chunk(pool_v, new_v))
+
+    with jax.named_scope(PAGED_SCOPE):
+        return _online_softmax(
+            q, fetch, mb * bs, 1 if v_width is not None else pool_k.shape[2],
+            valid, jnp.asarray(start), jnp.any(valid.astype(bool), axis=1),
+            per * bs, scale, v_width)
+
+
+def _online_softmax(q, fetch, S, Hkv, valid, start, live_rows, block, scale,
+                    v_width):
+    """The one loop both entries run. ``fetch(off_c)`` returns the keys and
+    values of slots ``off_c .. off_c + block`` ([B, block, Hkv, d] each; a
+    latent cache's [B, block, d] and None). ``live_rows`` ([B] bool, or
+    None for all) are the rows whose depth bounds the loop."""
+    B, T, Hq, d = q.shape
     rep = Hq // Hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
@@ -183,7 +300,9 @@ def _chunked_impl(q, k_cache, v_cache, valid, start, block, scale=None,
     # the loop bound must cover the DEEPEST row — shallower rows' extra
     # chunks are fully masked and contribute exact zeros
     start_b = jnp.broadcast_to(jnp.asarray(start), (B,))
-    live = jnp.max(start_b) + T  # number of potentially-visible slots
+    deepest = jnp.max(start_b if live_rows is None
+                      else jnp.where(live_rows, start_b, 0))
+    live = deepest + T  # number of potentially-visible slots
     n_chunks = jnp.minimum(
         (live + block - 1) // block, -(-S // block)
     ).astype(jnp.int32)
@@ -199,12 +318,10 @@ def _chunked_impl(q, k_cache, v_cache, valid, start, block, scale=None,
         # (no padding — a pad would COPY the whole cache every call); the
         # re-read slots below `off` are masked out so nothing double-counts
         off_c = jnp.minimum(off, S - block)
-        ks = jax.lax.dynamic_slice_in_dim(k_cache, off_c, block, axis=1)
+        ks, vs = fetch(off_c)
         if v_width is not None:  # [B, BK, d] -> one head; v a slice of k
             ks = ks[:, :, None, :]
             vs = ks[..., :v_width]
-        else:
-            vs = jax.lax.dynamic_slice_in_dim(v_cache, off_c, block, axis=1)
         vm = jax.lax.dynamic_slice_in_dim(valid, off_c, block, axis=1)
 
         scores = jnp.einsum(

@@ -9,6 +9,7 @@ import pytest
 
 from agilerl_tpu.llm import model as M
 from agilerl_tpu.llm.serving import BlockAllocator
+from tests.test_ops.test_decode_attention import paged_gather
 
 pytestmark = pytest.mark.serving
 
@@ -28,7 +29,7 @@ def test_scatter_gather_roundtrip():
     # two blocks placed out of order in the pool
     pool = M.paged_scatter_prompt(pool, jnp.asarray([5, 2], np.int32), kp, vp)
     tables = jnp.asarray([[5, 2, 0]], np.int32)
-    k_slab, v_slab = M.paged_gather(pool.k[:, :][0], pool.v[0], tables)
+    k_slab, v_slab = paged_gather(pool.k[:, :][0], pool.v[0], tables)
     np.testing.assert_array_equal(np.asarray(k_slab[0, :8]),
                                   np.asarray(kp[0]))
     np.testing.assert_array_equal(np.asarray(v_slab[0, :8]),

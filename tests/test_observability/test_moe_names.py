@@ -43,6 +43,22 @@ def test_the_programs_carry_the_seven_names(params):
     assert "mla/expand" not in step
 
 
+def test_the_paged_forward_attends_under_both_names(params):
+    """Over a latent pool the chunk loop runs under latent attention's own
+    ``mla/attend`` AND the paged forward's ``paged/attend`` (the name
+    ``paged_attend_share`` reads), with ``mla/absorb`` around it."""
+    slots, bs, mb = 2, 8, 3
+    pool = M.init_paged_cache(CFG, 1 + slots * mb, bs)
+    assert pool.v is None
+    ints = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    step = text(lambda p, c: M.forward_paged(
+        CFG, p, ints(slots, 1), ints(slots), ints(slots), c,
+        ints(slots, mb), ints(slots, mb * bs))[0], params, pool)
+    assert "mla/attend/jit(chunked_paged_attention)" in step
+    assert "paged/attend/while" in step and "mla/absorb" in step
+    assert all(name in step for name in MOE) and "mla/expand" not in step
+
+
 def generator(config, reg):
     return ContinuousGenerator(
         config, max_new_tokens=4, prompt_buckets=(8,), slots=2, block_size=8,

@@ -150,14 +150,29 @@ def paged_tier(v5e):
     return (gen,) + _on(v5e, ((params, lora, pool) + slot_state, drafts))
 
 
+def _whole_extent_results(gen, text):
+    """Compiled operations whose result is K or V of every slot at the
+    slot's whole extent (``bf16[8,2816,4,128]`` at these sizes): what the
+    paged forward gathered, copied and scattered into a layer a step until
+    its attention loop took to reading the pool a live chunk at a time."""
+    slab = rf"bf16\[{gen.slots},{gen.max_blocks * gen.block_size},4,128\]"
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= {slab}", line)]
+
+
 def test_paged_decode_chunk_at_qwen2_7b_widths(paged_tier):
     gen, state, _ = paged_tier
     text = _compiled_text(gen._decode, *state, greedy=False)
     # decode attention is the XLA dynamic-trip-count loop, not a kernel
     assert "while" in text and KERNEL not in text
+    assert (gen.slots, gen.max_blocks * gen.block_size) == (8, 2816)
+    assert not _whole_extent_results(gen, text)
+    # what the loop fetches instead: one chunk of 16 pool blocks a slot
+    assert re.search(r"= bf16\[8,512,4,128\]", text)
 
 
 def test_paged_verify_step_at_qwen2_7b_widths(paged_tier):
     gen, state, drafts = paged_tier
     text = _compiled_text(gen._verify, *state, *drafts, greedy=False)
     assert "while" in text and KERNEL not in text
+    assert not _whole_extent_results(gen, text)
