@@ -355,6 +355,12 @@ class GRPO(EvolvableAlgorithm):
                 "attach_rollout_fleet over a hybrid stack: the fleet's "
                 "prefill-to-decode transfer carries prompt KV only, not the "
                 "state-space layers' recurrent state; not implemented")
+        if self.model_config.is_cca:
+            raise NotImplementedError(
+                "attach_rollout_fleet over a CCA stack: the fleet's "
+                "prefill-to-decode transfer carries prompt KV only, not the "
+                "attention layers' rolling state (convolution windows and "
+                "the previous token's value half); not implemented")
         if fleet is None:
             if self.rollout_fleet is not None:
                 self.continuous_decode = self._pre_fleet_continuous_decode
@@ -587,6 +593,13 @@ class GRPO(EvolvableAlgorithm):
         """(logprobs, update) for the active parallelism mode, with the
         sequence-parallel input contract validated against THIS batch."""
         if self.sequence_parallel_axis is not None:
+            if self.model_config.is_cca:
+                raise NotImplementedError(
+                    "sequence_parallel_axis over a CCA stack: the "
+                    "long-context path splits the sequence across chips, "
+                    "and a shard's first positions would lose the rolling "
+                    "state (the previous shard's last convolution inputs "
+                    "and value half); not implemented")
             if self.model_config.is_mla or self.model_config.is_dropless:
                 raise NotImplementedError(
                     "sequence_parallel_axis over latent attention or a "
@@ -802,6 +815,14 @@ class GRPO(EvolvableAlgorithm):
                 raise ValueError("to_mesh needs a mesh or a plan")
             plan = PL.grpo_plan_for_mesh(mesh)
         plan, mesh = PL.resolve_plan_and_mesh(plan, mesh)
+        if self.model_config.is_cca:
+            try:
+                plan.shardings("params", self.base_params, mesh, strict=True)
+            except PL.UnmatchedLeafError as err:
+                raise ValueError(
+                    "to_mesh over a CCA stack: the plan's 'params' rules do "
+                    "not cover its leaves (wv1, wv2, conv0_w, conv1_w, tau, "
+                    f"router_in, router_w1, merge1, ...): {err}") from err
         if self.model_config.is_mla or self.model_config.is_dropless:
             try:
                 plan.shardings("params", self.base_params, mesh, strict=True)

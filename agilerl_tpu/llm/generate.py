@@ -107,8 +107,9 @@ def prefill_head(config, params, prompt, prompt_mask, caches, key, *,
     padding rows are born done); None means every row is real.
     return_logits=True appends the raw last-position logits [B, V] to the
     return (the serving tier's behavior-logprob capture hook).
-    keep_prev_state=True leaves a hybrid stack's recurrent state from BEFORE
-    the last prompt token in the returned cache (the continuous tier
+    keep_prev_state=True leaves the state of layers that keep one (a hybrid
+    stack's recurrent state, a CCA stack's rolling state) from BEFORE the
+    last prompt token in the returned cache (the continuous tier
     snapshots it for prefix-cache hits); otherwise it is dropped, so the
     decode loops carry the current state alone.
 
@@ -277,7 +278,7 @@ def paged_decode_step(config, params, carry, *, lora, lora_scale, temperature,
         **({"return_aux": True} if config.is_dropless else {}),
     )
     experts_hit = tuple(a[1] for a in aux)
-    # (new_k, new_v), and the new recurrent state too over a hybrid stack
+    # (new_k, new_v), and the new per-slot state too where layers keep one
     cache = M.paged_scatter_tokens(cache, block_tables, lengths, *new)
     logits = M.logits_fn(config, params, hidden)[:, 0, :]
     pos = pos + prev_ok.astype(pos.dtype)

@@ -112,6 +112,22 @@ class GPTConfig:
     router_bias: bool = False
     norm_topk: bool = True
     routed_scale: float = 1.0
+    # The head size where the model states one (None -> d_model // n_head):
+    # attention may run narrower or wider than the residual stream.
+    head_size: Optional[int] = None
+    # Compressed convolutional attention (llm/cca.py) in every attention
+    # layer iff cca_time0 > 0: the kernel sizes of the depthwise and of the
+    # head-wise causal convolution over the query/key latents. Such a layer
+    # keeps a rolling per-sequence state beside its K/V. What follows is
+    # read by a CCA stack's layers alone: the share of a head that rotary
+    # turns; a router that is an MLP router_hidden wide over a state carried
+    # from layer to layer (llm/moe.mlp_logits; 0 = x @ router); a learned
+    # per-channel scale and bias on both arms of every residual merge.
+    cca_time0: int = 0
+    cca_time1: int = 0
+    rotary_share: float = 1.0
+    router_hidden: int = 0
+    scaled_merge: bool = False
 
     def __post_init__(self):
         routed = (self.router_score != "softmax" or self.router_bias
@@ -126,6 +142,24 @@ class GPTConfig:
             raise ValueError(
                 "latent attention is built for a stack of attention layers "
                 "with rotary positions and no projection bias")
+        if self.is_cca and (
+                self.is_mla or self.is_hybrid or self.qkv_bias or not self.rope
+                or self.cca_time1 < 1 or self.kv_heads % 2
+                or (self.n_experts and self.capacity_factor is not None)):
+            raise ValueError(
+                "compressed convolutional attention is built for a stack of "
+                "attention layers with rotary positions, an even number of "
+                "key heads, two convolutions, no projection bias, no latent "
+                "cache, no state-space layers and the dropless dispatch")
+        if not self.is_cca and (self.rotary_share != 1.0 or self.router_hidden
+                                or self.scaled_merge):
+            raise ValueError(
+                "rotary_share, router_hidden and scaled_merge are read by a "
+                "CCA stack's layers alone (cca_time0 > 0)")
+        if self.router_hidden and not self.is_dropless:
+            raise ValueError(
+                "a router MLP scores for the dropless dispatch: state "
+                "n_experts and capacity_factor=None")
 
     def is_moe_layer(self, i: int) -> bool:
         return (self.n_experts > 0 and i >= self.n_dense_layers
@@ -134,6 +168,19 @@ class GPTConfig:
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def is_cca(self) -> bool:
+        return self.cca_time0 > 0
+
+    @property
+    def state_kind(self) -> Optional[str]:
+        """The kind of layer that keeps a fixed-size state a sequence beside
+        (or in place of) paged K/V: the state-space layers of a hybrid
+        stack, the attention layers of a CCA stack; None = no such layer."""
+        if self.is_hybrid:
+            return "mamba"
+        return "attn" if self.is_cca else None
 
     @property
     def is_dropless(self) -> bool:
@@ -148,8 +195,9 @@ class GPTConfig:
         """Weights are kept one stacked tree a RUN of equal layers
         (``params["runs"]``), the layout the programs scan, where stacking
         per-layer trees inside every program would cost a copy of the base:
-        hybrid stacks, latent attention and dense-then-expert stacks."""
-        return self.is_hybrid or self.is_mla or self.n_dense_layers > 0
+        hybrid stacks, latent attention, CCA and dense-then-expert stacks."""
+        return (self.is_hybrid or self.is_mla or self.is_cca
+                or self.n_dense_layers > 0)
 
     @property
     def ff_expert(self) -> int:
@@ -197,7 +245,7 @@ class GPTConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_head
+        return self.head_size or self.d_model // self.n_head
 
     @property
     def ff_dim(self) -> int:
@@ -226,7 +274,10 @@ class KVCache(NamedTuple):
     # a multi-token forward just ran — before it (llm/ssm.py):
     # one (conv [n, B, k-1, d_inner], ssm [n, B, d_state, d_inner] float32)
     # a run of n state-space layers — a run's scan reads and writes its own
-    # arrays whole, with no slicing or joining of one big array
+    # arrays whole, with no slicing or joining of one big array.
+    # CCA stacks (k/v hold every layer, as ever): the attention layers'
+    # rolling state (llm/cca.py), one (p window, c0 window, previous value)
+    # a run of layers, under the same two names
     state: Any = None
     prev_state: Any = None
 
@@ -241,22 +292,31 @@ def _token_shape(config: GPTConfig):
     return (config.kv_heads, config.head_dim), True
 
 
+def _init_state(config: GPTConfig, batch: int):
+    """Zero state of the layers that keep one (``config.state_kind``): one
+    entry a run of such layers, ``batch`` sequences each; None for a stack
+    without them."""
+    if config.state_kind is None:
+        return None
+    if config.is_hybrid:
+        from agilerl_tpu.llm import ssm as mixer
+    else:
+        from agilerl_tpu.llm import cca as mixer
+    return tuple(mixer.init_state(config, n, batch)
+                 for kind, _, n in config.layer_runs()
+                 if kind == config.state_kind)
+
+
 def init_kv_cache(config: GPTConfig, batch: int, max_len: Optional[int] = None) -> KVCache:
     s = max_len or config.max_seq_len
     per_token, has_v = _token_shape(config)
     shape = (config.n_layers_of("attn"), batch, s, *per_token)
-    state = None
-    if config.is_hybrid:
-        from agilerl_tpu.llm import ssm
-
-        state = tuple(ssm.init_state(config, n, batch)
-                      for kind, _, n in config.layer_runs() if kind == "mamba")
     return KVCache(
         k=jnp.zeros(shape, config.dtype),
         v=jnp.zeros(shape, config.dtype) if has_v else None,
         length=jnp.zeros((), jnp.int32),
         mask=jnp.zeros((batch, s), jnp.int32),
-        state=state,
+        state=_init_state(config, batch),
     )
 
 
@@ -290,6 +350,12 @@ def init_block(key: jax.Array, config: GPTConfig, i: int) -> Params:
         blk = {"ln1": jnp.ones((d,), jnp.float32),
                **mla.init_mla_mixer(ks[0], config, out_std),
                "ln2": jnp.ones((d,), jnp.float32)}
+    elif config.is_cca:
+        from agilerl_tpu.llm import cca
+
+        blk = {"ln1": jnp.ones((d,), jnp.float32),
+               **cca.init_cca_mixer(ks[0], config, out_std),
+               "ln2": jnp.ones((d,), jnp.float32)}
     else:
         blk = {
             "ln1": jnp.ones((d,), jnp.float32),
@@ -301,7 +367,12 @@ def init_block(key: jax.Array, config: GPTConfig, i: int) -> Params:
         }
     if config.is_moe_layer(i):
         E, f = config.n_experts, config.ff_expert
-        blk["router"] = _normal(ks[7], (d, E), std)
+        if config.router_hidden:
+            from agilerl_tpu.llm import moe
+
+            blk.update(moe.init_router_mlp(ks[7], d, config.router_hidden, E))
+        else:
+            blk["router"] = _normal(ks[7], (d, E), std)
         blk["w_gate"] = _normal(ks[4], (E, d, f), std)
         blk["w_up"] = _normal(ks[5], (E, d, f), std)
         blk["w_down"] = _normal(ks[6], (E, f, d), out_std)
@@ -322,6 +393,16 @@ def init_block(key: jax.Array, config: GPTConfig, i: int) -> Params:
         blk["bq"] = jnp.zeros((nh * hd,), jnp.float32)
         blk["bk"] = jnp.zeros((nkv * hd,), jnp.float32)
         blk["bv"] = jnp.zeros((nkv * hd,), jnp.float32)
+    if config.scaled_merge:
+        # rows: residual scale, residual bias, sublayer scale, sublayer
+        # bias. Scales drawn around 1 and biases around 0, not AT them: a
+        # unit scale or a zero bias checks nothing. The biases are small
+        # beside the embedding's 0.02: they add up over 4 arms a layer, and
+        # a stream they dominate routes every token alike
+        for j, name in enumerate(("merge1", "merge2")):
+            noise = _normal(jax.random.fold_in(key, 9 + j), (4, d), 1.0)
+            blk[name] = (noise * jnp.asarray([0.05, 0.002, 0.05, 0.002])[:, None]
+                         + jnp.asarray([1.0, 0.0, 1.0, 0.0])[:, None])
     return blk
 
 
@@ -383,6 +464,11 @@ def init_lora(
         from agilerl_tpu.llm import mla
 
         dims = mla.mla_dims(config)
+        attention = tuple(dims)
+    if config.is_cca:
+        from agilerl_tpu.llm import cca
+
+        dims = cca.cca_dims(config)
         attention = tuple(dims)
     if config.n_experts > 0 and any(t in ffn_names for t in targets):
         # MoE FFN weights are expert-stacked [E, ...]; the dense-shaped
@@ -503,26 +589,63 @@ def _qkv_rope(config: GPTConfig, blk, x, positions, lora_layer, lora_scale):
     return q, k, v
 
 
-def _block_ffn(config: GPTConfig, blk, h, lora_layer, lora_scale):
+def _merge(blk, name, h, y):
+    """The residual merge ``h + y``; where the block has the leaf ``name``
+    (``GPTConfig.scaled_merge``: rows residual scale, residual bias,
+    sublayer scale, sublayer bias) ``(s_r h + b_r) + (s_y y + b_y)``, in
+    float32."""
+    if name not in blk:
+        return h + y
+    m = blk[name].astype(jnp.float32)
+    return ((m[0] * h + m[1]) + (m[2] * y + m[3])).astype(h.dtype)
+
+
+def _stream(config: GPTConfig, stream):
+    """(h, router state) of what the layer loop carries: the residual
+    stream alone, or with ``GPTConfig.router_hidden`` the pair."""
+    return stream if config.router_hidden else (stream, None)
+
+
+def _restream(config: GPTConfig, h, s):
+    """The inverse of ``_stream``."""
+    return (h, s) if config.router_hidden else h
+
+
+def _stream_in(config: GPTConfig, h):
+    """What enters the first layer: the router state starts at zero, so the
+    first layer's mix with "the previous layer's" adds nothing."""
+    return _restream(config, h, jnp.zeros(
+        (*h.shape[:2], config.router_hidden), jnp.float32))
+
+
+def _block_ffn(config: GPTConfig, blk, stream, lora_layer, lora_scale):
     """Post-attention half of a block: RMSNorm + (MoE | SwiGLU) FFN with the
-    residual add. Returns (h_out, aux). Shared between forward's block_fn
+    residual merge. ``stream`` is what ``_run_layers`` carries (``_stream``);
+    returns (stream out, aux). Shared between forward's block_fn
     and forward_paged (same no-drift contract as _qkv_rope)."""
+    h, s = _stream(config, stream)
     B, T = h.shape[:2]
     dtype = h.dtype
     x = _rms(h, blk["ln2"], config.rms_eps)
     if "router" in blk and config.is_dropless:
         from agilerl_tpu.llm import moe
 
+        x2d, logits = x.reshape(B * T, config.d_model), None
+        if config.router_hidden:
+            logits, s = moe.mlp_logits(x2d, blk, s.reshape(B * T, -1),
+                                       config.rms_eps)
+            s = s.reshape(B, T, -1)
         out2d, load = moe.dropless_ffn(
-            x.reshape(B * T, config.d_model), blk,
-            top_k=config.expert_top_k, score=config.router_score,
-            norm_topk=config.norm_topk, scale=config.routed_scale)
+            x2d, blk, top_k=config.expert_top_k, score=config.router_score,
+            norm_topk=config.norm_topk, scale=config.routed_scale,
+            logits=logits)
         # what rides the aux channel of a dropless layer is its load, as
         # [fullest expert's rows over the mean, experts with any row]: no
         # auxiliary loss exists here (the router is frozen with the base)
         aux = jnp.stack([load.max() / jnp.maximum(load.mean(), 1.0),
                          (load > 0).sum()]).astype(jnp.float32)
-        return h + out2d.reshape(B, T, config.d_model), aux
+        h = _merge(blk, "merge2", h, out2d.reshape(B, T, config.d_model))
+        return _restream(config, h, s), aux
     if "router" in blk:
         from agilerl_tpu.llm.moe import moe_ffn
 
@@ -538,7 +661,8 @@ def _block_ffn(config: GPTConfig, blk, h, lora_layer, lora_scale):
     down = _maybe_lora(
         jax.nn.silu(gate) * up, blk["w_down"], lora_layer, "w_down", lora_scale, dtype
     )
-    return h + down, jnp.zeros(_aux_shape(config), jnp.float32)
+    return (_restream(config, _merge(blk, "merge2", h, down), s),
+            jnp.zeros(_aux_shape(config), jnp.float32))
 
 
 def _aux_shape(config: GPTConfig):
@@ -583,6 +707,10 @@ def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
     (``params["runs"][r]``), any other one tree a layer
     (``params["blocks"][str(i)]``), stacked here inside the program.
 
+    ``h`` is what the loop carries from layer to layer, a TREE: the residual
+    stream, or with a router state (``_stream``) the pair — so a second
+    stream rides the scan on every path.
+
     fns[kind](h, blk, x_i, lora_i) -> (h, y_i, aux); xs[kind]: None, or a
     list with one entry a run of that kind, each a tree stacked over the
     run's layers. Returns (h, {kind: [ys of each run]}, aux)."""
@@ -626,8 +754,8 @@ def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
         # more rows than experts reads a layer's slice whole anyway, and its
         # backward wants the slice in a layout of its own.
         held = {}
-        few_rows = h.shape[0] * h.shape[1] * config.expert_top_k \
-            < config.n_experts
+        B, T = jax.tree_util.tree_leaves(h)[0].shape[:2]
+        few_rows = B * T * config.expert_top_k < config.n_experts
         if scan and few_rows and config.is_dropless and "router" in w:
             held = {k: w[k] for k in ("w_gate", "w_up", "w_down")}
             w = {k: v for k, v in w.items() if k not in held}
@@ -690,8 +818,10 @@ def forward(
     else:
         start = cache_mask = None
 
-    def block_fn(h, blk, layer_kv, lora_layer):
-        """layer_kv: (k_cache [B,S,KV,hd], v_cache [B,S,KV,hd]) or None."""
+    def block_fn(stream, blk, layer_kv, lora_layer):
+        """layer_kv: (k_cache [B,S,KV,hd], v_cache [B,S,KV,hd]) or None;
+        over a CCA stack a third member, the layer's rolling state."""
+        h, router_state = _stream(config, stream)
         x = _rms(h, blk["ln1"], config.rms_eps)
         if config.is_mla:
             from agilerl_tpu.llm import mla
@@ -716,7 +846,17 @@ def forward(
                                dtype)
             h, aux = _block_ffn(config, blk, h + attn, lora_layer, lora_scale)
             return h, new_kv, aux
-        q, k, v = _qkv_rope(config, blk, x, positions, lora_layer, lora_scale)
+        states = ()
+        if config.is_cca:
+            from agilerl_tpu.llm import cca
+
+            q, k, v, *states = cca.qkv(
+                config, blk, x, positions, attention_mask,
+                None if layer_kv is None else layer_kv[2], lora_layer,
+                lora_scale)
+        else:
+            q, k, v = _qkv_rope(config, blk, x, positions, lora_layer,
+                                lora_scale)
 
         if layer_kv is not None:
             # layer_kv = this layer's PRE-update (k_slab, v_slab). Attention
@@ -730,7 +870,7 @@ def forward(
                 layer_kv[0], k, (0, start, 0, 0))
             cv = jax.lax.dynamic_update_slice(
                 layer_kv[1], v, (0, start, 0, 0))
-            new_kv = (k, v)
+            new_kv = (k, v, *states)
             # flash-decode: online-softmax over KV chunks bounded by the LIVE
             # cache length — never reads the dead cache tail, never
             # materializes GQA-repeated K/V (ops/decode_attention.py)
@@ -790,9 +930,11 @@ def forward(
                 B, T, config.n_head * config.head_dim
             )
         attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale, dtype)
-        h = h + attn
-        h, aux = _block_ffn(config, blk, h, lora_layer, lora_scale)
-        return h, new_kv, aux
+        h = _merge(blk, "merge1", h, attn)
+        stream, aux = _block_ffn(
+            config, blk, _restream(config, h, router_state), lora_layer,
+            lora_scale)
+        return stream, new_kv, aux
 
     fn = jax.checkpoint(block_fn, static_argnums=()) if config.remat else block_fn
     fns = {"attn": fn}
@@ -816,13 +958,17 @@ def forward(
         xs["attn"] = _split_by_runs(config, "attn", (cache.k, cache.v))
         if config.is_hybrid:
             xs["mamba"] = list(cache.state)
-    h, ys, aux_total = _run_layers(config, params, lora, h, fns, xs)
+        if config.is_cca:
+            xs["attn"] = [kv + (s,) for kv, s in zip(xs["attn"], cache.state)]
+    stream, ys, aux_total = _run_layers(
+        config, params, lora, _stream_in(config, h), fns, xs)
+    h, _ = _stream(config, stream)
 
     new_caches: Optional[KVCache] = None
     if cache is not None:
         # ONE bulk write of the new tokens ([L, B, T, KV, hd]) into the
         # (aliasable) cache buffers
-        new_k, new_v = _join_runs(ys["attn"])
+        new_k, new_v = _join_runs([y[:2] for y in ys["attn"]])
         at = (0, 0, start) + (0,) * (cache.k.ndim - 3)
         new_caches = KVCache(
             jax.lax.dynamic_update_slice(cache.k, new_k, at),
@@ -830,11 +976,14 @@ def forward(
                 cache.v, new_v, at),
             start + T, cache_mask,
         )
-        if config.is_hybrid:
-            # a one-token forward has no "before the last token" of its own
+        if config.state_kind is not None:
+            # (new state, state before the last token) of each run's layers:
+            # a state-space run's ys whole, the tail of a CCA run's; a
+            # one-token forward has no "before the last token" of its own
+            kept = [y[-2:] for y in ys[config.state_kind]]
             new_caches = new_caches._replace(
-                state=tuple(y[0] for y in ys["mamba"]),
-                prev_state=(tuple(y[1] for y in ys["mamba"]) if T > 1
+                state=tuple(y[0] for y in kept),
+                prev_state=(tuple(y[1] for y in kept) if T > 1
                             else cache.prev_state))
 
     h = _rms(h, params["ln_f"], config.rms_eps).astype(jnp.float32)
@@ -996,17 +1145,12 @@ def init_paged_cache(config: GPTConfig, n_blocks: int, block_size: int,
     per_token, has_v = _token_shape(config)
     shape = (config.n_layers_of("attn"), n_blocks, block_size, *per_token)
     state = snap = None
-    if config.is_hybrid:
+    if config.state_kind is not None:
         if slots is None:
-            raise ValueError("a hybrid stack's paged cache holds recurrent "
-                             "state per slot: pass slots=")
-        from agilerl_tpu.llm import ssm
-
-        mamba_runs = [n for kind, _, n in config.layer_runs()
-                      if kind == "mamba"]
-        state = tuple(ssm.init_state(config, n, slots) for n in mamba_runs)
-        snap = tuple(ssm.init_state(config, n, snapshots + 1)
-                     for n in mamba_runs)
+            raise ValueError("the paged cache of a stack whose layers keep "
+                             "state holds it per slot: pass slots=")
+        state = _init_state(config, slots)
+        snap = _init_state(config, snapshots + 1)
     return PagedKVCache(k=jnp.zeros(shape, config.dtype),
                         v=jnp.zeros(shape, config.dtype) if has_v else None,
                         state=state, snap=snap)
@@ -1193,9 +1337,32 @@ def forward_paged(
         new_kv = (lat if write_pos.ndim == 2 else lat[:, 0], None)
         return h, new_kv, (aux if config.is_dropless else 0.0)
 
-    def block_fn(h, blk, layer_kv, lora_layer):
+    if config.state_kind is not None:
+        # the second cache kind: rows ARE slots, so a layer that keeps state
+        # reads and writes its slot's record in place — no table, no gather
+        if T > 1:
+            raise NotImplementedError(
+                "a multi-token paged forward (speculative verify) over "
+                "layers that keep per-slot state (a hybrid stack's "
+                "recurrent state, a CCA stack's rolling state) needs that "
+                "state rolled back for rejected drafts; not implemented")
+        S = slot_mask.shape[1]
+        tok_mask = jnp.take_along_axis(
+            slot_mask, jnp.minimum(wp_start, S - 1)[:, None], axis=1)
+
+    def block_fn(stream, blk, layer_kv, lora_layer):
+        h, router_state = _stream(config, stream)
         x = _rms(h, blk["ln1"], config.rms_eps)
-        q, k, v = _qkv_rope(config, blk, x, pos2d, lora_layer, lora_scale)
+        states = ()
+        if config.is_cca:
+            from agilerl_tpu.llm import cca
+
+            q, k, v, new_state, _ = cca.qkv(
+                config, blk, x, pos2d, tok_mask, layer_kv[2], lora_layer,
+                lora_scale)
+            states = (new_state,)
+        else:
+            q, k, v = _qkv_rope(config, blk, x, pos2d, lora_layer, lora_scale)
         from agilerl_tpu.ops.decode_attention import chunked_paged_attention
 
         attn = chunked_paged_attention(q, layer_kv[0], layer_kv[1],
@@ -1203,25 +1370,16 @@ def forward_paged(
                                        slot_mask, wp_start)
         attn = attn.reshape(B, T, config.n_head * config.head_dim)
         attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale, dtype)
-        h = h + attn
-        h, aux = _block_ffn(config, blk, h, lora_layer, lora_scale)
+        h = _merge(blk, "merge1", h, attn)
+        stream, aux = _block_ffn(
+            config, blk, _restream(config, h, router_state), lora_layer,
+            lora_scale)
         new_kv = (k, v) if write_pos.ndim == 2 else (k[:, 0], v[:, 0])
-        return h, new_kv, (aux if config.is_dropless else 0.0)
+        return stream, new_kv + states, (aux if config.is_dropless else 0.0)
 
     fns = {"attn": mla_block_fn if config.is_mla else block_fn}
     if config.is_hybrid:
-        # the second cache kind: rows ARE slots, so a state-space layer
-        # reads and writes its slot's record in place — no table, no gather
-        if T > 1:
-            raise NotImplementedError(
-                "a multi-token paged forward (speculative verify) over a "
-                "hybrid stack needs recurrent-state rollback for rejected "
-                "drafts; not implemented")
         from agilerl_tpu.llm import ssm
-
-        S = slot_mask.shape[1]
-        tok_mask = jnp.take_along_axis(
-            slot_mask, jnp.minimum(wp_start, S - 1)[:, None], axis=1)
 
         def mamba_fn(h, blk, layer_state, lora_layer):
             x = _rms(h, blk["ln1"], config.rms_eps)
@@ -1234,11 +1392,17 @@ def forward_paged(
     xs = {"attn": _split_by_runs(config, "attn", (cache.k, cache.v))}
     if config.is_hybrid:
         xs["mamba"] = list(cache.state)
-    h, ys, aux = _run_layers(config, params, lora, h, fns, xs)
+    if config.is_cca:
+        xs["attn"] = [kv + (s,) for kv, s in zip(xs["attn"], cache.state)]
+    stream, ys, aux = _run_layers(config, params, lora,
+                                  _stream_in(config, h), fns, xs)
+    h, _ = _stream(config, stream)
     h = _rms(h, params["ln_f"], config.rms_eps).astype(jnp.float32)
-    new = _join_runs(ys["attn"])
+    new = _join_runs([y[:2] for y in ys["attn"]])
     if config.is_hybrid:
         new += (tuple(ys["mamba"]),)
+    if config.is_cca:
+        new += (tuple(y[2] for y in ys["attn"]),)
     if return_aux:
         return h, new, aux
     return h, new

@@ -10,6 +10,9 @@ an ``ep`` axis. A configuration that states none takes the dropless path —
 (token, choice) pairs by expert, one grouped matmul a projection, un-sort
 and weight) and an always-on ``shared`` expert beside it — which serves
 every token whatever the imbalance (``dropless_ffn`` is the whole layer).
+The logits ``route`` turns into a choice come from outside it:
+``linear_logits`` (``x @ router``), or ``mlp_logits``, a small network over
+a router state that is carried from layer to layer.
 
 The bucketed dispatch: dense capacity-bucketed dispatch — routing is expressed as
 one-hot einsums over static shapes ([tokens, E, C] dispatch/combine tensors),
@@ -97,22 +100,69 @@ def moe_ffn(
 # --------------------------------------------------------------------------- #
 
 ROUTE_SCOPE = "moe/route"
+SCORE_SCOPE = "moe/score"  # a router that is a network; NOT under moe/route
 EXPERTS_SCOPE = "moe/experts"
 SHARED_SCOPE = "moe/shared"
 COMBINE_SCOPE = "moe/combine"
 
 
-def route(x, router_w, select_bias=None, *, top_k: int, score: str = "softmax",
-          norm_topk: bool = True, scale: float = 1.0):
-    """Score -> choice -> weights, all in float32 whatever ``x`` is.
+def linear_logits(x, router_w):
+    """``x @ router_w`` in float32 whatever ``x`` is."""
+    return jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
 
-    ``s = score(x @ router_w)`` (``sigmoid`` or ``softmax``); the choice is
+
+def init_router_mlp(key, d: int, r: int, n_experts: int):
+    """The leaves ``mlp_logits`` reads, float32 all. Matrices normal(0,
+    0.02) but the two hidden ones, which at that size would pass nothing on
+    (256 x 0.02**2): they are drawn at 1/sqrt(r), and the last at 4/sqrt(r)
+    so that the softmax has a favourite (a top probability near 1/2, where
+    1/sqrt(r) leaves it near 1/E). Biases and ``gamma`` drawn, not at 0 and
+    1 where they would check nothing."""
+    from agilerl_tpu.llm.model import _normal as normal
+
+    ks = jax.random.split(key, 8)
+    return {
+        "router_in": normal(ks[0], (d, r), 0.02),
+        "router_in_b": normal(ks[1], (r,), 0.02),
+        "router_gamma": 1.0 + normal(ks[2], (r,), 0.1),
+        "router_norm": jnp.ones((r,), jnp.float32),
+        "router_w1": normal(ks[3], (r, r), r ** -0.5),
+        "router_b1": normal(ks[4], (r,), 0.02),
+        "router_w2": normal(ks[5], (r, r), r ** -0.5),
+        "router_b2": normal(ks[6], (r,), 0.02),
+        "router": normal(ks[7], (r, n_experts), 4 * r ** -0.5),
+    }
+
+
+def mlp_logits(x, blk, s_prev, eps: float):
+    """A router that is a network with a memory across layers, float32
+    throughout: ``z = x W_in + b_in + gamma * s_prev`` (``s_prev`` the
+    previous layer's ``z``; zeros into the first layer), then
+    ``logits = gelu(gelu(rms(z) W1 + b1) W2 + b2) W_out`` (GELU by erf).
+    x [N, d], s_prev [N, r] -> (logits [N, E], z [N, r])."""
+    f32 = jnp.float32
+    w = lambda name: blk[name].astype(f32)  # noqa: E731
+    with jax.named_scope(SCORE_SCOPE):
+        z = (linear_logits(x, w("router_in")) + w("router_in_b")
+             + w("router_gamma") * s_prev)
+        u = z * jax.lax.rsqrt(jnp.mean(z * z, axis=-1, keepdims=True) + eps)
+        u = u * w("router_norm")
+        for i in ("1", "2"):
+            u = jax.nn.gelu(linear_logits(u, w("router_w" + i))
+                            + w("router_b" + i), approximate=False)
+        return linear_logits(u, w("router")), z
+
+
+def route(logits, select_bias=None, *, top_k: int, score: str = "softmax",
+          norm_topk: bool = True, scale: float = 1.0):
+    """Score -> choice -> weights, in float32.
+
+    ``s = score(logits)`` (``sigmoid`` or ``softmax``); the choice is
     the top-k of ``s + select_bias`` — the bias moves the CHOICE only —; the
     weights are ``s`` at the chosen experts, renormalised to sum to one
     where ``norm_topk``, times ``scale``. Returns (choice [N, k] int32,
     weights [N, k] float32)."""
-    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
     if score == "sigmoid":
         s = jax.nn.sigmoid(logits)
     elif score == "softmax":
@@ -179,14 +229,17 @@ def shared_expert(x, w_gate, w_up, w_down):
 
 
 def dropless_ffn(x, blk, *, top_k: int, score: str, norm_topk: bool,
-                 scale: float):
+                 scale: float, logits=None):
     """The whole expert layer on ``x`` [N, d] from a block's weights
     (``router``, optional ``router_bias``, ``w_gate/w_up/w_down`` stacked
-    over experts, optional ``ws_gate/ws_up/ws_down``). Returns (out [N, d],
-    load [E] int32: the rows each expert served)."""
+    over experts, optional ``ws_gate/ws_up/ws_down``). ``logits`` [N, E]
+    where the router is not ``x @ router`` (``mlp_logits``). Returns (out
+    [N, d], load [E] int32: the rows each expert served)."""
     with jax.named_scope(ROUTE_SCOPE):
+        if logits is None:
+            logits = linear_logits(x, blk["router"])
         choice, weights = route(
-            x, blk["router"], blk.get("router_bias"), top_k=top_k,
+            logits, blk.get("router_bias"), top_k=top_k,
             score=score, norm_topk=norm_topk, scale=scale)
     out = dropless_experts(x, choice, weights, blk["w_gate"], blk["w_up"],
                            blk["w_down"], layer=blk.get("expert_layer"))

@@ -68,6 +68,22 @@ _PRESETS: Dict[str, Dict[str, Any]] = {
         d_ff_expert=32, d_ff_shared=64, router_score="sigmoid",
         router_bias=True, norm_topk=True, routed_scale=2.5,
     ),
+    # A ZAYA1-class stack at toy sizes, for the CPU tests: compressed
+    # convolutional attention (4 query heads on 2 key heads of 16, in a
+    # latent 64 / 32 wide under d_model 48; kernels 2 and 2; rotary on half
+    # a head) with its rolling state, then 8 experts of which ONE a token,
+    # chosen by a router MLP 16 wide over a state carried from layer to
+    # layer (softmax, a drawn selection bias, the probability itself as the
+    # weight), scale and bias on both arms of every merge, a tied head.
+    # Nothing of it is a model.
+    "tiny-cca-moe": dict(
+        vocab_size=256, n_layer=3, n_head=4, n_kv_head=2, head_size=16,
+        d_model=48, max_seq_len=256, rope_theta=5_000_000.0, rms_eps=1e-5,
+        tie_embeddings=True, cca_time0=2, cca_time1=2, rotary_share=0.5,
+        n_experts=8, expert_top_k=1, capacity_factor=None, d_ff_expert=32,
+        router_score="softmax", router_bias=True, norm_topk=False,
+        router_hidden=16, scaled_merge=True,
+    ),
 }
 
 

@@ -604,14 +604,16 @@ class BlockAllocator:
 
 
 class StateSnapshots:
-    """Host index of the snapshot entries in a hybrid stack's state cache
-    (``PagedKVCache.snap``): chain hash of a prompt's LAST block -> entry.
+    """Host index of the snapshot entries in the state cache of a stack
+    whose layers keep state (``GPTConfig.state_kind``: a hybrid stack's
+    state-space layers, a CCA stack's attention layers;
+    ``PagedKVCache.snap``): chain hash of a prompt's LAST block -> entry.
 
-    An entry holds the recurrent state from before the prompt's last token,
+    An entry holds the per-layer state from before the prompt's last token,
     written by the prefill that computed it. A prefix-cache hit needs it as
     much as it needs the chain's KV blocks: the last prompt token re-enters
-    on the first decode step, and a state-space layer must start that step
-    from where the prompt stood, not from zero. So an entry lives and dies
+    on the first decode step, and a layer that keeps state must start that
+    step from where the prompt stood, not from zero. So an entry lives and dies
     with its chain: dropped when the allocator forgets the hash (eviction,
     weight-epoch flush), and — there being ``n`` entries only — when a newer
     prompt needs the room (least recently used first); a chain whose entry
@@ -763,6 +765,22 @@ class ContinuousGenerator:
     ):
         self.config = config
         self.metrics = metrics if metrics is not None else observability.get_registry()
+        if config.is_cca:
+            if as_spec_config(speculate) is not None:
+                raise ValueError(
+                    "speculative decoding over a CCA stack would lose the "
+                    "attention layers' rolling state (convolution windows "
+                    "and the previous token's value half): the verify step "
+                    "advances it past rejected drafts and nothing rolls it "
+                    "back; not implemented: build the generator with "
+                    "speculate=None")
+            if sharding_plan is not None or mesh is not None:
+                raise ValueError(
+                    "a serving plan has no rule for a CCA stack's rolling-"
+                    "state cache (per-slot convolution windows and value "
+                    "half beside the paged pool) nor for its leaves "
+                    "(conv0_*, conv1_*, tau, wv1, wv2, router_*, merge*); "
+                    "not implemented on a mesh")
         if config.is_mla or config.is_dropless:
             # what this tier does not do over a latent cache or a dropless
             # expert stack refuses here, by mechanism
@@ -854,10 +872,10 @@ class ContinuousGenerator:
         #: flywheel's record — saves RolloutPod the extra behavior_logprobs
         #: forward; see result_logprobs / generate()'s info["logprobs"])
         self.capture_logprobs = bool(capture_logprobs)
-        #: snapshot entries of a hybrid stack's state cache, one a slot; None
-        #: for a stack without state-space layers
+        #: snapshot entries of the state cache, one a slot; None for a stack
+        #: none of whose layers keeps state
         self._snapshots = (StateSnapshots(self.slots, self.metrics)
-                           if config.is_hybrid else None)
+                           if config.state_kind is not None else None)
         self._proposer = (NgramProposer(self.speculate)
                           if self.speculate is not None else None)
         self._completions = (
@@ -994,12 +1012,12 @@ class ContinuousGenerator:
         """Prefill ONE request at its prompt bucket (the SHARED prefill_head
         — dense-parity maths) and scatter its prompt KV into the assigned
         physical blocks. Compiles once per (prompt bucket, greedy).
-        ``state_ids`` (hybrid stacks: int32 [slot, snapshot entry]) says
-        where the recurrent state after the prompt and the one before its
-        last token go."""
+        ``state_ids`` (stacks whose layers keep state: int32 [slot, snapshot
+        entry]) says where the state after the prompt and the one before
+        its last token go."""
         Pb = prompt.shape[1]
-        # keep_prev_state: a hybrid stack's snapshot (below); nothing to
-        # keep, and the same program, for a stack without recurrent state
+        # keep_prev_state: the snapshot of layers that keep state (below);
+        # nothing to keep, and the same program, for a stack without them
         # dense-parity extent: the same Pb + chunks*chunk the bucketed/dense
         # paths allocate, so chunked-attention chunking is identical
         dense = M.init_caches(self.config, 1, Pb + self._decode_extent)
@@ -1018,7 +1036,7 @@ class ContinuousGenerator:
         cache = M.paged_scatter_prompt(
             cache, block_ids, filled.k[:, 0, :Pb],
             None if filled.v is None else filled.v[:, 0, :Pb])
-        if self.config.is_hybrid:
+        if self.config.state_kind is not None:
             cache = M.paged_write_state(cache, state_ids[0], state_ids[1],
                                         filled.state, filled.prev_state)
         if self.capture_logprobs:
@@ -1235,6 +1253,12 @@ class ContinuousGenerator:
                 "submit_prefilled over a hybrid stack: the prefill worker's "
                 "export carries prompt KV only, not the recurrent state of "
                 "the state-space layers (nor its snapshot); not implemented")
+        if self.config.is_cca:
+            raise NotImplementedError(
+                "submit_prefilled over a CCA stack: the prefill worker's "
+                "export carries prompt KV only, not the attention layers' "
+                "rolling state (convolution windows and value half, nor "
+                "its snapshot); not implemented")
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         if key is None:
             # the raw request key is load-bearing: a prefix-cache HIT on an

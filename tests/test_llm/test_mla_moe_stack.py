@@ -55,9 +55,9 @@ def test_the_bias_moves_the_choice_and_not_the_weight():
     x = jax.random.normal(jax.random.PRNGKey(0), (32, 16))
     w = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (16, 8))
     kw = dict(top_k=2, score="sigmoid", norm_topk=False)
-    choice, weights = moe.route(x, w, None, **kw)
+    choice, weights = moe.route(moe.linear_logits(x, w), None, **kw)
     bias = jnp.zeros((8,)).at[5].set(10.0)  # expert 5 wins every choice
-    choice_b, weights_b = moe.route(x, w, bias, **kw)
+    choice_b, weights_b = moe.route(moe.linear_logits(x, w), bias, **kw)
     assert (choice_b[:, 0] == 5).all() and not (choice[:, 0] == 5).all()
     s = jax.nn.sigmoid(x @ w)
     np.testing.assert_allclose(  # the weight is the score WITHOUT the bias
@@ -69,8 +69,8 @@ def test_the_bias_moves_the_choice_and_not_the_weight():
 def test_renormalisation_and_scale(score):
     x = jax.random.normal(jax.random.PRNGKey(2), (32, 16))
     w = jax.random.normal(jax.random.PRNGKey(3), (16, 8))
-    _, plain = moe.route(x, w, top_k=3, score=score, norm_topk=False)
-    _, normed = moe.route(x, w, top_k=3, score=score, norm_topk=True,
+    _, plain = moe.route(moe.linear_logits(x, w), top_k=3, score=score, norm_topk=False)
+    _, normed = moe.route(moe.linear_logits(x, w), top_k=3, score=score, norm_topk=True,
                           scale=2.5)
     np.testing.assert_allclose(normed.sum(-1), 2.5, rtol=1e-5)
     np.testing.assert_allclose(

@@ -73,12 +73,13 @@ def _assert_spec_trees_equal(got, want):
 
 
 def _has_leaves_without_a_rule(name):
-    return preset(name).is_hybrid or preset(name).is_mla
+    cfg = preset(name)
+    return cfg.is_hybrid or cfg.is_mla or cfg.is_cca
 
 
 # the hand-built specs know GQA attention stacks; a hybrid preset's
 # state-space leaves and a latent-attention / dropless expert preset's have
-# no rule yet (next test)
+# (and a CCA preset's) no rule yet (next test)
 @pytest.mark.parametrize(
     "name", [n for n in preset_names() if not _has_leaves_without_a_rule(n)])
 def test_plan_params_specs_match_handbuilt_for_every_preset(name):
@@ -100,7 +101,8 @@ def test_plan_names_the_hybrid_leaves_it_has_no_rule_for(name):
     shapes = jax.eval_shape(lambda k: M.init_params(k, cfg),
                             jax.random.PRNGKey(0))
     with pytest.raises(UnmatchedLeafError,
-                       match="in_proj|conv_w|A_log|wkv_a|kv_norm|ws_gate"):
+                       match="in_proj|conv_w|A_log|wkv_a|kv_norm|ws_gate|"
+                             "conv0_w|wv1|router_in|merge1|tau"):
         make_grpo_plan(fsdp=4, tp=2).resolve("params", shapes, strict=True)
 
 
