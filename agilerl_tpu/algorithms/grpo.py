@@ -10,7 +10,10 @@ TPU-first deltas vs the reference:
   sharing the training param tree — no weight hot-swap, no engine sleep/wake;
 - no DeepSpeed: the base params + LoRA live in one pytree that parallel/mesh.py
   shards GSPMD-style (fsdp/tp axes);
-- the fused chunked loss (ops/fused_loss.py) replaces Liger's Triton kernel.
+- the fused chunked loss (ops/fused_loss.py) replaces Liger's Triton kernel;
+- a learn call of exactly one optimizer step recomputes the reference
+  logprobs only: the ratio's anchor is the update's own logprobs under
+  stop_gradient (_grpo_loss_core), not a second no-grad forward.
 """
 
 from __future__ import annotations
@@ -62,9 +65,22 @@ def _grpo_loss_core(lp, batch, clip, beta):
     correction exactly once and bounded-staleness off-policy data tilts
     the update instead of biasing it. ``rho`` is computed outside the grad
     (a constant under differentiation, like ``old_lp``); a batch without
-    the key compiles the exact on-policy program as before."""
+    the key compiles the exact on-policy program as before.
+
+    ``old_lp`` — the clipped ratio's anchor — is optional too. A batch
+    without it anchors the ratio at the update's own masked logprobs under
+    ``stop_gradient``: the ratio is 1 in value and ``d ratio / d theta =
+    d lp / d theta``, which is what a no-grad pass over the same adapter
+    and tokens gives up to the rounding between two compiled programs.
+    That holds for the FIRST optimizer step from an adapter only, so
+    ``GRPO.learn`` leaves the key out when a call takes exactly one step
+    (TRL's ``GRPOTrainer`` at ``num_iterations == 1`` does the same). A
+    batch that carries ``old_lp`` compiles the program it always did."""
     lp = lp * batch["loss_mask"]
-    ratio = jnp.exp(lp - batch["old_lp"])
+    old_lp = batch.get("old_lp")
+    if old_lp is None:
+        old_lp = jax.lax.stop_gradient(lp)
+    ratio = jnp.exp(lp - old_lp)
     adv = batch["advantage"][:, None]
     s1 = ratio * adv
     s2 = jnp.clip(ratio, 1 - clip, 1 + clip) * adv
@@ -564,6 +580,18 @@ class GRPO(EvolvableAlgorithm):
         (otherwise attention defaults to ids != pad_token_id)
         (parity: grpo.py:321). Returns (mean loss, mean k3 KL vs reference).
 
+        A call runs the reference adapter's no-grad pass, then the update.
+        The clipped ratio's anchor (the actor's logprobs at learn start)
+        costs a second no-grad pass only when the call takes more than one
+        optimizer step (``update_epochs > 1``, or more rows than
+        ``batch_size``): the later steps start from an adapter that has
+        moved. A call of exactly one step anchors the ratio at the update's
+        own logprobs under ``stop_gradient`` (:func:`_grpo_loss_core`) —
+        the same update, without recomputing a value the update's forward
+        already holds. Decided per call from ``update_epochs``,
+        ``batch_size`` and the batch's rows (HPO may mutate them); counted
+        in ``grpo/anchor_reused_total`` / ``grpo/anchor_recomputed_total``.
+
         With ``sequence_parallel_axis`` set (and ``to_mesh`` called with a mesh
         containing that axis), every forward — old/ref logprobs AND the
         differentiable update — runs with the sequence sharded across the axis
@@ -583,9 +611,26 @@ class GRPO(EvolvableAlgorithm):
                 rewards = jnp.asarray(rewards, jnp.float32)
                 advantage = self._calculate_advantage(rewards)
                 logprobs, update = self._resolve_learn_fns(ids, mask)
+            # one optimizer step from the adapter the anchor would be
+            # computed under: the update's own forward is that anchor
+            single_step = (self.update_epochs == 1
+                           and ids.shape[0] <= self.batch_size)
             with PhaseTimer(metrics, "learn/logprobs"):
-                old_lp = logprobs(self.actor.params, ids, mask) * loss_mask
+                old_lp = None if single_step else (
+                    logprobs(self.actor.params, ids, mask) * loss_mask)
                 ref_lp = logprobs(self.reference.params, ids, mask) * loss_mask
+            if single_step:
+                metrics.counter(
+                    "grpo/anchor_reused_total",
+                    help="learn calls of one optimizer step: the ratio's "
+                         "anchor is the update's own logprobs, no anchor pass",
+                ).inc()
+            else:
+                metrics.counter(
+                    "grpo/anchor_recomputed_total",
+                    help="learn calls of several optimizer steps: the "
+                         "ratio's anchor took a no-grad pass of its own",
+                ).inc()
             return self._run_update_epochs(
                 update, ids, mask, loss_mask, old_lp, ref_lp, advantage)
 
@@ -650,7 +695,10 @@ class GRPO(EvolvableAlgorithm):
         :meth:`learn_from_trajectory` (one home for permutation order, the
         donated-buffer bookkeeping, and the NaN guard — the two entry
         points cannot drift). ``rho`` (per-token truncated importance
-        weights, or None) rides into each minibatch dict."""
+        weights, or None) rides into each minibatch dict; so does
+        ``old_lp``, and None leaves the key out: the update then anchors
+        the ratio at its own logprobs (:func:`_grpo_loss_core`), which is
+        right for a call of one optimizer step and for no other."""
         lora, opt_state = self.actor.params, self.optimizer.opt_state
         n_rows = ids.shape[0]
         total, total_kl, n_updates = 0.0, 0.0, 0
@@ -666,10 +714,11 @@ class GRPO(EvolvableAlgorithm):
                         "tokens": ids[idx],
                         "mask": mask[idx],
                         "loss_mask": loss_mask[idx],
-                        "old_lp": old_lp[idx],
                         "ref_lp": ref_lp[idx],
                         "advantage": advantage[idx],
                     }
+                    if old_lp is not None:
+                        batch["old_lp"] = old_lp[idx]
                     if rho is not None:
                         batch["rho"] = rho[idx]
                 with PhaseTimer(metrics, "learn/update"):
