@@ -18,6 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from agilerl_tpu.llm import model as M
+from agilerl_tpu.observability.timeline import device_scope
+
+#: head + sampler of a paged decode step (docs/observability.md, "Device scopes")
+HEAD_SCOPE = "decode/head"
 
 
 def left_pad(
@@ -280,15 +284,16 @@ def paged_decode_step(config, params, carry, *, lora, lora_scale, temperature,
     experts_hit = tuple(a[1] for a in aux)
     # (new_k, new_v), and the new per-slot state too where layers keep one
     cache = M.paged_scatter_tokens(cache, block_tables, lengths, *new)
-    logits = M.logits_fn(config, params, hidden)[:, 0, :]
     pos = pos + prev_ok.astype(pos.dtype)
     split = jax.vmap(jax.random.split)(keys)  # [slots, 2, 2]
     keys, k_s = split[:, 0], split[:, 1]
-    tok = _sample_token_per_row(
-        _suppress_eos(logits, step_idx, eos_id, min_new_tokens), k_s,
-        temperature, top_k, top_p,
-    )
-    tok = jnp.where(done, pad_id, tok)
+    with device_scope(HEAD_SCOPE):
+        logits = M.logits_fn(config, params, hidden)[:, 0, :]
+        tok = _sample_token_per_row(
+            _suppress_eos(logits, step_idx, eos_id, min_new_tokens), k_s,
+            temperature, top_k, top_p,
+        )
+        tok = jnp.where(done, pad_id, tok)
     emit = jnp.logical_not(done)
     if eos_id is not None:
         done = jnp.logical_or(done, tok == eos_id)
@@ -297,7 +302,8 @@ def paged_decode_step(config, params, carry, *, lora, lora_scale, temperature,
     carry = (cache, block_tables, slot_mask, lengths, tok, emit, pos,
              step_idx, done, keys)
     if capture_lp:
-        lsm = jax.nn.log_softmax(logits, axis=-1)
-        lp = jnp.take_along_axis(lsm, tok[:, None], axis=-1)[:, 0]
+        with device_scope(HEAD_SCOPE):
+            lsm = jax.nn.log_softmax(logits, axis=-1)
+            lp = jnp.take_along_axis(lsm, tok[:, None], axis=-1)[:, 0]
         return carry, (tok, emit, lp) + experts_hit
     return carry, (tok, emit) + experts_hit

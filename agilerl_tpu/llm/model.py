@@ -23,7 +23,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from agilerl_tpu.observability.timeline import device_scope
+
 Params = Any
+
+#: the two parts of a layer that ``forward_paged`` names (docs/observability.md,
+#: "Device scopes"); attention has ``paged/attend`` and the mixers their own
+PROJ_SCOPE = "decode/proj"
+FFN_SCOPE = "decode/ffn"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1322,18 +1329,26 @@ def forward_paged(
     pos2d = positions if positions.ndim == 2 else positions[:, None]
     wp_start = write_pos[:, 0] if write_pos.ndim == 2 else write_pos
 
+    # the parts of a layer are named here (PERF.md section 3), at the paged
+    # path's call sites, so that the learn programs, which share _qkv_rope /
+    # _block_ffn, keep their text
     def mla_block_fn(h, blk, layer_kv, lora_layer):
         from agilerl_tpu.llm import mla
 
-        x = _rms(h, blk["ln1"], config.rms_eps)
-        q_nope, q_rope, lat = mla.project(config, blk, x, pos2d, lora_layer,
-                                          lora_scale)
+        with device_scope(PROJ_SCOPE):
+            x = _rms(h, blk["ln1"], config.rms_eps)
+            q_nope, q_rope, lat = mla.project(config, blk, x, pos2d,
+                                              lora_layer, lora_scale)
         attn = mla.attend_absorbed(
             config, blk, q_nope, q_rope,
             (layer_kv[0], block_tables, lat, write_pos), slot_mask,
             wp_start, lora_layer, lora_scale)
-        attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale, dtype)
-        h, aux = _block_ffn(config, blk, h + attn, lora_layer, lora_scale)
+        with device_scope(PROJ_SCOPE):
+            attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale,
+                               dtype)
+            h = h + attn
+        with device_scope(FFN_SCOPE):
+            h, aux = _block_ffn(config, blk, h, lora_layer, lora_scale)
         new_kv = (lat if write_pos.ndim == 2 else lat[:, 0], None)
         return h, new_kv, (aux if config.is_dropless else 0.0)
 
@@ -1352,28 +1367,33 @@ def forward_paged(
 
     def block_fn(stream, blk, layer_kv, lora_layer):
         h, router_state = _stream(config, stream)
-        x = _rms(h, blk["ln1"], config.rms_eps)
         states = ()
-        if config.is_cca:
-            from agilerl_tpu.llm import cca
+        with device_scope(PROJ_SCOPE):
+            x = _rms(h, blk["ln1"], config.rms_eps)
+            if config.is_cca:
+                from agilerl_tpu.llm import cca
 
-            q, k, v, new_state, _ = cca.qkv(
-                config, blk, x, pos2d, tok_mask, layer_kv[2], lora_layer,
-                lora_scale)
-            states = (new_state,)
-        else:
-            q, k, v = _qkv_rope(config, blk, x, pos2d, lora_layer, lora_scale)
+                q, k, v, new_state, _ = cca.qkv(
+                    config, blk, x, pos2d, tok_mask, layer_kv[2], lora_layer,
+                    lora_scale)
+                states = (new_state,)
+            else:
+                q, k, v = _qkv_rope(config, blk, x, pos2d, lora_layer,
+                                    lora_scale)
         from agilerl_tpu.ops.decode_attention import chunked_paged_attention
 
         attn = chunked_paged_attention(q, layer_kv[0], layer_kv[1],
                                        block_tables, k, v, write_pos,
                                        slot_mask, wp_start)
         attn = attn.reshape(B, T, config.n_head * config.head_dim)
-        attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale, dtype)
-        h = _merge(blk, "merge1", h, attn)
-        stream, aux = _block_ffn(
-            config, blk, _restream(config, h, router_state), lora_layer,
-            lora_scale)
+        with device_scope(PROJ_SCOPE):
+            attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale,
+                               dtype)
+            h = _merge(blk, "merge1", h, attn)
+        with device_scope(FFN_SCOPE):
+            stream, aux = _block_ffn(
+                config, blk, _restream(config, h, router_state), lora_layer,
+                lora_scale)
         new_kv = (k, v) if write_pos.ndim == 2 else (k[:, 0], v[:, 0])
         return stream, new_kv + states, (aux if config.is_dropless else 0.0)
 
@@ -1385,7 +1405,8 @@ def forward_paged(
             x = _rms(h, blk["ln1"], config.rms_eps)
             out, new_s, _ = ssm.mixer(config, blk, x, tok_mask, layer_state,
                                       lora_layer, lora_scale)
-            h, _ = _block_ffn(config, blk, h + out, lora_layer, lora_scale)
+            with device_scope(FFN_SCOPE):
+                h, _ = _block_ffn(config, blk, h + out, lora_layer, lora_scale)
             return h, new_s, 0.0
 
         fns["mamba"] = mamba_fn

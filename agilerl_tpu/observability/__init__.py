@@ -44,6 +44,7 @@ from agilerl_tpu.observability.timeline import (
     PhaseTimer,
     StepTimeline,
     device_memory_stats,
+    device_scope,
 )
 from agilerl_tpu.observability.trace import (
     Span,
@@ -62,6 +63,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "JsonlSink", "MemorySink", "NullSink", "read_jsonl",
     "StepTimeline", "PhaseTimer", "PROFILER_PREFIX", "device_memory_stats",
+    "device_scope",
     "LineageTracker",
     "RunTelemetry", "init_run_telemetry", "get_registry", "warn_once",
     "Tracer", "Span", "SpanContext", "get_tracer", "set_tracer",

@@ -13,10 +13,12 @@ an error at construction, never an assumed peak.
 
 from __future__ import annotations
 
+import contextlib
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Iterator, Optional
 
 import jax
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from agilerl_tpu.utils.log_utils import CombineLogs
 from agilerl_tpu.utils.profiling import (
@@ -241,3 +243,33 @@ class PhaseTimer:
         self.registry.histogram(self.name, buckets=PHASE_BUCKETS).observe(
             self.elapsed_s)
         return False
+
+
+@contextlib.contextmanager
+def device_scope(name: str) -> Iterator[None]:
+    """One named part of a device program: ``with device_scope("decode/head"):
+    ...`` traces the block under ``jax.named_scope(name)`` AND with the
+    frontend attribute ``scope = name`` on every operation it lowers to.
+
+    The scope puts the name into each operation's ``op_name``
+    (``jit(step)/while/body/decode/head/dot_general``), where a reader of a
+    profile finds it. But it lives in the module's debug locations only, and
+    JAX's persistent compilation cache keys a program on its text with those
+    stripped: a program whose only change is a ``named_scope`` loads the
+    executable cached before the scope was put on, without the name. The
+    attribute (``mhlo.frontend_attributes = {scope = "decode/head"}``)
+    survives the stripping, so putting a scope on, renaming it or taking it
+    off compiles the program anew. XLA carries the attribute along and
+    runs the operations the bare block compiles to. No operation is moved
+    into a function of its own, so a call site keeps its shape: the block
+    closes over what it closed over. What a name costs is lowering time in
+    the program's first call (an attribute an equation, built in Python:
+    0.4 s for 3000 of them on the chip's host, PERF.md section 6, PR 36).
+
+    Use it for a part of a program that a cached executable may stand in for
+    (a serving or generation program that a checkout compiles once and loads
+    ever after); a bare ``named_scope`` does inside code that is new to every
+    cache anyway.
+    """
+    with jax.named_scope(name), set_xla_metadata(scope=name):
+        yield

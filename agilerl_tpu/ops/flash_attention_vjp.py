@@ -284,6 +284,7 @@ def _fwd(q, k, v, padding_mask, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     out4 = out.reshape(B, H, Tp, dv)[:, :, :T, :]
     # lse rides as [B, H, Tp, 1] so the GSPMD partitioning rule can map its
@@ -491,6 +492,7 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
         out_shape=jax.ShapeDtypeStruct((bh, Tp, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(qf, kf, vf, dof, lse, dd, *mask_args)
 
     dkv_specs = [
@@ -522,6 +524,7 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
             pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(qf, kf, vf, dof, lse, dd, *mask_args)
 
     unpad = lambda x: x.reshape(B, H, Tp, -1)[:, :, :T, :]  # noqa: E731

@@ -27,11 +27,19 @@ from agilerl_tpu.components.rollout_buffer import shuffled_minibatches
 from agilerl_tpu.envs.core import JaxEnv, VecState, make_autoreset_step
 from agilerl_tpu.networks import distributions as D
 from agilerl_tpu.networks.base import EvolvableNetwork
+from agilerl_tpu.observability.timeline import device_scope
 from agilerl_tpu.parallel.generation import (
     evolve_actor_critic,
     make_pod_generation,
     make_vmap_generation,
 )
+
+#: the three parts of a generation that ``member_iteration`` names
+#: (docs/observability.md, "Device scopes"); tournament and mutation stay in
+#: the remainder
+ROLLOUT_SCOPE = "evo/rollout"
+SHUFFLE_SCOPE = "evo/shuffle"
+UPDATE_SCOPE = "evo/update"
 
 
 class MemberState(NamedTuple):
@@ -185,7 +193,8 @@ class EvoPPO:
 
         def epoch(carry, k):
             params, opt_state = carry
-            batches = shuffled_minibatches(k, flat, self.num_minibatches, mb)
+            with device_scope(SHUFFLE_SCOPE):
+                batches = shuffled_minibatches(k, flat, self.num_minibatches, mb)
 
             def minibatch(carry, b):
                 params, opt_state = carry
@@ -212,7 +221,9 @@ class EvoPPO:
                 params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
                 return (params, opt_state), loss
 
-            (params, opt_state), losses = jax.lax.scan(minibatch, (params, opt_state), batches)
+            with device_scope(UPDATE_SCOPE):
+                (params, opt_state), losses = jax.lax.scan(
+                    minibatch, (params, opt_state), batches)
             return (params, opt_state), losses.mean()
 
         params = {"actor": actor, "critic": critic}
@@ -223,9 +234,11 @@ class EvoPPO:
     # ------------------------------------------------------------------ #
     def member_iteration(self, state: MemberState) -> Tuple[MemberState, jax.Array]:
         """One generation for one member: rollout -> GAE -> PPO epochs."""
-        traj, vstate, obs, ep_ret, fitness, key = self._rollout(state)
-        last_value = EvolvableNetwork.apply(self.critic_config, state.critic, obs)[..., 0]
-        adv, ret = self._gae(traj, last_value)
+        with device_scope(ROLLOUT_SCOPE):
+            traj, vstate, obs, ep_ret, fitness, key = self._rollout(state)
+            last_value = EvolvableNetwork.apply(
+                self.critic_config, state.critic, obs)[..., 0]
+            adv, ret = self._gae(traj, last_value)
         key, k_up = jax.random.split(key)
         actor, critic, opt_state, _loss = self._ppo_update(
             state.actor, state.critic, state.opt_state, traj, adv, ret, k_up
