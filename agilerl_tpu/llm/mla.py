@@ -112,7 +112,8 @@ def attend_expanded(config: GPTConfig, blk, q_nope, q_rope, latent,
         from agilerl_tpu.ops.flash_attention_vjp import flash_attention_diff
 
         # spmd=False: the kernel's partitioning rule names one head size
-        # for q, k and v, and a mesh refuses this stack before it gets here
+        # for q, k and v, and a mesh refuses this stack before it gets here.
+        # The tiles are flash_plan's, from (T, 192, 128, dtype).
         attn = flash_attention_diff(qh, kh, vh, attention_mask, True,
                                     spmd=False)
     else:

@@ -902,7 +902,12 @@ def forward(
             vh = jnp.moveaxis(v, 2, 1)
             if use_flash:
                 # Pallas flash attention (causal + padding mask, custom VJP so
-                # it also serves training losses)
+                # it also serves training losses). No block sizes are passed:
+                # ops.flash_attention_vjp.flash_plan chooses each kernel's
+                # tile from (T, head widths, dtype) — one tile of the whole
+                # sequence at T 1024 or 1152, 1024 x 1024 from 2048 on — among the
+                # multiples of 128 that divide round_up(T, 128), so the
+                # padded extent never passes the next multiple of 128.
                 from agilerl_tpu.ops.flash_attention_vjp import (
                     flash_attention_diff,
                 )

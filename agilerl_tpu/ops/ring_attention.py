@@ -107,8 +107,8 @@ def ring_attention(
     kv_mask: Optional[jax.Array] = None,  # [B, T_local] 1 = real token; the
     # mask ROTATES around the ring with its k/v block (ragged/right-padded seqs)
     use_flash: bool = False,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,  # None: flash_plan chooses from T_local
+    block_k: Optional[int] = None,
 ) -> jax.Array:
     """Call INSIDE shard_map with q/k/v sharded on the sequence axis.
     ``use_flash=True`` swaps the per-block engine for the Pallas flash

@@ -21,7 +21,10 @@ from jax.sharding import SingleDeviceSharding
 from agilerl_tpu.llm import model as M
 from agilerl_tpu.llm.presets import preset
 from agilerl_tpu.llm.serving import ContinuousGenerator
-from agilerl_tpu.ops.flash_attention_vjp import flash_attention_diff
+from agilerl_tpu.ops.flash_attention_vjp import (
+    flash_attention_diff,
+    flash_plan,
+)
 from agilerl_tpu.ops.fused_loss import (
     fused_loss_plan,
     fused_token_logprob_diff,
@@ -106,19 +109,45 @@ def test_fused_loss_fwd_and_grad_at_qwen2_7b_head(v5e, N, dtype, head_grad):
     assert not moved, moved
 
 
-@pytest.mark.parametrize("spmd", [False, True])
-def test_flash_attention_fwd_and_grad_at_qwen2_7b_heads(v5e, spmd):
-    B, H, T, d = 4, 28, 2048, 128
+@pytest.mark.parametrize("B,H,T,d,dv,spmd", [
+    # the cells' learn batches as their configuration files give them: rows,
+    # query heads (GQA repeated before the kernel), positions, head widths
+    (8, 28, 1024, 128, 128, True),   # grpo_k4_reason
+    (8, 28, 1152, 128, 128, True),   # grpo_k4_longprompt: Tp stays 1152
+    (4, 28, 1024, 128, 128, False),  # grpo_learn_fsdp4: a chip, shard_map
+    (8, 32, 1024, 192, 128, False),  # grpo_kanana_reason: q, k 192 / v 128
+    (8, 8, 1024, 128, 128, True),    # grpo_zaya_reason
+    (8, 20, 1024, 128, 128, True),   # grpo_jamba_reason
+    (4, 28, 2048, 128, 128, False),  # past one tile: 1024 x 1024, clamped
+], ids=["k4_reason", "k4_longprompt", "learn_fsdp4", "kanana_reason",
+        "zaya_reason", "jamba_reason", "T2048"])
+def test_flash_attention_fwd_and_grad_at_the_cells_heads(v5e, B, H, T, d, dv,
+                                                         spmd):
     s = jax.ShapeDtypeStruct
-    q, m = _on(v5e, (s((B, H, T, d), jnp.bfloat16), s((B, T), jnp.int32)))
+    q, v, m = _on(v5e, (s((B, H, T, d), jnp.bfloat16),
+                        s((B, H, T, dv), jnp.bfloat16), s((B, T), jnp.int32)))
 
     def loss(qq, kk, vv, mm):
         return flash_attention_diff(
             qq, kk, vv, mm, True, spmd=spmd).astype(jnp.float32).sum()
 
+    # Mosaic takes the chosen tiling and its VMEM footprint
     text = _compiled_text(
-        jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))), q, q, q, m)
-    assert text.count(KERNEL) >= 3  # forward, dQ, dK/dV
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))), q, q, v, m)
+    assert text.count(KERNEL) == 3  # forward, dQ, dK/dV
+    tp = -(-T // 128) * 128
+    for kind in ("fwd", "dq", "dkv"):
+        assert f"flash_{kind}" in text
+        plan = flash_plan(T, d, dv, jnp.bfloat16, kind)
+        assert plan.t_pad == tp
+        print(f"\n  {kind}: {plan.block_q} x {plan.block_k} of Tp {plan.t_pad},"
+              f" {plan.vmem_bytes / 2**20:.1f} MiB of VMEM a grid step "
+              f"(limit asked {plan.vmem_limit_bytes / 2**20:.0f} MiB)", end="")
+    # the sequence is never padded past the next multiple of 128: every
+    # per-head array in the program is Tp long at most
+    extents = {int(t) for t in re.findall(rf"\[{B * H},(\d+),\d+\]", text)}
+    extents |= {int(t) for t in re.findall(rf"\[{B},{H},(\d+),\d+\]", text)}
+    assert tp in extents and max(extents) == tp, extents
 
 
 @pytest.fixture(scope="module")
