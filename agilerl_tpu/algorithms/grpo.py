@@ -361,6 +361,12 @@ class GRPO(EvolvableAlgorithm):
         Pass None to detach (restores the pre-attach ``continuous_decode``
         setting — detaching must not leave the agent on a private bare
         generator it never used before)."""
+        if self.model_config.varies:
+            raise NotImplementedError(
+                "attach_rollout_fleet over a stack with sliding-window "
+                "layers: the fleet's prefill workers size their prompt grid "
+                "themselves and have not run a window by layer; not "
+                "implemented")
         if self.model_config.is_mla:
             raise NotImplementedError(
                 "attach_rollout_fleet over a latent cache: the fleet's "
@@ -638,6 +644,11 @@ class GRPO(EvolvableAlgorithm):
         """(logprobs, update) for the active parallelism mode, with the
         sequence-parallel input contract validated against THIS batch."""
         if self.sequence_parallel_axis is not None:
+            if self.model_config.varies:
+                raise NotImplementedError(
+                    "sequence_parallel_axis over a stack with sliding-window "
+                    "layers: ring attention has no window and no positions "
+                    "by layer; not implemented")
             if self.model_config.is_cca:
                 raise NotImplementedError(
                     "sequence_parallel_axis over a CCA stack: the "
@@ -864,6 +875,12 @@ class GRPO(EvolvableAlgorithm):
                 raise ValueError("to_mesh needs a mesh or a plan")
             plan = PL.grpo_plan_for_mesh(mesh)
         plan, mesh = PL.resolve_plan_and_mesh(plan, mesh)
+        if self.model_config.varies:
+            raise ValueError(
+                "to_mesh over a stack with sliding-window layers: the period "
+                "scan's weights (params['runs'][r] a list, one tree a "
+                "position in the period) have no plan rule and the windowed "
+                "kernels have not been run under shard_map; not implemented")
         if self.model_config.is_cca:
             try:
                 plan.shardings("params", self.base_params, mesh, strict=True)

@@ -15,7 +15,9 @@ with logical axes ("embed", "heads"/"mlp") so GSPMD shards them over ("fsdp",
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -135,6 +137,23 @@ class GPTConfig:
     rotary_share: float = 1.0
     router_hidden: int = 0
     scaled_merge: bool = False
+    # Window and full attention mixed, and positions by layer. A layer i
+    # with window_layout[i] (every layer where the layout is None) sees the
+    # last sliding_window positions only: the flash kernels, the cached and
+    # the paged attention skip what lies behind the window. rope_layout[i]
+    # says whether layer i's q and k get rotary positions (None: ``rope`` in
+    # every layer). The two lists are two published keys and stay two
+    # fields. Layers of one run that differ in either are scanned a PERIOD
+    # of the pattern at a time (_run_layers), their kinds static in the body.
+    sliding_window: int = 0
+    window_layout: Optional[Tuple[int, ...]] = None
+    rope_layout: Optional[Tuple[int, ...]] = None
+    # The experts' gate activation ("silu": SwiGLU; "relu": ReGLU) and what
+    # the router reads: the FFN's normed input, or ("attn") the normed input
+    # of the layer's ATTENTION block — a router placed before attention,
+    # whose logits ride past it. Read by the dropless dispatch alone.
+    expert_act: str = "silu"
+    router_input: str = "ffn"
 
     def __post_init__(self):
         routed = (self.router_score != "softmax" or self.router_bias
@@ -167,6 +186,35 @@ class GPTConfig:
             raise ValueError(
                 "a router MLP scores for the dropless dispatch: state "
                 "n_experts and capacity_factor=None")
+        for name in ("window_layout", "rope_layout"):
+            layout = getattr(self, name)
+            if layout is not None and (not isinstance(layout, tuple)
+                                       or len(layout) != self.n_layer):
+                raise ValueError(
+                    f"{name} is a tuple with one entry a layer "
+                    f"({self.n_layer}), got {layout!r}")
+        if self.window_layout is not None and self.sliding_window <= 0:
+            raise ValueError("window_layout names window layers: state "
+                             "sliding_window, their window")
+        if self.varies and (self.is_mla or self.is_cca or self.is_hybrid):
+            raise ValueError(
+                "a sliding window and positions by layer (sliding_window, "
+                "window_layout, rope_layout) are built for a stack of "
+                "grouped-query attention layers: no latent cache, no CCA, "
+                "no state-space layers")
+        if self.expert_act not in ("silu", "relu") \
+                or self.router_input not in ("ffn", "attn"):
+            raise ValueError(
+                f"expert_act {self.expert_act!r} is 'silu' or 'relu', "
+                f"router_input {self.router_input!r} 'ffn' or 'attn'")
+        if (self.expert_act != "silu" or self.router_input != "ffn") and (
+                not self.is_dropless or self.router_hidden or self.is_mla
+                or self.is_hybrid):
+            raise ValueError(
+                "ReGLU experts and a router that reads the attention "
+                "block's input are the dropless dispatch's (n_experts, "
+                "capacity_factor=None), over grouped-query attention "
+                "layers and a linear router")
 
     def is_moe_layer(self, i: int) -> bool:
         return (self.n_experts > 0 and i >= self.n_dense_layers
@@ -202,9 +250,11 @@ class GPTConfig:
         """Weights are kept one stacked tree a RUN of equal layers
         (``params["runs"]``), the layout the programs scan, where stacking
         per-layer trees inside every program would cost a copy of the base:
-        hybrid stacks, latent attention, CCA and dense-then-expert stacks."""
+        hybrid stacks, latent attention, CCA, dense-then-expert stacks and
+        stacks whose layers have variants (there a run of period P > 1 is P
+        trees, one a position in the period: ``stack_run``)."""
         return (self.is_hybrid or self.is_mla or self.is_cca
-                or self.n_dense_layers > 0)
+                or self.n_dense_layers > 0 or self.varies)
 
     @property
     def ff_expert(self) -> int:
@@ -219,11 +269,47 @@ class GPTConfig:
             return "attn"
         return "mamba"
 
+    @property
+    def varies(self) -> bool:
+        """The stack states attention by layer (a window, positions by
+        layer): its layers have VARIANTS (``layer_variant``)."""
+        return self.sliding_window > 0 or self.rope_layout is not None
+
+    def layer_variant(self, i: int) -> Tuple[int, bool]:
+        """(window of layer i's attention, 0 = full; whether its q and k
+        get rotary positions): what a layer's program is static in beyond
+        its kind."""
+        windowed = self.sliding_window > 0 and (
+            self.window_layout is None or bool(self.window_layout[i]))
+        rope = self.rope if self.rope_layout is None \
+            else bool(self.rope_layout[i])
+        return (self.sliding_window if windowed else 0, rope)
+
+    @property
+    def variants(self):
+        """The distinct ``layer_variant``s of the stack."""
+        return {self.layer_variant(i) for i in range(self.n_layer)}
+
+    @property
+    def n_window_layers(self) -> int:
+        return sum(self.layer_variant(i)[0] > 0 for i in range(self.n_layer))
+
+    def run_period(self, first: int, n: int) -> int:
+        """The shortest period of the variants' pattern over the run of
+        ``n`` layers from ``first`` that divides n: 1 for equal layers, 4
+        for [full, win, win, win] x m, n where the pattern has none. One
+        ``lax.scan`` body holds one period."""
+        variants = [self.layer_variant(first + j) for j in range(n)]
+        return next(p for p in range(1, n + 1) if n % p == 0 and all(
+            variants[j] == variants[j % p] for j in range(n)))
+
     def layer_runs(self):
         """[(kind, first layer, number of layers)]: the maximal runs of
         consecutive structurally equal layers — one kind of mixer AND one
         kind of FFN, so an interleaved dense / expert stack (moe_every > 1)
-        is runs of one layer."""
+        is runs of one layer. A layer's VARIANT (window, positions) does not
+        end a run: a run whose variants repeat is scanned a period at a
+        time (``run_period``)."""
         runs, last = [], None
         for i in range(self.n_layer):
             kind = self.layer_kind(i)
@@ -413,13 +499,41 @@ def init_block(key: jax.Array, config: GPTConfig, i: int) -> Params:
     return blk
 
 
+def stack_run(blocks, period: int = 1):
+    """A run's per-layer trees in the layout its scan reads: one tree
+    stacked over the layers — or, where the run's variants repeat with a
+    ``period`` > 1, a list of ``period`` trees, tree p stacked over the
+    layers at position p of every period (layer ``j * period + p`` of the
+    run is row j of tree p), so that the period scan slices each as xs."""
+    stack = lambda *xs: jnp.stack(xs)  # noqa: E731
+    if period == 1:
+        return jax.tree_util.tree_map(stack, *blocks)
+    return [jax.tree_util.tree_map(stack, *blocks[p::period])
+            for p in range(period)]
+
+
+def run_layer(run, j: int):
+    """Layer j's tree out of a run stored as ``stack_run`` stores it."""
+    if isinstance(run, (list, tuple)):
+        run, j = run[j % len(run)], j // len(run)
+    return jax.tree_util.tree_map(lambda a: a[j], run)
+
+
+def run_length(run) -> int:
+    """Layers in a run stored as ``stack_run`` stores it."""
+    trees = run if isinstance(run, (list, tuple)) else [run]
+    return sum(jax.tree_util.tree_leaves(t)[0].shape[0] for t in trees)
+
+
 def init_params(key: jax.Array, config: GPTConfig) -> Params:
     """A uniform stack keeps one tree a layer (``params["blocks"][str(i)]``).
     A hybrid stack's weights (and those of every ``config.stores_runs``
     stack) are stored in the layout its programs scan: one
     stacked tree a RUN of equal layers (``params["runs"][r]``, leading axis =
     the run's layers, ``config.layer_runs()``'s order) — stacking per-layer
-    trees inside a program would cost a copy of the base in every one."""
+    trees inside a program would cost a copy of the base in every one. A
+    run whose layers' variants repeat with a period is ``stack_run``'s list
+    of one tree a position in the period."""
     d = config.d_model
     std = 0.02
     keys = jax.random.split(key, config.n_layer + 3)
@@ -431,8 +545,7 @@ def init_params(key: jax.Array, config: GPTConfig) -> Params:
     }
     if config.stores_runs:
         params["runs"] = [
-            jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
-                                   *blocks[first:first + n])
+            stack_run(blocks[first:first + n], config.run_period(first, n))
             for _, first, n in config.layer_runs()]
     else:
         params["blocks"] = {str(i): blk for i, blk in enumerate(blocks)}
@@ -537,12 +650,15 @@ def merge_lora(params: Params, lora: Params, scale: float = 2.0) -> Params:
     out = jax.tree_util.tree_map(lambda x: x, params)
     if "runs" in params:
         # a hybrid stack: layer i is row i - first of its run's stacked tree
+        # (of the tree at its position in the period where the run has one)
         first = 0
         for run in out["runs"]:
-            n = jax.tree_util.tree_leaves(run)[0].shape[0]
+            trees = run if isinstance(run, (list, tuple)) else [run]
+            n = run_length(run)
             for j in range(n):
+                tree, row = trees[j % len(trees)], j // len(trees)
                 for t, ab in lora["blocks"].get(str(first + j), {}).items():
-                    run[t] = run[t].at[j].add((ab["A"] @ ab["B"]) * scale)
+                    tree[t] = tree[t].at[row].add((ab["A"] @ ab["B"]) * scale)
             first += n
         return out
     for i, layer in lora["blocks"].items():
@@ -573,11 +689,14 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.reshape(x.shape)
 
 
-def _qkv_rope(config: GPTConfig, blk, x, positions, lora_layer, lora_scale):
+def _qkv_rope(config: GPTConfig, blk, x, positions, lora_layer, lora_scale,
+              rope=None):
     """Shared q/k/v projection + bias + head split + RoPE. ONE home for the
     projection maths so the dense cached path (forward) and the paged decode
     path (forward_paged) cannot drift — the paged serving tier's greedy
-    bit-parity guarantee rests on both paths running these exact ops."""
+    bit-parity guarantee rests on both paths running these exact ops.
+    ``rope``: this layer's (``GPTConfig.layer_variant``); None = the
+    stack's."""
     B, T = x.shape[:2]
     dtype = x.dtype
     q = _maybe_lora(x, blk["wq"], lora_layer, "wq", lora_scale, dtype)
@@ -590,10 +709,45 @@ def _qkv_rope(config: GPTConfig, blk, x, positions, lora_layer, lora_scale):
     q = q.reshape(B, T, config.n_head, config.head_dim)
     k = k.reshape(B, T, config.kv_heads, config.head_dim)
     v = v.reshape(B, T, config.kv_heads, config.head_dim)
-    if config.rope:
+    if config.rope if rope is None else rope:
         q = _rope(q, positions, config.rope_theta)
         k = _rope(k, positions, config.rope_theta)
     return q, k, v
+
+
+#: a learn-path attention block of a stack whose layers have variants, by
+#: kind (docs/observability.md): norm, projections, attention, ``wo``, merge
+WINDOW_SCOPE = "attn/win"
+FULL_SCOPE = "attn/full"
+
+
+def _attn_scope(config: GPTConfig, window: int, cached: bool):
+    """The scope of a learn-path attention block where the stack mixes
+    kinds of attention; no scope on any other stack or cached forward."""
+    if not config.varies or cached:
+        return contextlib.nullcontext()
+    return jax.named_scope(WINDOW_SCOPE if window else FULL_SCOPE)
+
+
+def _early_router_logits(config: GPTConfig, blk, h):
+    """A router placed before attention (``router_input="attn"``): its
+    logits from the attention block's NORMED input, in float32 from the
+    residual stream ``h`` [B, T, d] on — the norm is taken again here
+    without the rounding to the stream's type that ``_rms`` ends with, so
+    a choice of experts turns on the stream's own rounding alone (in the
+    first layer, whose input is a stored embedding, on none) —, under
+    ``moe/score``: what rides past attention to ``_block_ffn``. None on
+    any other stack or layer."""
+    if config.router_input != "attn" or "router" not in blk:
+        return None
+    from agilerl_tpu.llm import moe
+
+    with jax.named_scope(moe.SCORE_SCOPE):
+        x = h.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        x = x * jax.lax.rsqrt(var + config.rms_eps) \
+            * blk["ln1"].astype(jnp.float32)
+        return moe.linear_logits(x.reshape(-1, config.d_model), blk["router"])
 
 
 def _merge(blk, name, h, y):
@@ -625,11 +779,14 @@ def _stream_in(config: GPTConfig, h):
         (*h.shape[:2], config.router_hidden), jnp.float32))
 
 
-def _block_ffn(config: GPTConfig, blk, stream, lora_layer, lora_scale):
+def _block_ffn(config: GPTConfig, blk, stream, lora_layer, lora_scale,
+               logits=None):
     """Post-attention half of a block: RMSNorm + (MoE | SwiGLU) FFN with the
     residual merge. ``stream`` is what ``_run_layers`` carries (``_stream``);
     returns (stream out, aux). Shared between forward's block_fn
-    and forward_paged (same no-drift contract as _qkv_rope)."""
+    and forward_paged (same no-drift contract as _qkv_rope). ``logits``:
+    the router's where it reads the attention block's input
+    (``_early_router_logits``)."""
     h, s = _stream(config, stream)
     B, T = h.shape[:2]
     dtype = h.dtype
@@ -637,7 +794,12 @@ def _block_ffn(config: GPTConfig, blk, stream, lora_layer, lora_scale):
     if "router" in blk and config.is_dropless:
         from agilerl_tpu.llm import moe
 
-        x2d, logits = x.reshape(B * T, config.d_model), None
+        x2d = x.reshape(B * T, config.d_model)
+        if config.router_input == "attn" and logits is None:
+            raise ValueError(
+                "this stack's router reads the attention block's input: "
+                "the caller carries its logits past attention "
+                "(_early_router_logits)")
         if config.router_hidden:
             logits, s = moe.mlp_logits(x2d, blk, s.reshape(B * T, -1),
                                        config.rms_eps)
@@ -645,7 +807,7 @@ def _block_ffn(config: GPTConfig, blk, stream, lora_layer, lora_scale):
         out2d, load = moe.dropless_ffn(
             x2d, blk, top_k=config.expert_top_k, score=config.router_score,
             norm_topk=config.norm_topk, scale=config.routed_scale,
-            logits=logits)
+            logits=logits, act=config.expert_act)
         # what rides the aux channel of a dropless layer is its load, as
         # [fullest expert's rows over the mean, experts with any row]: no
         # auxiliary loss exists here (the router is frozen with the base)
@@ -703,6 +865,35 @@ def _same_structure(trees) -> bool:
     return all(sig(t) == first for t in trees[1:])
 
 
+def _scans(config: GPTConfig, steps: int, uniform_lora: bool) -> bool:
+    """Whether a run is ONE ``lax.scan`` (over its layers, or over its
+    periods): more than one step, ``scan_layers``, and adapters of one
+    structure from step to step. Otherwise the layers are called directly."""
+    return steps > 1 and config.scan_layers and uniform_lora
+
+
+def _hold_experts(config: GPTConfig, h, w):
+    """(a scanned run's stacked weights ``w`` without the experts' three
+    matrices, those three held whole — or ``w`` and {}).
+
+    Where a call's rows cannot touch every expert (a decode step: 8 rows x
+    6 choices of 128 experts), a dropless expert run's scan does not slice
+    the experts' weights: a slice of [n, E, d, f] handed to the grouped
+    matmul is a COPY of all E experts a layer (1.2 GB a decode step at 128
+    experts of 2048 x 768, three times what the step's rows read). They
+    stay whole, closed over, and step j addresses its experts as the groups
+    [j E, (j + 1) E) of one matmul grouped over n E
+    (moe.dropless_experts, ``expert_layer``). A call with more rows than
+    experts reads a layer's slice whole anyway, and its backward wants the
+    slice in a layout of its own."""
+    B, T = jax.tree_util.tree_leaves(h)[0].shape[:2]
+    few_rows = B * T * config.expert_top_k < config.n_experts
+    if not (few_rows and config.is_dropless and "router" in w):
+        return w, {}
+    held = {k: w[k] for k in ("w_gate", "w_up", "w_down")}
+    return {k: v for k, v in w.items() if k not in held}, held
+
+
 def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
     """THE layer loop, of every kind of stack. It walks
     ``config.layer_runs()``: a run of structurally equal layers is one
@@ -720,7 +911,13 @@ def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
 
     fns[kind](h, blk, x_i, lora_i) -> (h, y_i, aux); xs[kind]: None, or a
     list with one entry a run of that kind, each a tree stacked over the
-    run's layers. Returns (h, {kind: [ys of each run]}, aux)."""
+    run's layers. Returns (h, {kind: [ys of each run]}, aux).
+
+    Where the stack's layers have variants (``GPTConfig.varies``: a window,
+    positions by layer) ``fns[kind]`` is a dict from the variant to such a
+    function, and a run whose variants repeat with a period P > 1 is ONE
+    scan over its n / P periods (``_run_periods``): the body holds the P
+    layers of a period, each with its variant static."""
     stack = lambda *a: jnp.stack(a)  # noqa: E731
 
     def layer(t, j):
@@ -742,31 +939,27 @@ def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
         x_run = None if xs.get(kind) is None else xs[kind][seen[kind]]
         seen[kind] += 1
         fn = fns[kind]
+        if isinstance(fn, dict):
+            period = config.run_period(first, n)
+            if period > 1:
+                h, y, aux = _run_periods(
+                    config, [fn[config.layer_variant(first + p)]
+                             for p in range(period)], w, lo, x_run, h, aux)
+                ys[kind].append(y)
+                continue
+            fn = fn[config.layer_variant(first)]
         uniform_lora = _same_structure(lo)
-        scan = n > 1 and config.scan_layers and uniform_lora
+        scan = _scans(config, n, uniform_lora)
         if scan and isinstance(w, list):
             w = jax.tree_util.tree_map(stack, *w)
         if uniform_lora:
             # also where the run is not scanned: layer() then picks rows,
             # which is what a hybrid stack's runs of one have always lowered
             lo = jax.tree_util.tree_map(stack, *lo)
-        # Where a call's rows cannot touch every expert (a decode step: 8
-        # rows x 6 choices of 128 experts), a dropless expert run's scan
-        # does not slice the experts' weights: a slice of [n, E, d, f]
-        # handed to the grouped matmul is a COPY of all E experts a layer
-        # (1.2 GB a decode step at 128 experts of 2048 x 768, three times
-        # what the step's rows read). They stay whole, closed over, and
-        # layer j addresses its experts as the groups [j E, (j + 1) E) of
-        # one matmul grouped over n E (moe.dropless_experts). A call with
-        # more rows than experts reads a layer's slice whole anyway, and its
-        # backward wants the slice in a layout of its own.
         held = {}
-        B, T = jax.tree_util.tree_leaves(h)[0].shape[:2]
-        few_rows = B * T * config.expert_top_k < config.n_experts
-        if scan and few_rows and config.is_dropless and "router" in w:
-            held = {k: w[k] for k in ("w_gate", "w_up", "w_down")}
-            w = {k: v for k, v in w.items() if k not in held}
         if scan:
+            w, held = _hold_experts(config, h, w)
+
             def body(carry, x, fn=fn, held=held):
                 h, aux = carry
                 if held:
@@ -786,6 +979,69 @@ def _run_layers(config: GPTConfig, params: Params, lora, h, fns, xs):
             y = jax.tree_util.tree_map(stack, *outs)
         ys[kind].append(y)
     return h, ys, aux
+
+
+def _run_periods(config: GPTConfig, fns, w, lo, x_run, h, aux):
+    """A run whose layers' variants repeat with period ``P = len(fns)``:
+    one ``lax.scan`` over its n / P periods whose body calls the P layers
+    of a period one after the other, layer p with ``fns[p]`` — its variant
+    static, as the kernels want their window. ``w``: the run's weights as
+    ``stack_run`` stores them, tree p stacked over the periods and sliced
+    by the scan as xs; ``lo``: the n layers' adapters; ``x_run``: the run's
+    per-layer inputs stacked over its n layers in layer order (a cache),
+    closed over, each layer taking its own row — the slice of ONE layer
+    that a scan over layers takes, where a period's rows as xs would be a
+    slice P layers large. Returns (h, ys stacked over the n layers, aux).
+
+    A run of one period, adapters that differ between periods and
+    ``scan_layers=False`` call the layers directly (``_scans``). A dropless
+    expert run whose rows cannot touch every expert holds each position's
+    experts whole (``_hold_experts``)."""
+    stack = lambda *a: jnp.stack(a)  # noqa: E731
+    P, n = len(fns), len(lo)
+    m = n // P
+    if not isinstance(w, list) or len(w) != P:
+        raise ValueError(
+            f"a run of period {P} stores {P} trees, one a position in the "
+            "period (model.stack_run)")
+    uniform_lora = all(_same_structure(lo[p::P]) for p in range(P))
+    row = lambda t, j: jax.tree_util.tree_map(lambda a: a[j], t)  # noqa: E731
+
+    def layer_x(i):  # layer i's row of the run's inputs; i may be traced
+        if x_run is None:
+            return None
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            x_run)
+
+    if not _scans(config, m, uniform_lora):
+        outs = []
+        for i in range(n):
+            h, y, a = fns[i % P](h, row(w[i % P], i // P), layer_x(i), lo[i])
+            aux = aux + a
+            outs.append(y)
+        return h, jax.tree_util.tree_map(stack, *outs), aux
+
+    lo = [jax.tree_util.tree_map(stack, *lo[p::P]) for p in range(P)]
+    w, held = map(list, zip(*(_hold_experts(config, h, t) for t in w)))
+
+    def body(carry, x):
+        h, aux = carry
+        blks, los, j = x
+        ys = []
+        for p in range(P):
+            blk = blks[p]
+            if held[p]:
+                blk = {**blk, **held[p], "expert_layer": j}
+            h, y, a = fns[p](h, blk, layer_x(j * P + p), los[p])
+            aux = aux + a
+            ys.append(y)
+        return (h, aux), jax.tree_util.tree_map(stack, *ys)
+
+    (h, aux), y = jax.lax.scan(body, (h, aux), (w, lo, jnp.arange(m)))
+    # [m, P, ...] -> the run's n layers in layer order
+    return h, jax.tree_util.tree_map(
+        lambda a: a.reshape(n, *a.shape[2:]), y), aux
 
 
 def forward(
@@ -825,10 +1081,27 @@ def forward(
     else:
         start = cache_mask = None
 
-    def block_fn(stream, blk, layer_kv, lora_layer):
+    def block_fn(stream, blk, layer_kv, lora_layer, variant=None):
         """layer_kv: (k_cache [B,S,KV,hd], v_cache [B,S,KV,hd]) or None;
-        over a CCA stack a third member, the layer's rolling state."""
+        over a CCA stack a third member, the layer's rolling state.
+        ``variant``: the layer's (window, rotary) where the stack's layers
+        have variants (``GPTConfig.layer_variant``)."""
         h, router_state = _stream(config, stream)
+        window, rope = (0, None) if variant is None else variant
+        logits = _early_router_logits(config, blk, h)
+        with _attn_scope(config, window, layer_kv is not None):
+            h, new_kv = attend(h, blk, layer_kv, lora_layer, window, rope)
+        if config.is_mla:
+            h, aux = _block_ffn(config, blk, h, lora_layer, lora_scale)
+            return h, new_kv, aux
+        stream, aux = _block_ffn(
+            config, blk, _restream(config, h, router_state), lora_layer,
+            lora_scale, logits)
+        return stream, new_kv, aux
+
+    def attend(h, blk, layer_kv, lora_layer, window, rope):
+        """The attention half of a block: (h with the merged attention
+        output, the new tokens' K/V or None)."""
         x = _rms(h, blk["ln1"], config.rms_eps)
         if config.is_mla:
             from agilerl_tpu.llm import mla
@@ -851,8 +1124,7 @@ def forward(
                     lora_layer, lora_scale, use_flash)
             attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale,
                                dtype)
-            h, aux = _block_ffn(config, blk, h + attn, lora_layer, lora_scale)
-            return h, new_kv, aux
+            return h + attn, new_kv
         states = ()
         if config.is_cca:
             from agilerl_tpu.llm import cca
@@ -863,7 +1135,7 @@ def forward(
                 lora_scale)
         else:
             q, k, v = _qkv_rope(config, blk, x, positions, lora_layer,
-                                lora_scale)
+                                lora_scale, rope)
 
         if layer_kv is not None:
             # layer_kv = this layer's PRE-update (k_slab, v_slab). Attention
@@ -883,13 +1155,17 @@ def forward(
             # materializes GQA-repeated K/V (ops/decode_attention.py)
             from agilerl_tpu.ops.decode_attention import chunked_cached_attention
 
-            attn = chunked_cached_attention(q, ck, cv, cache_mask, start)
+            attn = chunked_cached_attention(q, ck, cv, cache_mask, start,
+                                            window=window)
             attn = attn.reshape(B, T, config.n_head * config.head_dim)
         else:
             new_kv = None
             # causal within the block + padding mask
             t_ids = jnp.arange(T)
             mask = (t_ids[None, None, :] <= t_ids[None, :, None])  # [1, T, S=T]
+            if window:  # and no further behind the query than the window
+                mask = jnp.logical_and(
+                    mask, t_ids[None, :, None] - t_ids[None, None, :] < window)
             mask = jnp.logical_and(mask, attention_mask[:, None, :].astype(bool))
             # GQA: repeat kv heads
             rep = config.n_head // config.kv_heads
@@ -923,7 +1199,7 @@ def forward(
                     qspec = P(bspec, hspec, None, None)
                     attn = shard_map(
                         lambda qq, kk, vv, mm: flash_attention_diff(
-                            qq, kk, vv, mm, True, spmd=False),
+                            qq, kk, vv, mm, True, spmd=False, window=window),
                         mesh=smesh,
                         in_specs=(qspec, qspec, qspec, P(bspec, None)),
                         out_specs=qspec,
@@ -931,7 +1207,7 @@ def forward(
                     )(qh, kh, vh, attention_mask)
                 else:
                     attn = flash_attention_diff(qh, kh, vh, attention_mask,
-                                                True)
+                                                True, window=window)
             else:
                 scores = jnp.einsum("bhtd,bhsd->bhts", qh, kh).astype(jnp.float32)
                 scores = scores / math.sqrt(config.head_dim)
@@ -942,14 +1218,15 @@ def forward(
                 B, T, config.n_head * config.head_dim
             )
         attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale, dtype)
-        h = _merge(blk, "merge1", h, attn)
-        stream, aux = _block_ffn(
-            config, blk, _restream(config, h, router_state), lora_layer,
-            lora_scale)
-        return stream, new_kv, aux
+        return _merge(blk, "merge1", h, attn), new_kv
 
     fn = jax.checkpoint(block_fn, static_argnums=()) if config.remat else block_fn
     fns = {"attn": fn}
+    if config.varies:  # one function a variant, its window and rotary static
+        wrap = jax.checkpoint if config.remat else (lambda f: f)
+        fns["attn"] = {
+            v: wrap(functools.partial(block_fn, variant=v))
+            for v in config.variants}
     if config.is_hybrid:
         from agilerl_tpu.llm import ssm
 
@@ -1370,9 +1647,11 @@ def forward_paged(
         tok_mask = jnp.take_along_axis(
             slot_mask, jnp.minimum(wp_start, S - 1)[:, None], axis=1)
 
-    def block_fn(stream, blk, layer_kv, lora_layer):
+    def block_fn(stream, blk, layer_kv, lora_layer, variant=None):
         h, router_state = _stream(config, stream)
+        window, rope = (0, None) if variant is None else variant
         states = ()
+        logits = _early_router_logits(config, blk, h)
         with device_scope(PROJ_SCOPE):
             x = _rms(h, blk["ln1"], config.rms_eps)
             if config.is_cca:
@@ -1384,12 +1663,12 @@ def forward_paged(
                 states = (new_state,)
             else:
                 q, k, v = _qkv_rope(config, blk, x, pos2d, lora_layer,
-                                    lora_scale)
+                                    lora_scale, rope)
         from agilerl_tpu.ops.decode_attention import chunked_paged_attention
 
         attn = chunked_paged_attention(q, layer_kv[0], layer_kv[1],
                                        block_tables, k, v, write_pos,
-                                       slot_mask, wp_start)
+                                       slot_mask, wp_start, window=window)
         attn = attn.reshape(B, T, config.n_head * config.head_dim)
         with device_scope(PROJ_SCOPE):
             attn = _maybe_lora(attn, blk["wo"], lora_layer, "wo", lora_scale,
@@ -1398,11 +1677,15 @@ def forward_paged(
         with device_scope(FFN_SCOPE):
             stream, aux = _block_ffn(
                 config, blk, _restream(config, h, router_state), lora_layer,
-                lora_scale)
+                lora_scale, logits)
         new_kv = (k, v) if write_pos.ndim == 2 else (k[:, 0], v[:, 0])
         return stream, new_kv + states, (aux if config.is_dropless else 0.0)
 
     fns = {"attn": mla_block_fn if config.is_mla else block_fn}
+    if config.varies:
+        fns["attn"] = {
+            v: functools.partial(block_fn, variant=v)
+            for v in config.variants}
     if config.is_hybrid:
         from agilerl_tpu.llm import ssm
 

@@ -11,8 +11,11 @@ an ``ep`` axis. A configuration that states none takes the dropless path —
 and weight) and an always-on ``shared`` expert beside it — which serves
 every token whatever the imbalance (``dropless_ffn`` is the whole layer).
 The logits ``route`` turns into a choice come from outside it:
-``linear_logits`` (``x @ router``), or ``mlp_logits``, a small network over
-a router state that is carried from layer to layer.
+``linear_logits`` (``x @ router``; of the FFN's input, or — a router placed
+before attention — of the attention block's normed input, computed by the
+caller under ``moe/score``), or ``mlp_logits``, a small network over a
+router state that is carried from layer to layer. The experts are gated
+units with a SiLU or a ReLU gate (``EXPERT_ACTS``).
 
 The bucketed dispatch: dense capacity-bucketed dispatch — routing is expressed as
 one-hot einsums over static shapes ([tokens, E, C] dispatch/combine tensors),
@@ -182,9 +185,15 @@ def expert_load(choice, n_experts: int):
     return jnp.zeros((n_experts,), jnp.int32).at[choice.reshape(-1)].add(1)
 
 
-def dropless_experts(x, choice, weights, w_gate, w_up, w_down, layer=None):
+#: a gated expert's activation by its published name: SwiGLU or ReGLU
+EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def dropless_experts(x, choice, weights, w_gate, w_up, w_down, layer=None,
+                     act: str = "silu"):
     """``out[n] = sum_k weights[n, k] * E_choice[n, k](x[n])`` with every
-    ``E`` a SwiGLU, for ALL N x k pairs: the pairs are sorted by expert, each
+    ``E`` a gated unit ``down(act(gate x) * up x)`` (``act`` "silu": a
+    SwiGLU; "relu": a ReGLU), for ALL N x k pairs: the pairs are sorted by expert, each
     projection is one grouped matmul over the experts (``lax.ragged_dot``:
     XLA:TPU tiles the rows by group and reads an expert's weights only where
     its group is not empty), and the result is un-sorted by the inverse
@@ -195,7 +204,7 @@ def dropless_experts(x, choice, weights, w_gate, w_up, w_down, layer=None):
     ``[n, E, ...]``, and this layer's experts are the groups ``[layer E,
     (layer + 1) E)`` of a matmul grouped over ``n E``: every other group is
     empty, so nothing of the other layers is read and nothing is sliced out
-    of the stacked array (``model._run_layers`` says why)."""
+    of the stacked array (``model._hold_experts`` says why)."""
     N, k = choice.shape
     E = w_gate.shape[-3]
     dtype = x.dtype
@@ -213,7 +222,8 @@ def dropless_experts(x, choice, weights, w_gate, w_up, w_down, layer=None):
     with jax.named_scope(EXPERTS_SCOPE):
         g = jax.lax.ragged_dot(xs, w_gate.astype(dtype), sizes)
         u = jax.lax.ragged_dot(xs, w_up.astype(dtype), sizes)
-        y = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_down.astype(dtype), sizes)
+        y = jax.lax.ragged_dot(EXPERT_ACTS[act](g) * u, w_down.astype(dtype),
+                               sizes)
     with jax.named_scope(COMBINE_SCOPE):
         back = jnp.argsort(order)  # where pair p went
         y = jnp.take(y, back, axis=0).reshape(N, k, -1)
@@ -229,11 +239,12 @@ def shared_expert(x, w_gate, w_up, w_down):
 
 
 def dropless_ffn(x, blk, *, top_k: int, score: str, norm_topk: bool,
-                 scale: float, logits=None):
+                 scale: float, logits=None, act: str = "silu"):
     """The whole expert layer on ``x`` [N, d] from a block's weights
     (``router``, optional ``router_bias``, ``w_gate/w_up/w_down`` stacked
     over experts, optional ``ws_gate/ws_up/ws_down``). ``logits`` [N, E]
-    where the router is not ``x @ router`` (``mlp_logits``). Returns (out
+    where the router is not ``x @ router`` (``mlp_logits``, or a router fed
+    from outside the FFN). ``act``: the experts' activation. Returns (out
     [N, d], load [E] int32: the rows each expert served)."""
     with jax.named_scope(ROUTE_SCOPE):
         if logits is None:
@@ -242,7 +253,8 @@ def dropless_ffn(x, blk, *, top_k: int, score: str, norm_topk: bool,
             logits, blk.get("router_bias"), top_k=top_k,
             score=score, norm_topk=norm_topk, scale=scale)
     out = dropless_experts(x, choice, weights, blk["w_gate"], blk["w_up"],
-                           blk["w_down"], layer=blk.get("expert_layer"))
+                           blk["w_down"], layer=blk.get("expert_layer"),
+                           act=act)
     if "ws_gate" in blk:
         out = out + shared_expert(x, blk["ws_gate"], blk["ws_up"],
                                   blk["ws_down"])

@@ -72,6 +72,51 @@ def _round_up(n: int, buckets: Sequence[int]) -> int:
     raise ValueError(f"{n} exceeds the largest bucket {buckets[-1]}")
 
 
+#: the prompt grid of a model whose context is 2048 positions or fewer
+BASE_PROMPT_BUCKETS = (64, 128, 256, 512, 1024, 2048)
+
+
+def default_prompt_buckets(config: M.GPTConfig) -> Tuple[int, ...]:
+    """The prompt grid where none is passed: ``BASE_PROMPT_BUCKETS`` and,
+    for a model with a longer context, doublings past 2048 up to
+    ``config.max_seq_len`` — so the longest prompt the continuous and the
+    bucketed tier take, and with it the pool's blocks a slot, follow from
+    the model and not from a constant."""
+    grid = list(BASE_PROMPT_BUCKETS)
+    while grid[-1] * 2 <= config.max_seq_len:
+        grid.append(grid[-1] * 2)
+    return tuple(grid)
+
+
+def _device_bytes_free() -> Optional[int]:
+    """Bytes of the first local device's memory that nothing holds yet,
+    where the backend says (a TPU does); None where it does not (the CPU
+    backend)."""
+    stats = jax.local_devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+def _refuse_window(config: M.GPTConfig, speculate, sharding_plan, mesh):
+    """What the serving tiers do not do over a stack that mixes window and
+    full attention (or states positions by layer), by mechanism."""
+    if not config.varies:
+        return
+    if as_spec_config(speculate) is not None:
+        raise ValueError(
+            "speculative decoding over a stack with sliding-window layers: "
+            "the multi-token verify step (speculate.paged_verify_step) is "
+            "not carried over to a window by layer; not implemented: build "
+            "the generator with speculate=None")
+    if sharding_plan is not None or mesh is not None:
+        raise ValueError(
+            "a serving plan over a stack with sliding-window layers: the "
+            "period scan's weights (one tree a position in the period) have "
+            "no rule, and the window has not been run on a mesh; not "
+            "implemented on a mesh")
+
+
 def _sampling_knobs(gen, greedy: bool, lora) -> Dict[str, Any]:
     """The per-call knob dict both serving generators hand to the shared
     prefill/decode building blocks — ONE home so the two tiers cannot
@@ -150,7 +195,7 @@ class BucketedGenerator:
         max_new_tokens: int = 64,
         pad_id: int = 0,
         eos_id: Optional[int] = None,
-        prompt_buckets: Sequence[int] = (64, 128, 256, 512, 1024, 2048),
+        prompt_buckets: Optional[Sequence[int]] = None,
         row_buckets: Sequence[int] = (8, 16, 32, 64, 128),
         decode_chunk: int = 32,
         temperature: float = 1.0,
@@ -176,7 +221,11 @@ class BucketedGenerator:
         self._pending_lock = threading.Lock()
         self.pad_id = int(pad_id)
         self.eos_id = eos_id
-        self.prompt_buckets = tuple(sorted(prompt_buckets))
+        _refuse_window(config, None, sharding_plan, mesh)
+        # None: the grid follows from the model (default_prompt_buckets)
+        self.prompt_buckets = tuple(sorted(
+            default_prompt_buckets(config) if prompt_buckets is None
+            else prompt_buckets))
         self.row_buckets = tuple(sorted(row_buckets))
         # a chunk larger than the whole budget would waste decode forwards
         # past max_new_tokens (review finding)
@@ -739,7 +788,7 @@ class ContinuousGenerator:
         max_new_tokens: int = 64,
         pad_id: int = 0,
         eos_id: Optional[int] = None,
-        prompt_buckets: Sequence[int] = (64, 128, 256, 512, 1024, 2048),
+        prompt_buckets: Optional[Sequence[int]] = None,
         slots: int = 8,
         block_size: int = 32,
         n_blocks: Optional[int] = None,
@@ -765,6 +814,7 @@ class ContinuousGenerator:
     ):
         self.config = config
         self.metrics = metrics if metrics is not None else observability.get_registry()
+        _refuse_window(config, speculate, sharding_plan, mesh)
         if config.is_cca:
             if as_spec_config(speculate) is not None:
                 raise ValueError(
@@ -812,6 +862,8 @@ class ContinuousGenerator:
                     "recurrent-state cache (per-slot conv and SSM state "
                     "beside the paged pool); not implemented on a mesh")
         self._tracer = tracer
+        #: layers whose attention has a sliding window (0: no such stack)
+        self._window_layers = config.n_window_layers
         # declarative serving layout: the paged pool is placed by the plan's
         # "kv" rules at allocation (kv-heads over tp; the pool has no batch
         # dim so (dp,fsdp) entries filter away), weights via place_params
@@ -819,7 +871,11 @@ class ContinuousGenerator:
             sharding_plan, mesh)
         self.pad_id = int(pad_id)
         self.eos_id = eos_id
-        self.prompt_buckets = tuple(sorted(prompt_buckets))
+        # None: the grid, and with it max_blocks and the pool, follow from
+        # the model's context (default_prompt_buckets)
+        self.prompt_buckets = tuple(sorted(
+            default_prompt_buckets(config) if prompt_buckets is None
+            else prompt_buckets))
         self.block_size = int(block_size)
         for b in self.prompt_buckets:
             if b % self.block_size:
@@ -1358,8 +1414,34 @@ class ContinuousGenerator:
         without one)."""
         return _place_params(self, params, lora)
 
+    def _refuse_a_pool_the_device_cannot_hold(self) -> None:
+        """The pool is provisioned for ``slots`` requests of the grid's top
+        bucket + the new tokens, and without ``prompt_buckets`` the grid's
+        top is the model's ``max_seq_len`` (``default_prompt_buckets``): at
+        a long context that is more than one device has free, and the
+        allocator would fail without saying what to pass."""
+        free = _device_bytes_free()
+        if free is None or self.mesh is not None:
+            return
+        shapes = jax.eval_shape(lambda: M.init_paged_cache(
+            self.config, self.n_blocks, self.block_size, slots=self.slots,
+            snapshots=0 if self._snapshots is None else self._snapshots.n))
+        need = sum(x.size * x.dtype.itemsize
+                   for x in jax.tree_util.tree_leaves(shapes))
+        if need > free:
+            raise ValueError(
+                f"the paged pool would hold {need / 1e9:.2f} GB "
+                f"({self.n_blocks} blocks of {self.block_size} tokens: "
+                f"{self.slots} slots x {self.max_blocks} blocks, a prompt "
+                f"grid up to {self.prompt_buckets[-1]} + "
+                f"{self._decode_extent} new tokens) and the device has "
+                f"{free / 1e9:.2f} GB free: pass prompt_buckets= with a lower "
+                "top, fewer slots=, n_blocks= below full provisioning, or "
+                "a GPTConfig with the max_seq_len the prompts need")
+
     def _ensure_pool(self) -> None:
         if self._pool is None:
+            self._refuse_a_pool_the_device_cannot_hold()
             if self._snapshots is None:
                 pool = M.init_paged_cache(
                     self.config, self.n_blocks, self.block_size)
@@ -1376,6 +1458,12 @@ class ContinuousGenerator:
                 help="bytes one physical block holds across the attention "
                      "layers, from the pool's own arrays",
             ).set(M.paged_block_bytes(pool))
+            if self._window_layers:
+                self.metrics.gauge(
+                    "serving/window_layers",
+                    help="layers of the stack whose attention has a sliding "
+                         "window (a constant of the model)",
+                ).set(self._window_layers)
             if self.sharding_plan is not None:
                 # kv_paged, NOT kv: the pool's axis 1 is global block ids —
                 # the dense rules' (dp,fsdp) batch entry must never touch it
@@ -2006,6 +2094,8 @@ class ContinuousGenerator:
                     "serving/decode_time_per_token_s", buckets=DECODE_BUCKETS,
                     help="decode-chunk wall time / delivered chunk tokens",
                 ).observe(dt_chunk / delivered)
+            if self._window_layers:
+                self._set_window_dead_bytes()
             for slot, req in enumerate(self._slot_req):
                 if req is None:
                     continue
@@ -2013,6 +2103,29 @@ class ContinuousGenerator:
                     finished.append(self._finish_slot(slot))
             self._set_pool_gauges()
         return finished
+
+    def _set_window_dead_bytes(self) -> None:
+        """``serving/window_dead_bytes``: pool bytes of the live slots'
+        blocks that lie WHOLLY behind the window in the window layers —
+        kept (the pool has one layout and one table for every layer) and
+        never read again. A block two slots share counts once. What an
+        allocator with two kinds of layer would free."""
+        live = np.array([req is not None for req in self._slot_req])
+        # blocks a slot holds wholly behind the next query's window (host
+        # mirrors: numpy, no device value)
+        behind = np.maximum(
+            self._lengths - self.config.sliding_window + 1, 0
+        ) // self.block_size
+        dead = self._tables[(np.arange(self.max_blocks)[None, :]
+                             < behind[:, None]) & live[:, None]]
+        layers = self.config.n_layers_of("attn")
+        per_layer = M.paged_block_bytes(self._pool) // layers
+        self.metrics.gauge(
+            "serving/window_dead_bytes",
+            help="pool bytes of live slots wholly behind a window layer's "
+                 "window: held, never read",
+        ).set(np.unique(dead[dead != 0]).size * per_layer
+              * self._window_layers)
 
     def _set_pool_gauges(self) -> None:
         """Once per scheduler iteration, after its last change to either."""

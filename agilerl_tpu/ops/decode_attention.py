@@ -31,6 +31,14 @@ differ in:
   at the benchmark's sizes). Forward-only. Its loop runs under the scope
   ``paged/attend``.
 
+A sliding window (``window=W`` > 0: query t sees slots ``t - W < j <= t``,
+a window layer of a stack that mixes window and full attention) is the
+loop's too: it STARTS at the chunk that holds the shallowest live row's slot
+``start - W + 1`` — what lies wholly behind every row's window is neither
+fetched nor read — and masks inside the chunks it runs. The paged entry's
+loop of a window layer runs under ``paged/attend_win`` inside
+``paged/attend``.
+
 Both layouts of a cache go through both entries: K and V with a head axis
 (``[.., Hkv, d]``), or one latent array without one whose value is the first
 ``v_width`` columns of its key (llm/mla.py).
@@ -63,6 +71,7 @@ the chunk written out once between the pool and the matmuls
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -72,10 +81,11 @@ import jax.numpy as jnp
 import numpy as np
 
 PAGED_SCOPE = "paged/attend"
+PAGED_WINDOW_SCOPE = "paged/attend_win"  # a window layer's loop, inside it
 
 
 def _dense_reference(q, k_cache, v_cache, valid, start, scale=None,
-                     v_width=None):
+                     v_width=None, window=0):
     """Differentiable dense formulation of the same visibility rule — used
     only as the backward path (custom VJP): the chunked forward's
     dynamic-trip-count while_loop is not reverse-differentiable, but its
@@ -99,6 +109,9 @@ def _dense_reference(q, k_cache, v_cache, valid, start, scale=None,
     start_b = jnp.broadcast_to(jnp.asarray(start), (B,))
     causal = (slot[None, None, :]
               <= (start_b[:, None] + jnp.arange(T)[None, :])[:, :, None])  # [B, T, S]
+    if window:
+        behind = (start_b[:, None] + jnp.arange(T)[None, :] - window)[:, :, None]
+        causal = jnp.logical_and(causal, slot[None, None, :] > behind)
     mask = jnp.logical_and(
         causal[:, None, None], valid.astype(bool)[:, None, None, None, :]
     )
@@ -112,13 +125,17 @@ def _dense_reference(q, k_cache, v_cache, valid, start, scale=None,
 
 
 @functools.lru_cache(maxsize=None)
-def _make_chunked(block: int, scale=None, v_width=None):
+def _make_chunked(block: int, scale=None, v_width=None, window=0):
     if v_width is not None:
+        if window:
+            raise NotImplementedError(
+                "a sliding window over a latent cache: not implemented")
         return _make_chunked_latent(block, scale, v_width)
 
     @jax.custom_vjp
     def f(q, k_cache, v_cache, valid, start):
-        return _chunked_impl(q, k_cache, v_cache, valid, start, block)
+        return _chunked_impl(q, k_cache, v_cache, valid, start, block,
+                             window=window)
 
     def fwd(q, k_cache, v_cache, valid, start):
         return f(q, k_cache, v_cache, valid, start), (
@@ -128,7 +145,8 @@ def _make_chunked(block: int, scale=None, v_width=None):
     def bwd(res, g):
         q, k_cache, v_cache, valid, start = res
         _, vjp = jax.vjp(
-            lambda q_, k_, v_: _dense_reference(q_, k_, v_, valid, start),
+            lambda q_, k_, v_: _dense_reference(q_, k_, v_, valid, start,
+                                                window=window),
             q, k_cache, v_cache,
         )
         dq, dk, dv = vjp(g)
@@ -165,7 +183,8 @@ def _make_chunked_latent(block: int, scale: float, v_width: int):
     return lambda q, k_cache, v_cache, valid, start: f(q, k_cache, valid, start)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "scale", "v_width"))
+@functools.partial(jax.jit, static_argnames=("block", "scale", "v_width",
+                                             "window"))
 def chunked_cached_attention(
     q: jax.Array,        # [B, T, Hq, d] RoPE'd queries (absolute pos start..start+T)
     k_cache: jax.Array,  # [B, S, Hkv, d] cache AFTER inserting this step's K
@@ -178,9 +197,11 @@ def chunked_cached_attention(
     block: int = 512,
     scale: Optional[float] = None,
     v_width: Optional[int] = None,
+    window: int = 0,
 ) -> jax.Array:
     """Returns attention output [B, T, Hq, d] (same visibility rule as the
-    dense path: slot j visible to query t iff j <= start[b] + t and valid[j]).
+    dense path: slot j visible to query t iff j <= start[b] + t and valid[j]
+    — and, with a ``window``, j > start[b] + t - window).
     Reverse-differentiable: grads route through a dense backward (custom
     VJP) since the dynamic-bound forward loop cannot be transposed.
 
@@ -189,13 +210,14 @@ def chunked_cached_attention(
     the value of a slot is the first ``v_width`` columns of its key,
     ``v_cache`` is None and the output is ``[B, T, Hq, v_width]``: the latent
     cache of llm/mla.py, where ``d`` is not the published head size."""
-    return _make_chunked(min(block, k_cache.shape[1]), scale, v_width)(
+    return _make_chunked(min(block, k_cache.shape[1]), scale, v_width,
+                         window)(
         q, k_cache, v_cache, valid, jnp.asarray(start)
     )
 
 
 def _chunked_impl(q, k_cache, v_cache, valid, start, block, scale=None,
-                  v_width=None):
+                  v_width=None, window=0):
     """The contiguous fetch: chunk i is a slice of a cache that already
     holds this call's K/V."""
     S = k_cache.shape[1]
@@ -208,10 +230,11 @@ def _chunked_impl(q, k_cache, v_cache, valid, start, block, scale=None,
         return ks, jax.lax.dynamic_slice_in_dim(v_cache, off_c, block, axis=1)
 
     return _online_softmax(q, fetch, S, Hkv, valid, start, None, block,
-                           scale, v_width)
+                           scale, v_width, window)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "scale", "v_width"))
+@functools.partial(jax.jit, static_argnames=("block", "scale", "v_width",
+                                             "window"))
 def chunked_paged_attention(
     q: jax.Array,             # [B, T, Hq, d] RoPE'd queries
     pool_k: jax.Array,        # [nb, bs, Hkv, d] ONE layer's pool, BEFORE this
@@ -227,6 +250,7 @@ def chunked_paged_attention(
     block: int = 512,
     scale: Optional[float] = None,
     v_width: Optional[int] = None,
+    window: int = 0,
 ) -> jax.Array:
     """``chunked_cached_attention`` over a block pool: the same loop, chunk
     boundaries, masks and order of accumulation, with chunk ``i`` taken
@@ -274,19 +298,29 @@ def chunked_paged_attention(
         return chunk(pool_k, new_k), (None if pool_v is None
                                       else chunk(pool_v, new_v))
 
-    with jax.named_scope(PAGED_SCOPE):
+    if window and v_width is not None:
+        raise NotImplementedError(
+            "a sliding window over a latent cache: not implemented")
+    with contextlib.ExitStack() as scopes:
+        scopes.enter_context(jax.named_scope(PAGED_SCOPE))
+        if window:
+            scopes.enter_context(jax.named_scope(PAGED_WINDOW_SCOPE))
         return _online_softmax(
             q, fetch, mb * bs, 1 if v_width is not None else pool_k.shape[2],
             valid, jnp.asarray(start), jnp.any(valid.astype(bool), axis=1),
-            per * bs, scale, v_width)
+            per * bs, scale, v_width, window)
 
 
 def _online_softmax(q, fetch, S, Hkv, valid, start, live_rows, block, scale,
-                    v_width):
+                    v_width, window=0):
     """The one loop both entries run. ``fetch(off_c)`` returns the keys and
     values of slots ``off_c .. off_c + block`` ([B, block, Hkv, d] each; a
     latent cache's [B, block, d] and None). ``live_rows`` ([B] bool, or
-    None for all) are the rows whose depth bounds the loop."""
+    None for all) are the rows whose depth bounds the loop. With a
+    ``window`` the loop starts at the chunk that holds slot ``start - window
+    + 1`` of the shallowest such row (the first query's window; later
+    queries' begin later) and slots at or behind ``start + t - window`` are
+    masked."""
     B, T, Hq, d = q.shape
     rep = Hq // Hkv
     if scale is None:
@@ -306,6 +340,14 @@ def _online_softmax(q, fetch, S, Hkv, valid, start, live_rows, block, scale,
     n_chunks = jnp.minimum(
         (live + block - 1) // block, -(-S // block)
     ).astype(jnp.int32)
+
+    first_chunk = 0
+    if window:
+        shallowest = jnp.min(start_b if live_rows is None
+                             else jnp.where(live_rows, start_b, S))
+        first_chunk = jnp.minimum(
+            jnp.maximum(shallowest - window + 1, 0) // block, n_chunks
+        ).astype(jnp.int32)
 
     m0 = jnp.full((B, Hkv, rep, T), -1e30, jnp.float32)
     l0 = jnp.zeros((B, Hkv, rep, T), jnp.float32)
@@ -331,6 +373,9 @@ def _online_softmax(q, fetch, S, Hkv, valid, start, live_rows, block, scale,
         slot = off_c + jnp.arange(block)
         causal = (slot[None, None, :]
                   <= (start_b[:, None] + t_ids[None, :])[:, :, None])  # [B, T, BK]
+        if window:
+            behind = (start_b[:, None] + t_ids[None, :] - window)[:, :, None]
+            causal = jnp.logical_and(causal, slot[None, None, :] > behind)
         fresh = slot >= off                                            # [BK]
         mask = jnp.logical_and(
             jnp.logical_and(causal, fresh[None, None, :])[:, None, None],
@@ -348,7 +393,8 @@ def _online_softmax(q, fetch, S, Hkv, valid, start, live_rows, block, scale,
         )
         return m_new, l, acc
 
-    _, l, acc = jax.lax.fori_loop(0, n_chunks, chunk_step, (m0, l0, acc0))
+    _, l, acc = jax.lax.fori_loop(first_chunk, n_chunks, chunk_step,
+                                  (m0, l0, acc0))
     out = acc / jnp.maximum(l, 1e-30)[..., None]   # [B, Hkv, rep, T, d]
     out = jnp.moveaxis(out, 3, 1)                  # [B, T, Hkv, rep, d]
     return out.reshape(B, T, Hq, dv).astype(q.dtype)

@@ -41,6 +41,14 @@ caller that knows better); one given alone leaves the other at 128, and Tp
 is then a multiple of both, as it always was. Under ``causal`` a tile wholly
 above the diagonal computes nothing and its index maps are clamped onto the
 last live tile, so it fetches nothing either.
+
+Window. With ``window=W`` > 0 (and ``causal``) query t sees keys
+``t - W < j <= t``: a sliding-window layer. The mask gains that edge, a tile
+wholly BEHIND the window is skipped as one wholly above the diagonal is, the
+index maps stay on a live tile on that side too, ``flash_plan`` counts the
+live tiles of the band, and the three kernels carry names of their own
+(``flash_fwd_win`` / ``flash_dq_win`` / ``flash_dkv_win``) so that a reader
+can credit an execution with the pairs it computes. ``W >= T`` is no window.
 """
 
 from __future__ import annotations
@@ -114,17 +122,23 @@ def _vmem_estimate(bq: int, bk: int, d: int, dv: int, isz: int,
     return blocks + out + 2 * row + col + tiles
 
 
-def _live_tiles(nq: int, nk: int, bq: int, bk: int, causal: bool) -> int:
+def _live_tiles(nq: int, nk: int, bq: int, bk: int, causal: bool,
+                window: int = 0) -> int:
     """Tiles of the (nq, nk) grid that compute: all of them, or under
-    ``causal`` those with a key at or before their last query."""
+    ``causal`` those with a key at or before their last query — and, with a
+    ``window``, a key inside the window of their first query."""
     if not causal:
         return nq * nk
-    return sum(min(nk, (i * bq + bq - 1) // bk + 1) for i in range(nq))
+    if not window:
+        return sum(min(nk, (i * bq + bq - 1) // bk + 1) for i in range(nq))
+    return sum(min(nk, (i * bq + bq - 1) // bk + 1)
+               - max(i * bq - window + 1, 0) // bk for i in range(nq))
 
 
 def flash_plan(T: int, d: int, dv: int, dtype, kind: str, causal: bool = True,
                block_q: Optional[int] = None, block_k: Optional[int] = None,
-               vmem_capacity: Optional[int] = None) -> FlashPlan:
+               vmem_capacity: Optional[int] = None,
+               window: int = 0) -> FlashPlan:
     """The tile one of the three kernels (``kind``: "fwd", "dq", "dkv") walks
     over queries and keys of length T with heads ``d`` (q, k) and ``dv`` (v)
     wide, chosen from those, the operands' itemsize and the device's VMEM
@@ -137,7 +151,8 @@ def flash_plan(T: int, d: int, dv: int, dtype, kind: str, causal: bool = True,
     least: live tiles x (block_q x block_k + ``_STEP_ELEMS``), i.e. the score
     elements computed (a tile on the diagonal computes its upper half for
     nothing, a tile above it is skipped) plus a fixed cost a grid step; the
-    larger block on the inner grid axis of two that cost the same. The
+    larger block on the inner grid axis of two that cost the same. Under a
+    ``window`` the live tiles are the band's. The
     compiler is asked for three quarters of VMEM (16 MiB is only Mosaic's
     default scoped limit).
 
@@ -168,7 +183,7 @@ def flash_plan(T: int, d: int, dv: int, dtype, kind: str, causal: bool = True,
     fits = [(bq, bk) for bq in sides for bk in sides
             if _vmem_estimate(bq, bk, d, dv, isz, kind) <= capacity // 2]
     bq, bk = min(fits or [(128, 128)], key=lambda t: (
-        _live_tiles(t_pad // t[0], t_pad // t[1], *t, causal)
+        _live_tiles(t_pad // t[0], t_pad // t[1], *t, causal, window)
         * (t[0] * t[1] + _STEP_ELEMS), -inner(*t)))
     return plan(bq, bk, t_pad)
 
@@ -186,21 +201,45 @@ def _dot(a, b, dims=_NN):
     return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _tile_mask(shape, q0, k0, causal, seq_len, pm_ref):
+def _tile_mask(shape, q0, k0, causal, seq_len, pm_ref, window=0):
     """Which [block_k, block_q] scores count, for the tile whose first query
-    is q0 and first key k0: keys at or before the query (``causal``), keys
-    short of ``seq_len`` (None where T == Tp or a padding mask says so
-    already) and keys the padding mask keeps (its [block_k, 1] column)."""
+    is q0 and first key k0: keys at or before the query (``causal``) and
+    less than ``window`` behind it, keys short of ``seq_len`` (None where
+    T == Tp or a padding mask says so already) and keys the padding mask
+    keeps (its [block_k, 1] column)."""
     keys = lax.broadcasted_iota(jnp.int32, shape, 0)
     masks = []
     if causal:
-        masks.append(
-            keys - lax.broadcasted_iota(jnp.int32, shape, 1) <= q0 - k0)
+        behind = keys - lax.broadcasted_iota(jnp.int32, shape, 1)
+        masks.append(behind <= q0 - k0)
+        if window:
+            masks.append(behind > q0 - k0 - window)
     if seq_len is not None:
         masks.append(keys < seq_len - k0)
     if pm_ref is not None:
         masks.append(pm_ref[0] > 0)
     return functools.reduce(jnp.logical_and, masks) if masks else None
+
+
+def _in_window(live, q0, k0, block_k, window):
+    """``live`` and the tile's last key inside the window of its first
+    query: a tile wholly behind the window is skipped as one wholly above
+    the diagonal is."""
+    return jnp.logical_and(live, k0 + block_k - 1 > q0 - window)
+
+
+def _run_live(body, live, q0, k0, block_q, block_k, window):
+    """Run ``body(edged)`` on a live tile of a WINDOWED kernel: with the
+    causal and the window edge in its mask where the tile straddles either
+    (a key above a query, or a pair the window parts), without them on an
+    interior tile — most tiles of a long band, and the two compares and the
+    ``and`` a score are vector work the MXU waits for."""
+    live = _in_window(live, q0, k0, block_k, window)
+    edged = jnp.logical_or(k0 + block_k - 1 > q0,
+                           q0 + block_q - 1 - k0 >= window)
+    pl.when(jnp.logical_and(live, edged))(lambda: body(True))
+    pl.when(jnp.logical_and(live, jnp.logical_not(edged)))(
+        lambda: body(False))
 
 
 def _probs(q, k, lse_ref, scale, mask):
@@ -209,7 +248,8 @@ def _probs(q, k, lse_ref, scale, mask):
     return p if mask is None else jnp.where(mask, p, 0.0)
 
 
-def _fwd_kernel(scale, causal, block_q, block_k, seq_len, with_mask):
+def _fwd_kernel(scale, causal, block_q, block_k, seq_len, with_mask,
+                window=0):
     def kernel(*refs):
         if with_mask:
             (q_ref, k_ref, v_ref, pm_ref, out_ref, lse_ref,
@@ -227,11 +267,11 @@ def _fwd_kernel(scale, causal, block_q, block_k, seq_len, with_mask):
             l_ref[:] = jnp.zeros_like(l_ref)
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        def body():
+        def body(edged=True):
             q, k, v = q_ref[0], k_ref[0], v_ref[0]
             s = _dot(k, q, _NT) * scale  # [BK, BQ]
-            mask = _tile_mask(s.shape, qi * block_q, kj * block_k, causal,
-                              seq_len, pm_ref)
+            mask = _tile_mask(s.shape, qi * block_q, kj * block_k,
+                              causal and edged, seq_len, pm_ref, window)
             if mask is not None:
                 s = jnp.where(mask, s, -1e30)
             m_old = m_ref[:]  # [1, BQ]
@@ -244,7 +284,12 @@ def _fwd_kernel(scale, causal, block_q, block_k, seq_len, with_mask):
             m_ref[:] = m_new
 
         if causal:
-            pl.when(kj * block_k <= qi * block_q + block_q - 1)(body)
+            live = kj * block_k <= qi * block_q + block_q - 1
+            if window:
+                _run_live(body, live, qi * block_q, kj * block_k, block_q,
+                          block_k, window)
+            else:
+                pl.when(live)(body)
         else:
             body()
 
@@ -257,7 +302,8 @@ def _fwd_kernel(scale, causal, block_q, block_k, seq_len, with_mask):
     return kernel
 
 
-def _dq_kernel(scale, causal, block_q, block_k, seq_len, with_mask):
+def _dq_kernel(scale, causal, block_q, block_k, seq_len, with_mask,
+               window=0):
     def kernel(*refs):
         if with_mask:
             (q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, pm_ref,
@@ -273,16 +319,21 @@ def _dq_kernel(scale, causal, block_q, block_k, seq_len, with_mask):
         def _init():
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        def body():
+        def body(edged=True):
             q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
             mask = _tile_mask((block_k, block_q), qi * block_q, kj * block_k,
-                              causal, seq_len, pm_ref)
+                              causal and edged, seq_len, pm_ref, window)
             p = _probs(q, k, lse_ref, scale, mask)
             ds = p * (_dot(v, do, _NT) - dd_ref[0])  # [BK, BQ]
             acc_ref[:] = acc_ref[:] + _dot(ds.astype(k.dtype), k, _TN)
 
         if causal:
-            pl.when(kj * block_k <= qi * block_q + block_q - 1)(body)
+            live = kj * block_k <= qi * block_q + block_q - 1
+            if window:
+                _run_live(body, live, qi * block_q, kj * block_k, block_q,
+                          block_k, window)
+            else:
+                pl.when(live)(body)
         else:
             body()
 
@@ -293,7 +344,8 @@ def _dq_kernel(scale, causal, block_q, block_k, seq_len, with_mask):
     return kernel
 
 
-def _dkv_kernel(scale, causal, block_q, block_k, seq_len, with_mask):
+def _dkv_kernel(scale, causal, block_q, block_k, seq_len, with_mask,
+                window=0):
     def kernel(*refs):
         if with_mask:
             (q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, pm_ref,
@@ -311,10 +363,10 @@ def _dkv_kernel(scale, causal, block_q, block_k, seq_len, with_mask):
             dk_acc[:] = jnp.zeros_like(dk_acc)
             dv_acc[:] = jnp.zeros_like(dv_acc)
 
-        def body():
+        def body(edged=True):
             q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
             mask = _tile_mask((block_k, block_q), qi * block_q, kj * block_k,
-                              causal, seq_len, pm_ref)
+                              causal and edged, seq_len, pm_ref, window)
             p = _probs(q, k, lse_ref, scale, mask)
             dv_acc[:] = dv_acc[:] + _dot(p.astype(do.dtype), do)
             ds = p * (_dot(v, do, _NT) - dd_ref[0])
@@ -322,7 +374,12 @@ def _dkv_kernel(scale, causal, block_q, block_k, seq_len, with_mask):
 
         if causal:
             # q blocks strictly before this kv block contribute nothing
-            pl.when(qi * block_q + block_q - 1 >= kj * block_k)(body)
+            live = qi * block_q + block_q - 1 >= kj * block_k
+            if window:
+                _run_live(body, live, qi * block_q, kj * block_k, block_q,
+                          block_k, window)
+            else:
+                pl.when(live)(body)
         else:
             body()
 
@@ -343,7 +400,7 @@ def _pad_t(x, pad):
     return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def flash_attention_diff(
     q: jax.Array,  # [B, H, T, d]
     k: jax.Array,
@@ -354,6 +411,7 @@ def flash_attention_diff(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     spmd: bool = True,
+    window: int = 0,  # > 0: query t sees keys t - window < j <= t
 ) -> jax.Array:
     """``spmd=True`` (default) routes through the custom_partitioning
     wrappers so plain-GSPMD callers shard over (batch, heads) at runtime;
@@ -363,7 +421,7 @@ def flash_attention_diff(
     PJRT clients don't host, 'Custom emitter for CustomSPMDPartitioning
     not found')."""
     out, _ = _fwd_rule(q, k, v, padding_mask, causal, block_q, block_k,
-                       interpret, spmd)
+                       interpret, spmd, window)
     return out
 
 
@@ -373,23 +431,40 @@ def _compiler_params(plan: FlashPlan):
         vmem_limit_bytes=plan.vmem_limit_bytes)
 
 
-def _kv_block(causal, block_q, block_k):
+def _kv_block(causal, block_q, block_k, window=0):
     """(q block i, kv block j) -> the K/V block to fetch: j, but under
     ``causal`` a block above the diagonal stays on the last live one of its
-    row, so the step that computes nothing fetches nothing either."""
+    row (and with a ``window`` a block behind it on the first), so the step
+    that computes nothing fetches nothing either."""
     if not causal:
         return lambda i, j: j
-    return lambda i, j: jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+    if not window:
+        return lambda i, j: jnp.minimum(
+            j, (i * block_q + block_q - 1) // block_k)
+    return lambda i, j: jnp.clip(
+        j, jnp.maximum(i * block_q - window + 1, 0) // block_k,
+        (i * block_q + block_q - 1) // block_k)
 
 
-def _fwd(q, k, v, padding_mask, causal, block_q, block_k, interpret):
+def _effective_window(window: int, T: int, causal: bool) -> int:
+    """0 where the window masks nothing (none stated, or as long as the
+    sequence): the kernels are then the plain ones, by name too."""
+    if window and not causal:
+        raise ValueError("a sliding window is a causal window: causal=True")
+    return window if 0 < window < T else 0
+
+
+def _fwd(q, k, v, padding_mask, causal, block_q, block_k, interpret,
+         window=0):
     interpret = resolve_interpret(interpret)
     if pltpu is None:  # pragma: no cover
         raise RuntimeError("pallas tpu module unavailable")
     B, H, T, d = q.shape
     dv = v.shape[-1]  # values may be narrower than queries and keys
     scale = 1.0 / math.sqrt(d)
-    plan = flash_plan(T, d, dv, q.dtype, "fwd", causal, block_q, block_k)
+    window = _effective_window(window, T, causal)
+    plan = flash_plan(T, d, dv, q.dtype, "fwd", causal, block_q, block_k,
+                      window=window)
     block_q, block_k, Tp = plan.block_q, plan.block_k, plan.t_pad
     pad = Tp - T
     qf = _pad_t(q, pad).reshape(B * H, Tp, d)
@@ -397,7 +472,7 @@ def _fwd(q, k, v, padding_mask, causal, block_q, block_k, interpret):
     vf = _pad_t(v, pad).reshape(B * H, Tp, dv)
     with_mask = padding_mask is not None
 
-    kv = _kv_block(causal, block_q, block_k)
+    kv = _kv_block(causal, block_q, block_k, window)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, kv(i, j), 0)),
@@ -413,7 +488,7 @@ def _fwd(q, k, v, padding_mask, causal, block_q, block_k, interpret):
         args.append(_mask_column(padding_mask, pad))
     out, lse = pl.pallas_call(
         _fwd_kernel(scale, causal, block_q, block_k,
-                    None if with_mask or not pad else T, with_mask),
+                    None if with_mask or not pad else T, with_mask, window),
         grid=(B * H, Tp // block_q, Tp // block_k),
         in_specs=in_specs,
         out_specs=[
@@ -431,7 +506,7 @@ def _fwd(q, k, v, padding_mask, causal, block_q, block_k, interpret):
         ],
         compiler_params=_compiler_params(plan),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd_win" if window else "flash_fwd",
     )(*args)
     out4 = out.reshape(B, H, Tp, dv)[:, :, :T, :]
     # lse rides as [B, H, 1, Tp] so the GSPMD partitioning rule can map its
@@ -471,13 +546,15 @@ def _keep_dims(mesh, info, keep):
 
 
 @functools.lru_cache(maxsize=None)
-def _partitioned_fwd(causal, block_q, block_k, interpret, with_mask):
+def _partitioned_fwd(causal, block_q, block_k, interpret, with_mask,
+                     window=0):
     from jax.experimental.custom_partitioning import custom_partitioning
 
     def impl(*args):
         q, k, v = args[:3]
         mask = args[3] if with_mask else None
-        return _fwd(q, k, v, mask, causal, block_q, block_k, interpret)
+        return _fwd(q, k, v, mask, causal, block_q, block_k, interpret,
+                    window)
 
     fn = custom_partitioning(impl)
     arg_keep = [(0, 1), (0, 1), (0, 1)] + ([(0,)] if with_mask else [])
@@ -498,14 +575,15 @@ def _partitioned_fwd(causal, block_q, block_k, interpret, with_mask):
 
 
 @functools.lru_cache(maxsize=None)
-def _partitioned_bwd(causal, block_q, block_k, interpret, with_mask):
+def _partitioned_bwd(causal, block_q, block_k, interpret, with_mask,
+                     window=0):
     from jax.experimental.custom_partitioning import custom_partitioning
 
     def impl(*args):
         q, k, v, do, out, lse = args[:6]
         mask = args[6] if with_mask else None
         return _bwd_arrays(q, k, v, do, out, lse, mask, causal, block_q,
-                           block_k, interpret)
+                           block_k, interpret, window=window)
 
     fn = custom_partitioning(impl)
     arg_keep = [(0, 1)] * 6 + ([(0,)] if with_mask else [])
@@ -527,30 +605,31 @@ def _partitioned_bwd(causal, block_q, block_k, interpret, with_mask):
 
 
 def _fwd_rule(q, k, v, padding_mask, causal, block_q, block_k, interpret,
-              spmd=True):
+              spmd=True, window=0):
     concrete = resolve_interpret(interpret)
     with_mask = padding_mask is not None
     if spmd:
         args = (q, k, v) + ((padding_mask,) if with_mask else ())
         out, lse = _partitioned_fwd(causal, block_q, block_k, concrete,
-                                    with_mask)(*args)
+                                    with_mask, window)(*args)
     else:
         out, lse = _fwd(q, k, v, padding_mask, causal, block_q, block_k,
-                        concrete)
+                        concrete, window)
     return out, (q, k, v, padding_mask, out, lse)
 
 
-def _bwd_rule(causal, block_q, block_k, interpret, spmd, res, do):
+def _bwd_rule(causal, block_q, block_k, interpret, spmd, window, res, do):
     q, k, v, padding_mask, out, lse = res
     concrete = resolve_interpret(interpret)
     with_mask = padding_mask is not None
     if spmd:
         args = (q, k, v, do, out, lse) + ((padding_mask,) if with_mask else ())
         dq, dk, dv = _partitioned_bwd(causal, block_q, block_k, concrete,
-                                      with_mask)(*args)
+                                      with_mask, window)(*args)
     else:
         dq, dk, dv = _bwd_arrays(q, k, v, do, out, lse, padding_mask,
-                                 causal, block_q, block_k, concrete)
+                                 causal, block_q, block_k, concrete,
+                                 window=window)
     return dq, dk, dv, None
 
 
@@ -599,12 +678,14 @@ flash_attention_with_lse.defvjp(_with_lse_fwd, _with_lse_bwd)
 
 
 def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
-                block_k, interpret, dlse=None):
+                block_k, interpret, dlse=None, window=0):
     interpret = resolve_interpret(interpret)
     B, H, T, d = q.shape
     dv = v.shape[-1]
     scale = 1.0 / math.sqrt(d)
-    plans = {kind: flash_plan(T, d, dv, q.dtype, kind, causal, block_q, block_k)
+    window = _effective_window(window, T, causal)
+    plans = {kind: flash_plan(T, d, dv, q.dtype, kind, causal, block_q, block_k,
+                              window=window)
              for kind in ("dq", "dkv")}
     Tp = plans["dq"].t_pad  # the forward's and both kernels': T decides it
     pad = Tp - T
@@ -625,7 +706,7 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
     seq_len = None if with_mask or not pad else T
 
     block_q, block_k = plans["dq"][:2]
-    kv = _kv_block(causal, block_q, block_k)
+    kv = _kv_block(causal, block_q, block_k, window)
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # q by qi
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, kv(i, j), 0)),
@@ -638,7 +719,8 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
         dq_specs.append(pl.BlockSpec(
             (1, block_k, 1), lambda b, i, j, H=H: (b // H, kv(i, j), 0)))
     dq = pl.pallas_call(
-        _dq_kernel(scale, causal, block_q, block_k, seq_len, with_mask),
+        _dq_kernel(scale, causal, block_q, block_k, seq_len, with_mask,
+                   window),
         grid=(bh, Tp // block_q, Tp // block_k),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -646,12 +728,15 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(plans["dq"]),
         interpret=interpret,
-        name="flash_dq",
+        name="flash_dq_win" if window else "flash_dq",
     )(qf, kf, vf, dof, lse, dd, *mask_args)
 
     bq, bk = plans["dkv"][:2]
 
     def qb(j, i):  # a q block before kv block j: stay on the first live one
+        if window:  # and one past the window of its last key on the last
+            return jnp.clip(i, (j * bk) // bq,
+                            (j * bk + bk + window - 2) // bq)
         return jnp.maximum(i, (j * bk) // bq) if causal else i
 
     dkv_specs = [
@@ -666,7 +751,7 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
         dkv_specs.append(
             pl.BlockSpec((1, bk, 1), lambda b, j, i, H=H: (b // H, j, 0)))
     dk, dvv = pl.pallas_call(
-        _dkv_kernel(scale, causal, bq, bk, seq_len, with_mask),
+        _dkv_kernel(scale, causal, bq, bk, seq_len, with_mask, window),
         grid=(bh, Tp // bk, Tp // bq),
         in_specs=dkv_specs,
         out_specs=[
@@ -683,7 +768,7 @@ def _bwd_arrays(q, k, v, do, out, lse, padding_mask, causal, block_q,
         ],
         compiler_params=_compiler_params(plans["dkv"]),
         interpret=interpret,
-        name="flash_dkv",
+        name="flash_dkv_win" if window else "flash_dkv",
     )(qf, kf, vf, dof, lse, dd, *mask_args)
 
     unpad = lambda x: x.reshape(B, H, Tp, -1)[:, :, :T, :]  # noqa: E731

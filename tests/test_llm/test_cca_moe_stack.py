@@ -576,3 +576,106 @@ def test_stacks_without_cca_lower_to_the_text_they_lowered_to(program):
     text = lowered(STACKS[stack](), what).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == LOWERED_BEFORE[program]
+
+
+# --------------------------------------------------------------------------- #
+# ... and through a window in the kernels, a period scan and a router fed
+# from outside the FFN (PR 38)
+# --------------------------------------------------------------------------- #
+
+#: sha256 (first 16 digits) of more programs' lowered text at the commit
+#: before a sliding window reached the flash kernels, the cached and the paged
+#: attention loop, ``_run_layers`` got its period scan and ``_block_ffn`` its
+#: logits from outside (e3d311a, PR 37), on this installation: with no window
+#: configured none of them may change. ``*/learn_flash`` are GRADIENTS through
+#: ``token_logprobs`` with the flash kernels on — in interpret mode here, so
+#: the text holds the three kernels' bodies, index maps and grids operation by
+#: operation (q, k 24 wide and v 16 under latent attention) —, ``mesh/`` the
+#: same under ``flash_shard_axes`` on an fsdp 4 x tp 2 mesh, ``dense/step``
+#: the one-token decode through ``KVCache`` (``chunked_cached_attention``),
+#: ``dense/verify`` the multi-token paged forward, ``cca/*`` the CCA stack's
+#: three programs, ``flash_plan`` the tiles chosen at T 64-16384 for heads of
+#: 128 / 128 and 192 / 128.
+LOWERED_AT_PR37 = {
+    "cca/learn": "2ea31a4c80162d01", "cca/prefill": "f6edafb875432fa6",
+    "cca/decode": "f63083a8726e3a21", "dense/learn_flash": "3933b328fe2d072e",
+    "mla/learn_flash": "de2b0a6bbfe253e4", "cca/learn_flash": "b85af7493ee50f96",
+    "dense/step": "f54fdbc3c50c3d61", "dense/verify": "5eeb5c0d0de6e3fe",
+    "mesh/learn_flash": "e27b6bc262a2eb43", "flash_plan": "b32bf4203f00e13b",
+}
+STACKS["cca"] = lambda: preset("tiny-cca-moe", dtype=jnp.float32, remat=False,
+                               use_flash_attention=False)
+
+
+def lowered_more(cfg, what):
+    """``lowered`` for the programs above."""
+    targets = (("wq", "wkv_b") if cfg.is_mla else
+               ("wq", "wv1", "wv2") if cfg.is_cca else ("wq", "wv"))
+    shapes = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg))
+    lora = jax.eval_shape(lambda: M.init_lora(jax.random.PRNGKey(1), cfg, 2,
+                                              targets))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    kw = {"return_aux": True} if cfg.is_dropless else {}
+    if what == "learn":
+        return jax.jit(lambda p, lo, t, m: M.token_logprobs(
+            cfg, p, t, attention_mask=m, lora=lo, **kw)).lower(
+                shapes, lora, i32(2, 16), i32(2, 16))
+    if what == "learn_flash":
+        def loss(lo, p, t, m):
+            out = M.token_logprobs(cfg, p, t, attention_mask=m, lora=lo,
+                                   flash=True, **kw)
+            return (out[0] if kw else out).sum()
+
+        return jax.jit(jax.grad(loss)).lower(lora, shapes, i32(2, 40),
+                                             i32(2, 40))
+    if what in ("prefill", "step"):
+        T = 16 if what == "prefill" else 1
+        return jax.jit(lambda p, lo, t, m, c: M.forward(
+            cfg, p, t, attention_mask=m, cache=c, lora=lo)).lower(
+                shapes, lora, i32(2, T), i32(2, T),
+                jax.eval_shape(lambda: M.init_caches(cfg, 2, 32)))
+    slots = {"slots": 3, "snapshots": 3} if cfg.state_kind is not None else {}
+    pool = jax.eval_shape(lambda: M.init_paged_cache(cfg, 9, 8, **slots))
+    shape = (3, 3) if what == "verify" else (3,)
+    return jax.jit(lambda p, lo, t, pos, c, tab, sm: M.forward_paged(
+        cfg, p, t, pos, pos, c, tab, sm, lora=lo, **kw)).lower(
+            shapes, lora, i32(3, shape[-1] if what == "verify" else 1),
+            i32(*shape), pool, i32(3, 4), i32(3, 32))
+
+
+def mesh_learn_flash_text():
+    from agilerl_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(dp=1, fsdp=4, tp=2)
+    cfg = dataclasses.replace(STACKS["dense"](), use_flash_attention=True,
+                              flash_shard_axes=(("dp", "fsdp"), "tp"))
+    shapes = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    with mesh:
+        return jax.jit(jax.grad(lambda p, t, m: M.token_logprobs(
+            cfg, p, t, attention_mask=m).mean())).lower(
+                shapes, i32(8, 32), i32(8, 32)).as_text()
+
+
+def flash_plans_text():
+    from agilerl_tpu.ops.flash_attention_vjp import flash_plan
+
+    return repr([
+        tuple(flash_plan(T, d, dv, jnp.bfloat16, kind,
+                         vmem_capacity=128 * 2 ** 20))
+        for T in (64, 1024, 1152, 2048, 4096, 8192, 16384)
+        for d, dv in ((128, 128), (192, 128))
+        for kind in ("fwd", "dq", "dkv")])
+
+
+@pytest.mark.parametrize("program", sorted(LOWERED_AT_PR37))
+def test_programs_without_a_window_lower_to_the_text_they_lowered_to(program):
+    if program == "flash_plan":
+        text = flash_plans_text()
+    elif program == "mesh/learn_flash":
+        text = mesh_learn_flash_text()
+    else:
+        stack, what = program.split("/")
+        text = lowered_more(STACKS[stack](), what).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == LOWERED_AT_PR37[program]
