@@ -450,10 +450,10 @@ class GRPO(EvolvableAlgorithm):
                     gen.fits(ids_np.shape[0], longest):
                 seqs = [row[m.astype(bool)]
                         for row, m in zip(ids_np, mask_np)]
-                comp, cmask, self.last_generation_info = gen.generate(
-                    seqs, self.next_key(), self.base_params,
-                    lora=self.actor.params, greedy=not training,
-                )
+                # (a method at the class's end: a kernel's serialized body
+                # holds the line of every frame above its call, learn's too)
+                comp, cmask, self.last_generation_info = \
+                    self._continuous_rollout(gen, seqs, training)
                 return comp, cmask
             # prompt too long for the bucket grid: dense path below
         elif self.bucketed_decode:
@@ -929,3 +929,18 @@ class GRPO(EvolvableAlgorithm):
         the DeepSpeed-engine teardown has no analogue; XLA buffers free with
         the params)."""
         self._clear_jit_cache()
+
+    def _continuous_rollout(self, gen, seqs, training: bool):
+        """``gen.generate`` (a ``ContinuousGenerator`` or a fleet) over the
+        matrices in the compute type: cast once a rollout, not once inside
+        every prefill and decode-chunk program, and let go again before
+        ``learn`` needs the memory (``serving.rollout_weights``). The
+        masters stay what ``base_params`` holds; a base already stored in
+        the compute type is handed over as it is."""
+        from agilerl_tpu.llm.serving import rollout_weights
+
+        with rollout_weights(gen, self.model_config,
+                             self.base_params) as weights:
+            return gen.generate(
+                seqs, self.next_key(), weights,
+                lora=self.actor.params, greedy=not training)

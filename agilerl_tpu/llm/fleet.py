@@ -1078,6 +1078,12 @@ class ServingFleet:
                     "lease cannot expire? advance the clock or scale_up)")
         return finished
 
+    def end_weight_epoch(self) -> None:
+        """``ContinuousGenerator.end_weight_epoch`` on every serving
+        replica: none keeps a reference to the trees of the last call."""
+        for m in self._serving_members().values():
+            m.gen.end_weight_epoch()
+
     def generate(
         self,
         sequences: List[Any],

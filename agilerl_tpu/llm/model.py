@@ -1820,3 +1820,118 @@ def _hidden_logprobs(config, params, hidden, tokens, temperature, chunk_size,
 
     _, lp = jax.lax.scan(one_chunk, None, (chunks_h, chunks_t))
     return lp.reshape(-1)[:n].reshape(B, Tm1)
+
+
+# --------------------------------------------------------------------------- #
+# The weights a rollout computes on. It belongs beside forward_paged and
+# stands here, at the end of the file, because a Mosaic kernel's serialized
+# body holds the line of every Python frame above its call: a function put
+# in above token_logprobs would give every learn program a new cache key.
+# --------------------------------------------------------------------------- #
+
+#: the leaves that every cached forward (forward_paged, paged_decode_step,
+#: the prefill) rounds to ``config.dtype`` before it reads them, by the name
+#: of the leaf: the lookup, _maybe_lora's and ssm._proj_f32's matrices, the
+#: experts' and the shared expert's, latent attention's two, CCA's four
+#: projections and its grouped convolution. Everything else is read as
+#: stored — norm scales and biases (vectors, also where a run stacks them to
+#: rank 2), the router and its MLP (float32 at ``highest``), conv taps,
+#: ``A_log``, ``D``, ``dt_bias``, ``tau``, the merge vectors — and stays
+#: what it is. It is the rule the bf16-stored configurations store by, but
+#: for THE HEAD (``lm_head``, or the embedding of a stack that ties it),
+#: which stays as stored: ``logits_fn`` widens it for an f32 product, and
+#: though the chip's default precision rounds that product's operands to
+#: bf16, a head handed over in bf16 does not give the same bits there (1 of
+#: 6144 captured log-probabilities of a qwen2-7b rollout moved, by 2e-3:
+#: PERF.md, section 6, PR 39).
+COMPUTE_MATRICES = frozenset((
+    "tok_emb", "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+    "ws_gate", "ws_up", "ws_down", "wkv_a", "wkv_b", "wv1", "wv2", "conv1_w",
+    "in_proj", "x_proj", "dt_proj", "out_proj"))
+
+
+@functools.lru_cache(maxsize=None)
+def _copy_program(dtype, narrow, runs, leaf_shardings, run_shardings):
+    """ONE program that makes ``compute_params``' new leaves. ``leaves`` are
+    cast to ``dtype``; ``blocks`` (one flat list of leaves a layer, or none)
+    come back stacked as ``stack_run`` stacks them — for each of ``runs``
+    (first layer, layers, period) and each position in its period, one array
+    a leaf over that position's layers — with the leaves that ``narrow``
+    marks cast. The shardings: one entry a result, a mesh placement or
+    None."""
+    def copy(leaves, blocks):
+        blocks = [[x.astype(dtype) if cast else x
+                   for x, cast in zip(layer, narrow)] for layer in blocks]
+        return ([x.astype(dtype) for x in leaves],
+                [jnp.stack(xs) for first, n, period in runs
+                 for p in range(period)
+                 for xs in zip(*blocks[first + p:first + n:period])])
+
+    return jax.jit(copy, out_shardings=(list(leaf_shardings),
+                                        list(run_shardings)))
+
+
+def compute_params(config: GPTConfig, params: Params) -> Params:
+    """The tree the cached forwards compute on: ``params`` with every leaf
+    of ``COMPUTE_MATRICES`` that is not yet in ``config.dtype`` cast to it —
+    the cast each of those programs would make itself, a call. A tree in
+    which nothing is to cast is returned as the same object and dispatches
+    nothing. Otherwise ONE jitted program makes the new leaves, and every
+    leaf outside ``params["blocks"]`` that it does not cast is the SAME
+    array (a leaf handed through a jit untouched is copied). A stack stored
+    one tree a layer (``params["blocks"]``) comes back in the layout its
+    programs scan, ``params["runs"]`` as ``init_params`` stores a stack by
+    runs: the copy is being written anyway, and written a layer the layer
+    loop would stack it again inside every program (a quarter of what the
+    casts cost a decode chunk of qwen2-7b). Under a mesh each leaf keeps
+    its placement, a stacked one with the layer axis replicated. The
+    masters stay with the caller: what ``learn`` reads, a checkpoint saves
+    and a population shares."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    dtype = jnp.dtype(config.dtype)
+    names = COMPUTE_MATRICES - ({"tok_emb"} if config.tie_embeddings else set())
+
+    def narrows(path, x):
+        return (getattr(path[-1], "key", None) in names and x.ndim >= 2
+                and x.dtype != dtype)
+
+    def placement(x, stacked=False):
+        s = getattr(x, "sharding", None)
+        if not isinstance(s, NamedSharding):
+            return None
+        return NamedSharding(s.mesh, PartitionSpec(None, *s.spec)) \
+            if stacked else s
+
+    rest = {k: v for k, v in params.items() if k != "blocks"}
+    flat, treedef = jax.tree_util.tree_flatten_with_path(rest)
+    leaves = [x for _, x in flat]
+    todo = [i for i, (path, x) in enumerate(flat) if narrows(path, x)]
+    blocks, narrow, runs, block_def = [], (), (), None
+    if "blocks" in params:
+        layers = [jax.tree_util.tree_flatten_with_path(
+            params["blocks"][str(i)]) for i in range(config.n_layer)]
+        block_def = layers[0][1]
+        blocks = [[x for _, x in layer] for layer, _ in layers]
+        narrow = tuple(narrows(path, x) for path, x in layers[0][0])
+        runs = tuple((first, n, config.run_period(first, n))
+                     for _, first, n in config.layer_runs())
+    if not (todo or any(narrow)):
+        return params
+    cast, stacked = _copy_program(
+        dtype, narrow, runs, tuple(placement(leaves[i]) for i in todo),
+        tuple(placement(x, stacked=True) for first, _, period in runs
+              for p in range(period) for x in blocks[first + p]),
+    )([leaves[i] for i in todo], blocks)
+    for i, x in zip(todo, cast):
+        leaves[i] = x
+    out = jax.tree_util.tree_unflatten(treedef, leaves)
+    if block_def is not None:
+        k = len(narrow)
+        trees = [block_def.unflatten(stacked[j:j + k])
+                 for j in range(0, len(stacked), k)]
+        out["runs"] = []
+        for _, _, period in runs:
+            run, trees = trees[:period], trees[period:]
+            out["runs"].append(run[0] if period == 1 else run)
+    return out

@@ -24,6 +24,7 @@ fold order across chunks.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import hashlib
 import threading
@@ -162,6 +163,47 @@ def _place_params(gen, params, lora=None):
     if lora is not None:
         return params, gen.sharding_plan.place("lora", lora, gen.mesh)
     return params
+
+
+@contextlib.contextmanager
+def rollout_weights(gen, config: M.GPTConfig, params):
+    """The weights ONE rollout's programs compute on, for the length of the
+    block: ``M.compute_params`` — the matrices cast to ``config.dtype`` and
+    a stack stored a layer stacked a run, by one program, once, where every
+    prefill and every decode chunk would do both again (a chunk call of
+    qwen2-7b at four layers read 8 GB of f32 masters and wrote 4 GB of bf16
+    before its first token) — as one tree object, so ``gen`` (a
+    ``ContinuousGenerator``, or a fleet of them) sees one weight epoch. A
+    tree already stored in the compute type is handed through as it is, and
+    nothing else happens. A copy is let go at the block's end, the
+    generator's own reference with it (``end_weight_epoch``): the caller's
+    learn step needs the memory.
+
+    ``serving/weight_cast_total`` counts the copies made,
+    ``serving/weight_cast_bytes`` is the size of the live one (0 outside a
+    rollout, and always for a tree that needs none); the host phase
+    ``rollout/weight_cast`` is the dispatch."""
+    reg = gen.metrics
+    live = reg.gauge("serving/weight_cast_bytes",
+                     help="bytes of the rollout's live compute-type copy "
+                          "of the weights")
+    with PhaseTimer(reg, "rollout/weight_cast"):
+        weights = M.compute_params(config, params)
+    if weights is params:
+        live.set(0)
+        yield params
+        return
+    reg.counter("serving/weight_cast_total",
+                help="compute-type copies of the weights made for a "
+                     "rollout").inc()
+    masters = {id(x) for x in jax.tree_util.tree_leaves(params)}
+    live.set(sum(x.nbytes for x in jax.tree_util.tree_leaves(weights)
+                 if id(x) not in masters))
+    try:
+        yield weights
+    finally:
+        gen.end_weight_epoch()
+        live.set(0)
 
 
 def measured_cache_size(*jitted) -> int:
@@ -1956,6 +1998,22 @@ class ContinuousGenerator:
         if self._weights is not None and (self._weights[0] is params
                                           and self._weights[1] is lora):
             return
+        self._flush_weight_epoch()
+        self._weights = (params, lora)
+
+    def end_weight_epoch(self) -> None:
+        """Forget the weight trees of the epoch that ends here: what a new
+        tree at the next step would flush is flushed now, and no reference
+        to either tree is kept. For a caller whose trees live no longer
+        than its call (``rollout_weights``: a copy in the compute type that
+        must be gone before ``learn`` needs the memory) — the identity test
+        above would otherwise keep the copy alive until the next rollout."""
+        self._flush_weight_epoch()
+        self._weights = None
+
+    def _flush_weight_epoch(self) -> None:
+        """Drop everything that was computed under the current weights (a
+        generator that has seen none yet has nothing to drop)."""
         if self._weights is not None:
             if self.prefix_cache:
                 self.allocator.invalidate_cache()
@@ -1986,7 +2044,6 @@ class ContinuousGenerator:
                 # zero accept rate it costs a wider forward for nothing
                 self._completions.clear()
                 self._slot_follow = [None] * self.slots
-        self._weights = (params, lora)
 
     def step(self, params, lora=None, greedy: bool = False) -> List[int]:
         """ONE scheduler iteration: admit into free slots, then run one

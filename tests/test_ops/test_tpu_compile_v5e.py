@@ -205,3 +205,55 @@ def test_paged_verify_step_at_qwen2_7b_widths(paged_tier):
     text = _compiled_text(gen._verify, *state, *drafts, greedy=False)
     assert "while" in text and KERNEL not in text
     assert not _whole_extent_results(gen, text)
+
+
+def _narrowing_casts(jaxpr, dtype, min_size=1_000_000):
+    """(casts, stacks) in the jaxpr and in every jaxpr its equations hold
+    (scan bodies, calls): the operand's shape of every
+    ``convert_element_type`` to ``dtype`` from a wider type, and the result's
+    shape of every ``concatenate`` (what ``jnp.stack`` lowers to), of more
+    than ``min_size`` elements."""
+    casts, stacks = [], []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "convert_element_type":
+            (x,) = eqn.invars
+            if (eqn.params["new_dtype"] == dtype and x.aval.size > min_size
+                    and x.aval.dtype.itemsize > jnp.dtype(dtype).itemsize):
+                casts.append(x.aval.shape)
+        if eqn.primitive.name == "concatenate" \
+                and eqn.outvars[0].aval.size > min_size:
+            stacks.append(eqn.outvars[0].aval.shape)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            c, k = _narrowing_casts(sub, dtype, min_size)
+            casts += c
+            stacks += k
+    return casts, stacks
+
+
+def test_decode_chunk_from_the_rollout_copy_casts_no_matrix(paged_tier):
+    """The decode chunk handed ``model.compute_params``' tree (what
+    ``GRPO.get_action`` hands it since ISSUE 39) narrows no matrix to the
+    compute type and stacks no layers' weights; handed the f32 masters it
+    does both to every layer's matrices, a chunk call (with what XLA:TPU
+    adds — the embedding's table cast ahead of its 8-row lookup, the head
+    rounded for the default precision's one bf16 pass: neither is a cast in
+    the jaxpr — 8 GB read and 4 GB written at four layers). The head is not
+    in the copy (``model.COMPUTE_MATRICES`` says why): its rounding stays in
+    the chunk, and only the chip's trace sees it."""
+    gen, (params, *state), _ = paged_tier
+    cfg = gen.config
+    chunk = lambda p: jax.make_jaxpr(  # noqa: E731
+        lambda *a: gen._decode_chunk_impl(*a, greedy=False))(p, *state).jaxpr
+    casts, stacks = _narrowing_casts(chunk(params), cfg.dtype)
+    matrices = {(3584, 3584), (3584, 512), (3584, 18944), (18944, 3584)}
+    assert set(casts) == matrices, casts  # q / o, k / v, gate / up, down
+    assert set(stacks) == {(2, *m) for m in matrices}, stacks
+    copy = jax.eval_shape(lambda p: M.compute_params(cfg, p), params)
+    (run,) = copy["runs"]
+    assert copy["tok_emb"].dtype == run["wk"].dtype == cfg.dtype
+    assert run["w_up"].shape == (2, 3584, 18944)
+    # the head is read at f32 (logits_fn) and stays as stored, like every
+    # vector
+    assert copy["lm_head"].dtype == copy["ln_f"].dtype == jnp.float32
+    assert run["bq"].dtype == jnp.float32
+    assert _narrowing_casts(chunk(copy), cfg.dtype) == ([], [])
