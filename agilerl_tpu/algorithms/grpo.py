@@ -582,8 +582,8 @@ class GRPO(EvolvableAlgorithm):
         """experiences = (ids, action_masks, rewards[, attention_mask]):
         ids [B*G, P+N] full prompt+completion sequences, action_masks [B*G, P+N-1]
         marking completion-token predictions, rewards [B, G]; pass the optional
-        4th element when pad_token_id collides with a real vocabulary token
-        (otherwise attention defaults to ids != pad_token_id)
+        4th element when a prompt holds pad_token_id as a real token (without
+        it: ids != pad_token_id, and every completion token the mask marks)
         (parity: grpo.py:321). Returns (mean loss, mean k3 KL vs reference).
 
         A call runs the reference adapter's no-grad pass, then the update.
@@ -768,8 +768,8 @@ class GRPO(EvolvableAlgorithm):
         ids = jnp.asarray(ids)
         if attention_mask is not None:
             mask = jnp.asarray(attention_mask, jnp.int32)
-        else:
-            mask = (ids != self.pad_token_id).astype(jnp.int32)
+        else:  # a completion token counts whatever its id (_attention_mask)
+            mask = _attention_mask(ids, action_masks, self.pad_token_id)
         return ids, mask, jnp.asarray(action_masks, jnp.float32)
 
     def behavior_logprobs(self, ids, action_masks,
@@ -984,3 +984,13 @@ def _note_load(metrics, load):
             help="learn: routed pairs of the updates' forwards that fell on "
                  "the experts this chip holds, summed over the expert "
                  "layers").inc(float(load[1]))
+
+
+@functools.partial(jax.jit, static_argnames="pad_id")
+def _attention_mask(ids, action_masks, pad_id: int):
+    """1 where a token is real: every id but ``pad_id``, and every completion
+    token ``action_masks`` marks whatever its id — the rollout attended to
+    it, and the sampler may draw the pad id. One program, not one a jnp
+    operation: ``GRPO.learn`` runs it every call."""
+    completion = jnp.pad(action_masks > 0, ((0, 0), (1, 0)))
+    return ((ids != pad_id) | completion).astype(jnp.int32)

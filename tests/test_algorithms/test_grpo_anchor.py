@@ -345,3 +345,19 @@ def test_sharded_step_takes_a_batch_without_old_lp():
                     jax.tree_util.tree_leaves(p_lora)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                    atol=1e-7)
+
+
+def test_a_sampled_pad_id_in_a_completion_stays_attended():
+    """The rollout attended to every token it sampled, the pad id among
+    them: the learn side's default mask keeps a completion token whatever
+    its id, and masks only the prompt's left padding and what follows EOS
+    (action mask 0). An explicit attention mask still wins."""
+    agent = make_agent()
+    ids = np.array([[0, 0, 5, 6, 7, 0, 8, 1, 0, 0]], np.int32)
+    action = np.zeros((1, 9), np.int32)
+    action[0, 3:7] = 1  # predictions of ids 4..7: 7, the sampled 0, 8, EOS
+    _, mask, _ = agent._learn_masks(ids, action, None)
+    np.testing.assert_array_equal(mask[0], [0, 0, 1, 1, 1, 1, 1, 1, 0, 0])
+    explicit = np.ones_like(ids)
+    _, mask, _ = agent._learn_masks(ids, action, explicit)
+    np.testing.assert_array_equal(mask, explicit)

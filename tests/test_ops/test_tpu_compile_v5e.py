@@ -1,5 +1,6 @@
 """The main path's kernels and serving programs, compiled at qwen2-7b widths
-by the TPU's own compiler for a described ``v5e:2x2`` (no chip attached).
+(the selective scan's at jamba2-3b's) by the TPU's own compiler for a
+described ``v5e:2x2`` (no chip attached).
 
 Interpret mode accepts tilings and VMEM footprints that Mosaic refuses, and
 ``jax.default_backend()`` is ``cpu`` here, so each test hands the compiler
@@ -30,6 +31,8 @@ from agilerl_tpu.ops.fused_loss import (
     fused_token_logprob_diff,
 )
 from agilerl_tpu.ops.kernel_mode import native_kernels
+from agilerl_tpu.ops.selective_scan import (SCAN_BWD_SCOPE, SCAN_SCOPE,
+                                            scan_plan, selective_scan)
 
 KERNEL = "tpu_custom_call"
 
@@ -148,6 +151,45 @@ def test_flash_attention_fwd_and_grad_at_the_cells_heads(v5e, B, H, T, d, dv,
     extents = {int(t) for t in re.findall(rf"\[{B * H},(\d+),\d+\]", text)}
     extents |= {int(t) for t in re.findall(rf"\[{B},{H},(\d+),\d+\]", text)}
     assert tp in extents and max(extents) == tp, extents
+
+
+@pytest.mark.parametrize("T,learn", [(1024, True), (255, False)],
+                         ids=["learn_8x1024", "prefill_state_255"])
+def test_selective_scan_kernels_at_jamba2_3b_widths(v5e, T, learn):
+    # grpo_jamba_reason's Mamba-1 layers (d_inner 5120, d_state 16): the
+    # learn step's 8 rows x 1024 forward and VJP, and the prefill's T - 1
+    # positions from a state, forward only
+    B, D, N = 8, 5120, 16
+    f32 = jnp.float32
+    s = jax.ShapeDtypeStruct
+    x, dt, A, Bm, Cm, Dk, mask, h0 = _on(v5e, (
+        s((B, T, D), jnp.bfloat16), s((B, T, D), f32), s((D, N), f32),
+        s((B, T, N), f32), s((B, T, N), f32), s((D,), f32), s((B, T), f32),
+        s((B, N, D), f32)))
+
+    def loss(*a):
+        y, h = selective_scan(*a)
+        return y.astype(f32).sum() + h.sum()
+
+    if learn:
+        fn, args = jax.grad(loss, argnums=tuple(range(6))), (
+            x, dt, A, Bm, Cm, Dk, mask)
+    else:
+        fn, args = selective_scan, (x, dt, A, Bm, Cm, Dk, mask, h0)
+    # Mosaic takes both kernels and their VMEM footprint
+    text = _compiled_text(jax.jit(fn), *args)
+    calls = [line for line in text.splitlines() if KERNEL in line]
+    kinds = ("fwd", "bwd") if learn else ("fwd",)
+    assert len(calls) == len(kinds)
+    for kind, scope in zip(kinds, (SCAN_SCOPE, SCAN_BWD_SCOPE)):
+        line = next(c for c in calls if f"%ssm_scan_{kind}" in c)
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        # under its scope: jit(f)/ssm/scan/..., or jvp(ssm/scan)/... and
+        # transpose(jvp(ssm/scan_bwd))/... inside a gradient
+        assert re.search(rf"\b{scope}\)*/ssm_scan_{kind}/", op_name), op_name
+    plan = scan_plan(D, N, jnp.bfloat16)
+    print(f"\n  {plan.tile} lanes x {plan.block} positions a grid step, "
+          f"VMEM limit asked {plan.vmem_limit_bytes / 2**20:.0f} MiB", end="")
 
 
 @pytest.fixture(scope="module")
