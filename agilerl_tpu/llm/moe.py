@@ -35,6 +35,7 @@ training loss can add ``router_aux_weight * aux``.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Tuple
 
@@ -186,8 +187,9 @@ def route(logits, select_bias=None, *, top_k: int, score: str = "softmax",
 
 
 def expert_load(choice, n_experts: int):
-    """Rows each expert serves, [E] int32."""
-    return jnp.zeros((n_experts,), jnp.int32).at[choice.reshape(-1)].add(1)
+    """Rows each expert serves, [E] int32: a count, not a scatter-add."""
+    return jnp.sum(choice.reshape(-1, 1) == jnp.arange(n_experts), axis=0,
+                   dtype=jnp.int32)
 
 
 #: an expert's activation by its published name: the gate of a SwiGLU or a
@@ -203,9 +205,10 @@ def dropless_experts(x, choice, weights, w_gate, w_up, w_down, layer=None,
     SwiGLU; "relu": a ReGLU), for ALL N x k pairs: the pairs are sorted by expert, each
     projection is one grouped matmul over the experts (``lax.ragged_dot``:
     XLA:TPU tiles the rows by group and reads an expert's weights only where
-    its group is not empty), and the result is un-sorted by the inverse
-    permutation (a gather, no scatter-add) and weighted. x [N, d]; choice,
-    weights [N, k]; w_gate, w_up [E, d, f]; w_down [E, f, d].
+    its group is not empty), and the result is un-sorted and weighted. The
+    permutation is built once (``_permutation``) and rows move by gathers
+    only, forward and backward (``_sort_rows``, ``_unsort``). x [N, d];
+    choice, weights [N, k]; w_gate, w_up [E, d, f]; w_down [E, f, d].
 
     With ``layer`` (a traced index) the weights are a whole run's,
     ``[n, E, ...]``, and this layer's experts are the groups ``[layer E,
@@ -222,13 +225,12 @@ def dropless_experts(x, choice, weights, w_gate, w_up, w_down, layer=None,
     E = (w_up if w_gate is None else w_gate).shape[-3]
     dtype = x.dtype
     with jax.named_scope(ROUTE_SCOPE):
-        flat = choice.reshape(-1)
+        keys = choice.reshape(-1)  # pair p = token p // k
         if first is not None:
-            flat = flat - first
-            flat = jnp.where((flat >= 0) & (flat < E), flat, E)
-        order = jnp.argsort(flat, stable=True)  # pair p = token p // k
-        sizes = (expert_load(choice, E) if first is None else
-                 jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E])
+            keys = keys - first
+            keys = jnp.where((keys >= 0) & (keys < E), keys, E)
+        order, pos, sizes = _permutation(keys, E if first is None else E + 1)
+        sizes = sizes[:E]
         if layer is not None:
             n = w_gate.shape[0]
             sizes = jax.lax.dynamic_update_slice(
@@ -236,7 +238,7 @@ def dropless_experts(x, choice, weights, w_gate, w_up, w_down, layer=None,
             w_gate, w_up, w_down = (
                 None if w is None else w.reshape(n * E, *w.shape[2:])
                 for w in (w_gate, w_up, w_down))
-        xs = jnp.take(x, order // k, axis=0)  # [N k, d] sorted by expert
+        xs = _sort_rows(x, order, pos, k)  # [N k, d] sorted by expert
         if first is not None:
             # the rows after the last group are no group's: XLA:TPU's
             # grouped matmul leaves them unwritten, in its result and in the
@@ -251,20 +253,106 @@ def dropless_experts(x, choice, weights, w_gate, w_up, w_down, layer=None,
                 xs, w_up.astype(dtype), sizes)), w_down.astype(dtype), sizes)
             if first is not None:
                 y = jnp.where(valid, y, jnp.zeros((), y.dtype))
-            return _combine(y, order, weights, N, k, dtype)
+            return _combine(y, order, pos, weights, dtype)
         g = jax.lax.ragged_dot(xs, w_gate.astype(dtype), sizes)
         u = jax.lax.ragged_dot(xs, w_up.astype(dtype), sizes)
         y = jax.lax.ragged_dot(EXPERT_ACTS[act](g) * u, w_down.astype(dtype),
                                sizes)
-    return _combine(y, order, weights, N, k, dtype)
+    return _combine(y, order, pos, weights, dtype)
 
 
-def _combine(y, order, weights, N, k, dtype):
+#: rows of one block of ``_permutation``'s count: one MXU tile
+COUNT_ROWS = 128
+
+
+def _permutation(keys, buckets: int):
+    """The stable sort of ``keys`` [M] (ints in ``[0, buckets)``) as
+    (order [M]: the pair at each sorted row; pos [M]: the row each pair
+    went to, ``order``'s inverse; sizes [buckets]: the pairs of each key).
+    One sort gives ``order``. ``pos`` and ``sizes`` come from a count, not
+    from a second sort or a scatter: a pair goes to its key's first row
+    plus the pairs of its key before it, which is a prefix count over the
+    one-hot of the keys, taken in blocks of ``COUNT_ROWS`` by a
+    lower-triangular matmul (0s and 1s in, counts of at most 128 out:
+    exact in bfloat16) and across blocks by a cumulative sum of the
+    blocks' totals."""
+    M = keys.shape[0]
+    order = jnp.argsort(keys, stable=True)
+    b = min(COUNT_ROWS, M)
+    blocks = -(-M // b)
+    padded = jnp.pad(keys, (0, blocks * b - M), constant_values=buckets)
+    hot = padded.reshape(blocks, b, 1) == jnp.arange(buckets)
+    upto = jnp.einsum(  # [blocks, b, buckets]: pairs of each key to here
+        "ij,gjk->gik", jnp.tril(jnp.ones((b, b), jnp.bfloat16)),
+        hot.astype(jnp.bfloat16),
+        preferred_element_type=jnp.bfloat16).astype(jnp.int32)
+    totals = upto[:, -1]
+    before = jnp.cumsum(totals, axis=0) - totals  # in the blocks before
+    sizes = before[-1] + totals[-1]
+    start = jnp.cumsum(sizes) - sizes
+    pos = jnp.sum(jnp.where(hot, upto + before[:, None] + start, 0), axis=-1)
+    return order, pos.reshape(-1)[:M] - 1, sizes
+
+
+def _rows(a, index):
+    """``a[index]`` along the first axis; every index in range."""
+    return a.at[index].get(mode="promise_in_bounds")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sort_rows(x, order, pos, k):
+    """Each pair's row of ``x`` [N, d], in sorted order: ``x[order // k]``
+    [N k, d]. Its transpose would scatter-add the rows back, k to a token;
+    the backward gathers them instead (``_sort_rows_bwd``)."""
+    return _rows(x, order // k)
+
+
+def _sort_rows_fwd(x, order, pos, k):
+    return _sort_rows(x, order, pos, k), pos
+
+
+def _sort_rows_bwd(k, pos, dxs):
+    """``dx[n] = sum_j dxs[pos[n k + j]]``, summed in float32."""
+    with jax.named_scope(ROUTE_SCOPE + "_bwd"):
+        pairs = _rows(dxs, pos).reshape(-1, k, dxs.shape[-1])
+        return pairs.astype(jnp.float32).sum(1).astype(dxs.dtype), None, None
+
+
+_sort_rows.defvjp(_sort_rows_fwd, _sort_rows_bwd)
+
+
+@jax.custom_vjp
+def _unsort(y, weights, order, pos):
+    """Each token's pairs' rows of ``y`` [N k, d] (sorted by expert),
+    weighted and summed: ``out[n] = sum_j weights[n, j] y[pos[n k + j]]``.
+    The backward moves rows by gathers too (``_unsort_bwd``)."""
+    return _unsort_fwd(y, weights, order, pos)[0]
+
+
+def _unsort_fwd(y, weights, order, pos):
+    N, k = weights.shape
+    mine = _rows(y, pos).reshape(N, k, -1)
+    return jnp.einsum("nkd,nk->nd", mine, weights), (mine, weights, order)
+
+
+def _unsort_bwd(res, dout):
+    """``dy[r] = weights[order[r]] dout[order[r] // k]``; ``dweights[n, j]
+    = <dout[n], y[pos[n k + j]]>``."""
+    mine, weights, order = res
+    k = weights.shape[1]
+    with jax.named_scope(COMBINE_SCOPE + "_bwd"):
+        dy = _rows(dout, order // k) * _rows(weights.reshape(-1),
+                                             order)[:, None]
+        return dy, jnp.einsum("nkd,nd->nk", mine, dout), None, None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def _combine(y, order, pos, weights, dtype):
     """Un-sort the pairs' results and sum each token's, weighted."""
     with jax.named_scope(COMBINE_SCOPE):
-        back = jnp.argsort(order)  # where pair p went
-        y = jnp.take(y, back, axis=0).reshape(N, k, -1)
-        return jnp.einsum("nkd,nk->nd", y, weights.astype(dtype))
+        return _unsort(y, weights.astype(dtype), order, pos)
 
 
 def shared_expert(x, w_gate, w_up, w_down, act: str = "silu"):

@@ -24,6 +24,7 @@ from agilerl_tpu.llm import model as M
 from agilerl_tpu.llm.presets import preset
 from agilerl_tpu.llm.serving import ContinuousGenerator
 from perfbench.reference import zaya_f32 as ref
+from tests.test_llm.test_moe_dispatch import argsort_experts, scatter_load
 
 G = importlib.import_module("agilerl_tpu.llm.generate")
 
@@ -509,6 +510,39 @@ def test_configurations_the_layers_do_not_compute_refuse():
 # The stacks that existed lower to the programs they lowered to
 # --------------------------------------------------------------------------- #
 
+#: sha256 (first 16 digits) of the programs pinned below that hold a
+#: dropless expert layer, at the dispatch that builds its permutation once a
+#: forward and moves rows by gathers both ways: with the dispatch
+#: and the load as they were before it (``test_moe_dispatch``'s
+#: ``argsort_experts`` and ``scatter_load``) patched in, each lowers to the
+#: hash it is pinned to below still — nothing else of these programs changed.
+DISPATCH_BY_GATHERS = {
+    "mla/decode": "f71b63d78f509d6d", "mla/learn": "cde659fae91e6013",
+    "mla/prefill": "11f55b5fb0a1e421", "cca/decode": "476fcfb11e307ddd",
+    "cca/learn": "4bd587d5eb23a837", "cca/learn_flash": "6daeefd56f63c3ce",
+    "cca/prefill": "8750e4e9f4e4c578", "mla/learn_flash": "1b10cb4910ed517c",
+    "experts/held": "fc68da1616e4ca42", "experts/relu": "5281d45e85993ae7",
+    "experts/shared": "a227ccf50a84cc58", "experts/silu": "1ec4ac99cc3338e5",
+    "swa/decode": "6fc5b7f567ff15d5", "swa/learn": "580ad465e857d934",
+    "swa/learn_grad": "0bced13c61e874ac", "swa/prefill": "1decc74f377f2777",
+}
+
+
+def assert_pinned(program, text, pinned, monkeypatch):
+    """``text()`` hashes to ``pinned``; for a program with a dropless expert
+    layer, to ``DISPATCH_BY_GATHERS[program]``, and to ``pinned`` with the old
+    dispatch patched in."""
+    def digest():
+        return hashlib.sha256(text().encode()).hexdigest()[:16]
+
+    if program in DISPATCH_BY_GATHERS:
+        assert digest() == DISPATCH_BY_GATHERS[program]
+        monkeypatch.setattr(moe, "dropless_experts", argsort_experts)
+        monkeypatch.setattr(moe, "expert_load", scatter_load)
+        jax.clear_caches()  # a traced checkpoint would keep the new dispatch
+    assert digest() == pinned
+
+
 #: sha256 (first 16 digits) of the lowered text of each program at the commit
 #: before CCA (13b37f2), on this installation: ``_run_layers`` carries a tree
 #: and ``route`` takes its logits from outside now, and neither may change a
@@ -571,11 +605,11 @@ def lowered(cfg, what):
 
 
 @pytest.mark.parametrize("program", sorted(LOWERED_BEFORE))
-def test_stacks_without_cca_lower_to_the_text_they_lowered_to(program):
+def test_stacks_without_cca_lower_to_the_text_they_lowered_to(program,
+                                                              monkeypatch):
     stack, what = program.split("/")
-    text = lowered(STACKS[stack](), what).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == LOWERED_BEFORE[program]
+    assert_pinned(program, lambda: lowered(STACKS[stack](), what).as_text(),
+                  LOWERED_BEFORE[program], monkeypatch)
 
 
 # --------------------------------------------------------------------------- #
@@ -669,16 +703,17 @@ def flash_plans_text():
 
 
 @pytest.mark.parametrize("program", sorted(LOWERED_AT_PR37))
-def test_programs_without_a_window_lower_to_the_text_they_lowered_to(program):
-    if program == "flash_plan":
-        text = flash_plans_text()
-    elif program == "mesh/learn_flash":
-        text = mesh_learn_flash_text()
-    else:
+def test_programs_without_a_window_lower_to_the_text_they_lowered_to(
+        program, monkeypatch):
+    def text():
+        if program == "flash_plan":
+            return flash_plans_text()
+        if program == "mesh/learn_flash":
+            return mesh_learn_flash_text()
         stack, what = program.split("/")
-        text = lowered_more(STACKS[stack](), what).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == LOWERED_AT_PR37[program]
+        return lowered_more(STACKS[stack](), what).as_text()
+
+    assert_pinned(program, text, LOWERED_AT_PR37[program], monkeypatch)
 
 
 # --------------------------------------------------------------------------- #
@@ -795,7 +830,6 @@ def lowered_at_pr39(program):
 
 @pytest.mark.parametrize("program", sorted(LOWERED_AT_PR39))
 def test_programs_a_mamba2_stack_shares_lower_to_the_text_they_lowered_to(
-        program):
-    text = lowered_at_pr39(program)
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == LOWERED_AT_PR39[program]
+        program, monkeypatch):
+    assert_pinned(program, lambda: lowered_at_pr39(program),
+                  LOWERED_AT_PR39[program], monkeypatch)

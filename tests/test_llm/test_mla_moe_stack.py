@@ -77,57 +77,6 @@ def test_renormalisation_and_scale(score):
         normed, 2.5 * plain / plain.sum(-1, keepdims=True), rtol=1e-5)
 
 
-def per_token_loop(x, choice, weights, w_gate, w_up, w_down):
-    """sum_k w_k E_choice_k(x), a token and a choice at a time."""
-    out = []
-    for n in range(x.shape[0]):
-        acc = 0.0
-        for k in range(choice.shape[1]):
-            e = int(choice[n, k])
-            y = (jax.nn.silu(x[n] @ w_gate[e]) * (x[n] @ w_up[e])) @ w_down[e]
-            acc = acc + weights[n, k] * y
-        out.append(acc)
-    return jnp.stack(out)
-
-
-def expert_weights(E=8, d=16, f=12, seed=4):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    return (0.3 * jax.random.normal(ks[0], (E, d, f)),
-            0.3 * jax.random.normal(ks[1], (E, d, f)),
-            0.3 * jax.random.normal(ks[2], (E, f, d)))
-
-
-@pytest.mark.parametrize("routing", ["spread", "all_to_one"])
-def test_every_token_is_served_whatever_the_imbalance(routing):
-    """``all_to_one``: every token's first choice is expert 3, eight times
-    a capacity bucket's worth; none is dropped."""
-    x = jax.random.normal(jax.random.PRNGKey(5), (40, 16))
-    wg, wu, wd = expert_weights()
-    choice = jax.random.randint(jax.random.PRNGKey(6), (40, 2), 0, 8)
-    if routing == "all_to_one":
-        choice = choice.at[:, 0].set(3).at[:, 1].set(6)
-    weights = jax.random.uniform(jax.random.PRNGKey(7), (40, 2)) + 0.5
-    got = moe.dropless_experts(x, choice, weights, wg, wu, wd)
-    want = per_token_loop(x, choice, weights, wg, wu, wd)
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
-    assert int(moe.expert_load(choice, 8).sum()) == 80
-    assert float(jnp.abs(got).sum(-1).min()) > 0  # no token passed through
-
-
-def test_gradients_wrt_activations_equal_the_per_token_loops():
-    x = jax.random.normal(jax.random.PRNGKey(8), (24, 16))
-    wg, wu, wd = expert_weights(seed=9)
-    choice = jax.random.randint(jax.random.PRNGKey(10), (24, 2), 0, 8)
-    weights = jax.random.uniform(jax.random.PRNGKey(11), (24, 2)) + 0.5
-    probe = jax.random.normal(jax.random.PRNGKey(12), (24, 16))
-    loss = lambda f: lambda x_, w_: jnp.sum(  # noqa: E731
-        f(x_, choice, w_, wg, wu, wd) * probe)
-    got = jax.grad(loss(moe.dropless_experts), argnums=(0, 1))(x, weights)
-    want = jax.grad(loss(per_token_loop), argnums=(0, 1))(x, weights)
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
-
-
 def test_the_learn_step_builds_no_weight_gradient_of_the_experts(params):
     """The base is frozen: every grouped matmul of the update, forward and
     backward, keeps the experts as its GROUPED operand (a weight gradient's

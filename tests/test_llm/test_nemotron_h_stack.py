@@ -22,6 +22,7 @@ from agilerl_tpu.llm import moe, nemotron_h, ssm2
 from agilerl_tpu.llm.serving import ContinuousGenerator
 from agilerl_tpu.ops.ssd import ssd, ssd_reference
 from perfbench.reference import nemotron_h_f32 as ref
+from tests.test_llm.test_moe_dispatch import argsort_experts
 
 G = importlib.import_module("agilerl_tpu.llm.generate")
 
@@ -135,32 +136,6 @@ def test_the_mixers_three_shapes_agree(params):
 # --------------------------------------------------------------------------- #
 
 
-@pytest.mark.parametrize("act,gated", [("relu2", False), ("silu", True),
-                                       ("relu", True)])
-def test_experts_against_a_dense_loop(act, gated):
-    N, k, E, d, f = 13, 3, 6, 8, 12
-    ks = jax.random.split(jax.random.PRNGKey(1), 6)
-    x = jax.random.normal(ks[0], (N, d))
-    choice = jax.random.randint(ks[1], (N, k), 0, E)
-    weights = jax.random.uniform(ks[2], (N, k))
-    w_gate = jax.random.normal(ks[3], (E, d, f)) if gated else None
-    w_up = jax.random.normal(ks[4], (E, d, f))
-    w_down = jax.random.normal(ks[5], (E, f, d))
-    got = moe.dropless_experts(x, choice, weights, w_gate, w_up, w_down,
-                               act=act)
-    fn = moe.EXPERT_ACTS[act]
-    want = np.zeros((N, d), np.float32)
-    for n in range(N):
-        for j in range(k):
-            e = int(choice[n, j])
-            hid = (fn(x[n] @ w_gate[e]) * (x[n] @ w_up[e]) if gated
-                   else fn(x[n] @ w_up[e]))
-            want[n] += float(weights[n, j]) * np.asarray(hid @ w_down[e])
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
-    assert act != "relu2" or np.allclose(fn(jnp.asarray([-2.0, 3.0])),
-                                         [0.0, 9.0])
-
-
 def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(params):
     """4 shares of 4 experts: their outputs, with the shared expert counted
     once, add up to the layer that holds all 16; each share's draw is the
@@ -251,6 +226,25 @@ def test_rows_after_the_last_group_are_never_read(params, monkeypatch):
     got_grad = jax.grad(loss)(x)
     assert np.isfinite(np.asarray(got_grad)).all()
     np.testing.assert_array_equal(got_grad, want_grad)
+    # the dispatch's own backward gathers each token's cotangent from the
+    # sorted rows by the inverse permutation: the rows after the groups are
+    # NaN in the stand-in's lhs cotangent, and no held pair reads them.
+    # Against the argsort formulation (its scatter-add transposes) with the
+    # real grouped matmul
+    lat = x @ blk["lat_down"]
+    choice, weights = moe.route(
+        moe.linear_logits(x, blk["router"]), blk["router_bias"], top_k=4,
+        score="sigmoid", norm_topk=True, scale=5.0)
+    args = (None, blk["w_up"], blk["w_down"])
+    probe = r[:, :lat.shape[1]]
+    grads = lambda f: jax.grad(  # noqa: E731
+        lambda a, w: (f(a, choice, w, *args, act="relu2", first=4)
+                      * probe).sum(), argnums=(0, 1))(lat, weights)
+    got_grads = grads(moe.dropless_experts)
+    monkeypatch.setattr(jax.lax, "ragged_dot", real)
+    for g, w in zip(got_grads, grads(argsort_experts)):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
 
 
 # --------------------------------------------------------------------------- #

@@ -23,11 +23,13 @@ from jax.experimental.compilation_cache import compilation_cache
 
 from agilerl_tpu import observability
 from agilerl_tpu.llm import model as M
+from agilerl_tpu.llm import moe
 from agilerl_tpu.llm.presets import preset
 from agilerl_tpu.llm.serving import ContinuousGenerator
 from agilerl_tpu.parallel import population
 from tests.test_llm.test_cca_moe_stack import (DECODE_WITHOUT_SCOPES, STACKS,
                                                lowered)
+from tests.test_llm.test_moe_dispatch import argsort_experts, scatter_load
 from tests.test_parallel.test_population import make_evo
 
 # the package exports a function of the same name
@@ -273,8 +275,12 @@ def test_without_the_helper_the_paged_forward_lowers_to_its_old_text(
         program, monkeypatch):
     """The attributes are ALL that PR 36 changed in ``forward_paged``: with
     the helper a no-op the three stacks' paged forwards hash to what they
-    hashed to at 13b37f2."""
+    hashed to at 13b37f2 (the latent stack's with the dropless dispatch as
+    it was then, too: it has changed since, ``test_cca_moe_stack``'s
+    ``DISPATCH_BY_GATHERS``)."""
     no_scopes(monkeypatch)
+    monkeypatch.setattr(moe, "dropless_experts", argsort_experts)
+    monkeypatch.setattr(moe, "expert_load", scatter_load)
     text = lowered(STACKS[program.split("/")[0]](), "decode").as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == DECODE_WITHOUT_SCOPES[program]
