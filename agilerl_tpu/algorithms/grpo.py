@@ -126,7 +126,7 @@ def make_update_fn(config, tx, lora_scale: float, use_flash: bool,
     if use_fused_loss is None:
         use_fused_loss = use_flash
     # a dropless expert stack's update has a fifth result (a pattern stack's
-    # a sixth too): _update_aux. What the gauge moe/load_max_over_mean and
+    # a sixth too): M.load_stats. What the gauge moe/load_max_over_mean and
     # the counter moe/held_pairs show; no other stack's program has them
     extra = ({"return_aux": True} if config.is_dropless else {})
 
@@ -140,7 +140,8 @@ def make_update_fn(config, tx, lora_scale: float, use_flash: bool,
             )
             lp, *aux = out if extra else (out,)
             loss, kl = _grpo_loss_core(lp, batch, clip, beta)
-            return loss, (kl, *_update_aux(config, aux))
+            stats = M.load_stats(config, aux[0]) if extra else ()
+            return loss, (kl, *stats)
 
         (loss, rest), grads = jax.value_and_grad(loss_fn, has_aux=True)(lora)
         updates, opt_state = tx.update(grads, opt_state, lora)
@@ -361,28 +362,7 @@ class GRPO(EvolvableAlgorithm):
         Pass None to detach (restores the pre-attach ``continuous_decode``
         setting — detaching must not leave the agent on a private bare
         generator it never used before)."""
-        if self.model_config.varies:
-            raise NotImplementedError(
-                "attach_rollout_fleet over a stack with sliding-window "
-                "layers: the fleet's prefill workers size their prompt grid "
-                "themselves and have not run a window by layer; not "
-                "implemented")
-        if self.model_config.is_mla:
-            raise NotImplementedError(
-                "attach_rollout_fleet over a latent cache: the fleet's "
-                "prefill-to-decode transfer carries K and V arrays, not the "
-                "one latent array; not implemented")
-        if self.model_config.state_kind in ("mamba", "mamba2"):
-            raise NotImplementedError(
-                "attach_rollout_fleet over a hybrid or Mamba-2 stack: the "
-                "fleet's prefill-to-decode transfer carries prompt KV only, "
-                "not the state-space layers' recurrent state; not implemented")
-        if self.model_config.is_cca:
-            raise NotImplementedError(
-                "attach_rollout_fleet over a CCA stack: the fleet's "
-                "prefill-to-decode transfer carries prompt KV only, not the "
-                "attention layers' rolling state (convolution windows and "
-                "the previous token's value half); not implemented")
+        M.refuse(self.model_config, "fleet", "prefill_transfer")
         if fleet is None:
             if self.rollout_fleet is not None:
                 self.continuous_decode = self._pre_fleet_continuous_decode
@@ -450,8 +430,6 @@ class GRPO(EvolvableAlgorithm):
                     gen.fits(ids_np.shape[0], longest):
                 seqs = [row[m.astype(bool)]
                         for row, m in zip(ids_np, mask_np)]
-                # (a method at the class's end: a kernel's serialized body
-                # holds the line of every frame above its call, learn's too)
                 comp, cmask, self.last_generation_info = \
                     self._continuous_rollout(gen, seqs, training)
                 return comp, cmask
@@ -644,31 +622,7 @@ class GRPO(EvolvableAlgorithm):
         """(logprobs, update) for the active parallelism mode, with the
         sequence-parallel input contract validated against THIS batch."""
         if self.sequence_parallel_axis is not None:
-            if self.model_config.varies:
-                raise NotImplementedError(
-                    "sequence_parallel_axis over a stack with sliding-window "
-                    "layers: ring attention has no window and no positions "
-                    "by layer; not implemented")
-            if self.model_config.is_cca:
-                raise NotImplementedError(
-                    "sequence_parallel_axis over a CCA stack: the "
-                    "long-context path splits the sequence across chips, "
-                    "and a shard's first positions would lose the rolling "
-                    "state (the previous shard's last convolution inputs "
-                    "and value half); not implemented")
-            if self.model_config.is_mla or self.model_config.is_dropless:
-                raise NotImplementedError(
-                    "sequence_parallel_axis over latent attention or a "
-                    "dropless expert stack: the long-context path runs ring "
-                    "attention over GQA keys and values and shards tokens "
-                    "without an expert exchange; not implemented")
-            if self.model_config.is_hybrid:
-                raise NotImplementedError(
-                    "sequence_parallel_axis over a hybrid stack: the "
-                    "long-context path splits the sequence across chips for "
-                    "ring attention, and a state-space layer's recurrence "
-                    "would have to hand its state from shard to shard; not "
-                    "implemented")
+            M.refuse(self.model_config, "sequence_parallel")
             mesh, axis = self._require_sp_mesh()
             sp_size = mesh.shape[axis]
             if ids.shape[1] % sp_size:
@@ -875,45 +829,14 @@ class GRPO(EvolvableAlgorithm):
                 raise ValueError("to_mesh needs a mesh or a plan")
             plan = PL.grpo_plan_for_mesh(mesh)
         plan, mesh = PL.resolve_plan_and_mesh(plan, mesh)
-        if self.model_config.varies:
-            raise ValueError(
-                "to_mesh over a stack with sliding-window layers: the period "
-                "scan's weights (params['runs'][r] a list, one tree a "
-                "position in the period) have no plan rule and the windowed "
-                "kernels have not been run under shard_map; not implemented")
-        if self.model_config.is_cca:
+
+        def unplaced():
             try:
                 plan.shardings("params", self.base_params, mesh, strict=True)
             except PL.UnmatchedLeafError as err:
-                raise ValueError(
-                    "to_mesh over a CCA stack: the plan's 'params' rules do "
-                    "not cover its leaves (wv1, wv2, conv0_w, conv1_w, tau, "
-                    f"router_in, router_w1, merge1, ...): {err}") from err
-        if self.model_config.state_kind == "mamba2":
-            raise ValueError(
-                "to_mesh over a Mamba-2 stack: no plan rule places its "
-                "leaves (in_proj, conv_w, A_log, lat_down, the held experts) "
-                "nor its experts over an ep axis; not implemented")
-        if self.model_config.is_mla or self.model_config.is_dropless:
-            try:
-                plan.shardings("params", self.base_params, mesh, strict=True)
-            except PL.UnmatchedLeafError as err:
-                raise ValueError(
-                    "to_mesh over latent attention or a dropless expert "
-                    "stack: the plan's 'params' rules do not cover their "
-                    "leaves (wkv_a, wkv_b, kv_norm, router_bias, ws_gate, "
-                    f"ws_up, ws_down): {err}") from err
-        if self.model_config.is_hybrid:
-            # a plan written for attention stacks would silently replicate
-            # every state-space leaf: refuse unless it names them all
-            try:
-                plan.shardings("params", self.base_params, mesh, strict=True)
-            except PL.UnmatchedLeafError as err:
-                raise ValueError(
-                    "to_mesh over a hybrid stack: the plan's 'params' rules "
-                    "do not cover the state-space layers' leaves (in_proj, "
-                    f"conv_w, x_proj, dt_proj, A_log, out_proj, ...): {err}"
-                ) from err
+                return err
+
+        M.refuse(self.model_config, "mesh", "learn_mesh", unplaced=unplaced)
 
         # cached logprob/update closures capture the OLD base_params (and, for
         # sp fns, the old mesh) — drop them so learn() rebuilds against the
@@ -951,27 +874,8 @@ class GRPO(EvolvableAlgorithm):
                 lora=self.actor.params, greedy=not training)
 
 
-# --------------------------------------------------------------------------- #
-# Here, at the end of the file: a Mosaic kernel's body holds the file and
-# line of every Python frame above its pallas_call (the update's, the
-# no-grad pass's, the rollout's), so an edit that moves a line above one of
-# them changes the program text, and the compile-cache key, of every cell.
-# --------------------------------------------------------------------------- #
-
-
-def _update_aux(config, aux):
-    """What a dropless expert stack's update returns beside (loss, kl), from
-    the forward's aux summed over the layers: the fullest expert's rows over
-    the mean, averaged over the expert layers; a pattern stack's also the
-    routed pairs that fell on the experts the chip holds."""
-    out = [a[0] / config.n_moe_layers for a in aux]
-    if config.layer_pattern is not None:
-        out += [a[2] for a in aux]
-    return out
-
-
 def _note_load(metrics, load):
-    """``_update_aux``'s results into the registry: the gauge
+    """``M.load_stats``' results into the registry: the gauge
     ``moe/load_max_over_mean`` and, where there, the counter
     ``moe/held_pairs`` (docs/observability.md)."""
     metrics.gauge(

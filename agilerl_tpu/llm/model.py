@@ -1281,6 +1281,15 @@ def forward(
     return h, new_caches
 
 
+def load_stats(config: GPTConfig, aux: jax.Array) -> Tuple[jax.Array, ...]:
+    """A dropless expert stack's load statistics from ``forward``'s summed
+    aux: the fullest expert's rows over the mean, averaged over the expert
+    layers, and a pattern stack's routed pairs that fell on the experts the
+    chip holds."""
+    load = aux[0] / config.n_moe_layers
+    return (load, aux[2]) if config.layer_pattern is not None else (load,)
+
+
 def _shard_mesh(axes):
     """The active mesh for a kernel shard_map wrap, or None. Reads the
     `with mesh:` trace-time context (the pattern every sharded program in
@@ -1968,6 +1977,170 @@ def _check_pattern(config: GPTConfig) -> None:
         raise ValueError(
             "mamba_heads, moe_latent, experts_held and squared-ReLU experts "
             "are read by a pattern stack's layers alone (layer_pattern)")
+
+
+# --------------------------------------------------------------------------- #
+# What each kind of stack cannot do, stated once (docs/serving.md, "What
+# each stack refuses"). The serving tiers and GRPO call ``refuse`` where a
+# feature is asked for; a new kind of stack adds a row here.
+# --------------------------------------------------------------------------- #
+
+#: a stack kind -> (how a refusal names it, whether a config is of it), in
+#: the order kinds answer: a stack of two kinds (an expert stack: window,
+#: CCA, Mamba-2 or latent attention with the dropless dispatch) refuses by
+#: the first
+STACK_KINDS = {
+    "window": ("a stack with sliding-window layers", lambda c: c.varies),
+    "cca": ("a CCA stack", lambda c: c.is_cca),
+    "mamba2": ("a Mamba-2 stack", lambda c: c.state_kind == "mamba2"),
+    "latent": ("a latent cache", lambda c: c.is_mla),
+    "dropless": ("a dropless expert stack", lambda c: c.is_dropless),
+    "hybrid": ("a hybrid stack", lambda c: c.is_hybrid),
+}
+
+#: a feature -> (the exception its refusal raises, what the refusal names)
+FEATURES = {
+    # ContinuousGenerator(speculate=...): the multi-token verify step
+    "speculate": (ValueError, "speculative decoding"),
+    # any plan or mesh: BucketedGenerator, ContinuousGenerator, GRPO.to_mesh
+    "mesh": (ValueError, "a sharding plan or mesh"),
+    # ContinuousGenerator(sharding_plan= / mesh=): the paged pool and caches
+    "serving_plan": (ValueError, "a serving plan"),
+    # GRPO.to_mesh: the learn state's placement
+    "learn_mesh": (ValueError, "to_mesh"),
+    # GRPO.attach_rollout_fleet: the fleet's prefill workers
+    "fleet": (NotImplementedError, "attach_rollout_fleet"),
+    # GRPO.attach_rollout_fleet and ContinuousGenerator.submit_prefilled
+    "prefill_transfer": (NotImplementedError,
+                         "the fleet's prefill-to-decode transfer "
+                         "(attach_rollout_fleet, submit_prefilled)"),
+    # GRPO(sequence_parallel_axis=...): ring attention over sequence shards
+    "sequence_parallel": (NotImplementedError, "sequence_parallel_axis"),
+}
+
+
+class Unplaced(NamedTuple):
+    """A ``learn_mesh`` entry that refuses only where the plan's 'params'
+    rules leave one of the stack's own leaves unplaced: a plan written for
+    attention stacks would replicate them silently, one that names them
+    all places the stack."""
+    leaves: str
+
+
+#: a stack kind -> {feature: why it refuses}; a feature a kind does not
+#: list, it does
+CANNOT = {
+    "window": {
+        "speculate": "the multi-token verify step (speculate."
+                     "paged_verify_step) is not carried over to a window by "
+                     "layer",
+        "mesh": "the period scan's weights (params['runs'][r] a list, one "
+                "tree a position in the period) have no plan rule, and the "
+                "windowed kernels have not been run on a mesh",
+        "fleet": "the fleet's prefill workers size their prompt grid "
+                 "themselves and have not run a window by layer",
+        "sequence_parallel": "ring attention has no window and no "
+                             "positions by layer",
+    },
+    "cca": {
+        "speculate": "the verify step advances the attention layers' "
+                     "rolling state (convolution windows and the previous "
+                     "token's value half) past rejected drafts and nothing "
+                     "rolls it back",
+        "serving_plan": "no rule places the rolling-state cache (per-slot "
+                        "convolution windows and value half beside the "
+                        "paged pool) nor the leaves conv0_*, conv1_*, tau, "
+                        "wv1, wv2, router_*, merge*",
+        "learn_mesh": Unplaced("its leaves (wv1, wv2, conv0_w, conv1_w, "
+                               "tau, router_in, router_w1, merge1, ...)"),
+        "prefill_transfer": "the export carries prompt KV only, not the "
+                            "attention layers' rolling state (convolution "
+                            "windows and value half, nor its snapshot)",
+        "sequence_parallel": "a shard's first positions would lose the "
+                             "rolling state (the previous shard's last "
+                             "convolution inputs and value half)",
+    },
+    "mamba2": {
+        "speculate": "the verify step advances the per-slot SSM and "
+                     "convolution state past rejected drafts and nothing "
+                     "rolls it back",
+        "serving_plan": "no rule places the state cache (per-slot SSM and "
+                        "convolution state beside the paged pool) nor the "
+                        "leaves in_proj, conv_w, A_log, lat_down, ...",
+        "learn_mesh": "no plan rule places its leaves (in_proj, conv_w, "
+                      "A_log, lat_down, the held experts) nor its experts "
+                      "over an ep axis",
+        "prefill_transfer": "the export carries prompt KV only, not the "
+                            "Mamba-2 layers' SSM and convolution state (nor "
+                            "its snapshot)",
+    },
+    "latent": {
+        "speculate": "the multi-token verify step (speculate."
+                     "paged_verify_step, model.paged_scatter_multi) is not "
+                     "carried over to the one-array pool",
+        "serving_plan": "no rule places a latent cache's pool (one array, "
+                        "one head) nor the leaves wkv_a, wkv_b, kv_norm",
+        "learn_mesh": Unplaced("the latent attention leaves (wkv_a, wkv_b, "
+                               "kv_norm)"),
+        "prefill_transfer": "the export and the import (model."
+                            "paged_scatter_prompt through _admit_import) "
+                            "carry K and V arrays, not the one latent array",
+        "sequence_parallel": "ring attention runs over GQA keys and values",
+    },
+    "dropless": {
+        "speculate": "the multi-token verify step (speculate."
+                     "paged_verify_step, model.paged_scatter_multi) is not "
+                     "carried over to the decode chunk's expert counts",
+        "serving_plan": "no rule places the expert leaves (router_bias, "
+                        "ws_*)",
+        "learn_mesh": Unplaced("the expert leaves (router_bias, ws_gate, "
+                               "ws_up, ws_down)"),
+        "sequence_parallel": "the long-context path shards tokens without "
+                             "an expert exchange",
+    },
+    "hybrid": {
+        "speculate": "the verify step advances the recurrent state past "
+                     "rejected drafts: it needs recurrent-state rollback",
+        "serving_plan": "no rule places the recurrent-state cache (per-slot "
+                        "conv and SSM state beside the paged pool)",
+        "learn_mesh": Unplaced("the state-space layers' leaves (in_proj, "
+                               "conv_w, x_proj, dt_proj, A_log, out_proj, "
+                               "...)"),
+        "prefill_transfer": "the export carries prompt KV only, not the "
+                            "state-space layers' recurrent state (nor its "
+                            "snapshot)",
+        "sequence_parallel": "a state-space layer's recurrence would have "
+                             "to hand its state from shard to shard",
+    },
+}
+
+
+def stack_kinds(config: GPTConfig) -> Tuple[str, ...]:
+    """The kinds of ``STACK_KINDS`` that ``config`` is, in their order."""
+    return tuple(k for k, (_, of) in STACK_KINDS.items() if of(config))
+
+
+def refuse(config: GPTConfig, *features: str, unplaced=None) -> None:
+    """Raise for the first of ``config``'s kinds that cannot do one of
+    ``features`` (``CANNOT``), that feature's exception naming the kind and
+    why; return where none refuses. ``unplaced()`` -> the error of the
+    plan's strict resolution of the base, or None: what an ``Unplaced``
+    entry refuses on."""
+    for kind in stack_kinds(config):
+        name = STACK_KINDS[kind][0]
+        for feature in features:
+            why = CANNOT[kind].get(feature)
+            if why is None:
+                continue
+            error, what = FEATURES[feature]
+            if isinstance(why, Unplaced):
+                err = unplaced()
+                if err is not None:
+                    raise error(
+                        f"{what} over {name}: the plan's 'params' rules do "
+                        f"not cover {why.leaves}: {err}") from err
+                continue
+            raise error(f"{what} over {name}: {why}; not implemented")
 
 
 # --------------------------------------------------------------------------- #

@@ -99,25 +99,6 @@ def _device_bytes_free() -> Optional[int]:
     return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
 
 
-def _refuse_window(config: M.GPTConfig, speculate, sharding_plan, mesh):
-    """What the serving tiers do not do over a stack that mixes window and
-    full attention (or states positions by layer), by mechanism."""
-    if not config.varies:
-        return
-    if as_spec_config(speculate) is not None:
-        raise ValueError(
-            "speculative decoding over a stack with sliding-window layers: "
-            "the multi-token verify step (speculate.paged_verify_step) is "
-            "not carried over to a window by layer; not implemented: build "
-            "the generator with speculate=None")
-    if sharding_plan is not None or mesh is not None:
-        raise ValueError(
-            "a serving plan over a stack with sliding-window layers: the "
-            "period scan's weights (one tree a position in the period) have "
-            "no rule, and the window has not been run on a mesh; not "
-            "implemented on a mesh")
-
-
 def _sampling_knobs(gen, greedy: bool, lora) -> Dict[str, Any]:
     """The per-call knob dict both serving generators hand to the shared
     prefill/decode building blocks — ONE home so the two tiers cannot
@@ -263,7 +244,8 @@ class BucketedGenerator:
         self._pending_lock = threading.Lock()
         self.pad_id = int(pad_id)
         self.eos_id = eos_id
-        _refuse_window(config, None, sharding_plan, mesh)
+        if sharding_plan is not None or mesh is not None:
+            M.refuse(config, "mesh")
         # None: the grid follows from the model (default_prompt_buckets)
         self.prompt_buckets = tuple(sorted(
             default_prompt_buckets(config) if prompt_buckets is None
@@ -856,38 +838,10 @@ class ContinuousGenerator:
     ):
         self.config = config
         self.metrics = metrics if metrics is not None else observability.get_registry()
-        _refuse_window(config, speculate, sharding_plan, mesh)
-        if config.is_cca:
-            if as_spec_config(speculate) is not None:
-                raise ValueError(
-                    "speculative decoding over a CCA stack would lose the "
-                    "attention layers' rolling state (convolution windows "
-                    "and the previous token's value half): the verify step "
-                    "advances it past rejected drafts and nothing rolls it "
-                    "back; not implemented: build the generator with "
-                    "speculate=None")
-            if sharding_plan is not None or mesh is not None:
-                raise ValueError(
-                    "a serving plan has no rule for a CCA stack's rolling-"
-                    "state cache (per-slot convolution windows and value "
-                    "half beside the paged pool) nor for its leaves "
-                    "(conv0_*, conv1_*, tau, wv1, wv2, router_*, merge*); "
-                    "not implemented on a mesh")
-        if config.state_kind == "mamba2":
-            # a pattern stack's Mamba-2 layers: refused by name before the
-            # dropless branch below would refuse it by its experts
-            if as_spec_config(speculate) is not None:
-                raise ValueError(
-                    "speculative decoding over a Mamba-2 stack needs its "
-                    "per-slot SSM and convolution state rolled back for "
-                    "rejected drafts (the verify step advances it past them); "
-                    "not implemented: build the generator with speculate=None")
-            if sharding_plan is not None or mesh is not None:
-                raise ValueError(
-                    "a serving plan has no rule for a Mamba-2 stack's state "
-                    "cache (per-slot SSM and convolution state beside the "
-                    "paged pool) nor for its leaves (in_proj, conv_w, A_log, "
-                    "lat_down, ...); not implemented on a mesh")
+        if as_spec_config(speculate) is not None:
+            M.refuse(config, "speculate")
+        if sharding_plan is not None or mesh is not None:
+            M.refuse(config, "mesh", "serving_plan")
         if config.experts_held is not None:
             # a chip's share of the experts: what it holds of what it routes
             self.metrics.gauge(
@@ -896,36 +850,6 @@ class ContinuousGenerator:
             self.metrics.gauge(
                 "moe/experts_routed", help="experts of an expert layer its "
                 "router chooses among").set(config.n_experts)
-        if config.is_mla or config.is_dropless:
-            # what this tier does not do over a latent cache or a dropless
-            # expert stack refuses here, by mechanism
-            if as_spec_config(speculate) is not None:
-                raise ValueError(
-                    "speculative decoding over a latent cache or a dropless "
-                    "expert stack: the multi-token verify step "
-                    "(speculate.paged_verify_step, model.paged_scatter_multi) "
-                    "is not carried over to them; not implemented: build "
-                    "the generator with speculate=None")
-            if sharding_plan is not None or mesh is not None:
-                raise ValueError(
-                    "a serving plan has no rule for a latent cache's pool "
-                    "(one array, one head) nor for the leaves of a latent-"
-                    "attention or dropless expert layer (wkv_a, wkv_b, "
-                    "kv_norm, router_bias, ws_*); not implemented on a mesh")
-        if config.is_hybrid:
-            # what this tier does not do for a stack with state-space
-            # layers refuses here, by mechanism, before any program exists
-            if as_spec_config(speculate) is not None:
-                raise ValueError(
-                    "speculative decoding over a hybrid stack needs "
-                    "recurrent-state rollback for rejected drafts (the "
-                    "verify step advances the state past them); not "
-                    "implemented: build the generator with speculate=None")
-            if sharding_plan is not None or mesh is not None:
-                raise ValueError(
-                    "a serving plan has no rule for a hybrid stack's "
-                    "recurrent-state cache (per-slot conv and SSM state "
-                    "beside the paged pool); not implemented on a mesh")
         self._tracer = tracer
         #: layers whose attention has a sliding window (0: no such stack)
         self._window_layers = config.n_window_layers
@@ -1363,28 +1287,7 @@ class ContinuousGenerator:
         TTFT includes prefill + transfer latency. Decode-side admission
         control (free-block watermark, queue, TTFT SLO) applies unless
         ``no_shed``."""
-        if self.config.is_mla:
-            raise NotImplementedError(
-                "submit_prefilled over a latent cache: the prefill worker's "
-                "export and this tier's import (model.paged_scatter_prompt "
-                "through _admit_import) carry K and V arrays, not the one "
-                "latent array; not implemented")
-        if self.config.state_kind == "mamba2":
-            raise NotImplementedError(
-                "submit_prefilled over a Mamba-2 stack: the prefill worker's "
-                "export carries prompt KV only, not the Mamba-2 layers' SSM "
-                "and convolution state (nor its snapshot); not implemented")
-        if self.config.is_hybrid:
-            raise NotImplementedError(
-                "submit_prefilled over a hybrid stack: the prefill worker's "
-                "export carries prompt KV only, not the recurrent state of "
-                "the state-space layers (nor its snapshot); not implemented")
-        if self.config.is_cca:
-            raise NotImplementedError(
-                "submit_prefilled over a CCA stack: the prefill worker's "
-                "export carries prompt KV only, not the attention layers' "
-                "rolling state (convolution windows and value half, nor "
-                "its snapshot); not implemented")
+        M.refuse(self.config, "prefill_transfer")
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         if key is None:
             # the raw request key is load-bearing: a prefix-cache HIT on an

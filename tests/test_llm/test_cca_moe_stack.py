@@ -454,12 +454,6 @@ def test_the_adapter_gradient_matches_the_reference_with_it_merged(params):
 
 
 def test_what_would_lose_the_rolling_state_refuses_by_name(params):
-    with pytest.raises(ValueError, match="rolling state"):
-        generator(speculate=True)
-    with pytest.raises(NotImplementedError, match="rolling state"):
-        generator().submit_prefilled(
-            np.arange(3, 9, dtype=np.int32), k_prompt=None, v_prompt=None,
-            tok0=0, done0=False, key_next=None)
     pool = M.init_paged_cache(CFG, 5, 8, slots=2, snapshots=2)
     with pytest.raises(NotImplementedError, match="rolling state"):
         M.forward_paged(CFG, params, jnp.ones((2, 3), jnp.int32),
@@ -469,20 +463,6 @@ def test_what_would_lose_the_rolling_state_refuses_by_name(params):
                         jnp.ones((2, 32), jnp.int32))
     with pytest.raises(ValueError, match="per slot"):
         M.init_paged_cache(CFG, 5, 8)
-    agent = make_agent(params, sequence_parallel_axis="sp")
-    ids = jnp.ones((2, 8), jnp.int32)
-    with pytest.raises(NotImplementedError, match="rolling"):
-        agent._resolve_learn_fns(ids, jnp.ones((2, 8), jnp.int32))
-    with pytest.raises(NotImplementedError, match="rolling state"):
-        agent.attach_rollout_fleet(object())
-    from jax.sharding import Mesh
-
-    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2, 1),
-                ("dp", "fsdp", "tp"))
-    with pytest.raises(ValueError, match="conv0_w"):
-        make_agent(params).to_mesh(mesh)
-    with pytest.raises(ValueError, match="rolling-state cache"):
-        generator(mesh=mesh)
 
 
 def test_configurations_the_layers_do_not_compute_refuse():

@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 
 from agilerl_tpu import observability
-from agilerl_tpu.algorithms.grpo import GRPO
 from agilerl_tpu.llm import model as M
 from agilerl_tpu.llm.serving import ContinuousGenerator
 from perfbench.reference import jamba_f32 as ref
@@ -260,13 +259,6 @@ def test_the_learn_step_holds_one_mamba_body_a_run_not_one_a_layer(params):
 
 
 def test_what_is_not_hybrid_aware_refuses_by_name(params):
-    with pytest.raises(ValueError, match="recurrent-state rollback"):
-        generator(speculate=True)
-    gen = generator()
-    with pytest.raises(NotImplementedError, match="submit_prefilled"):
-        gen.submit_prefilled(
-            np.arange(3, 9, dtype=np.int32), k_prompt=None, v_prompt=None,
-            tok0=0, done0=False, key_next=None)
     with pytest.raises(ValueError, match="LoRA targets"):
         M.init_lora(jax.random.PRNGKey(0), CFG, 2, ("w_q",))
     pool = M.init_paged_cache(CFG, 4, 8, slots=2, snapshots=1)
@@ -277,19 +269,3 @@ def test_what_is_not_hybrid_aware_refuses_by_name(params):
             jnp.zeros((2, 2), jnp.int32), jnp.ones((2, 16), jnp.int32))
     with pytest.raises(ValueError, match="pass slots"):
         M.init_paged_cache(CFG, 4, 8)
-
-    agent = GRPO(config=CFG, base_params=params, pad_token_id=0,
-                 eos_token_id=1, group_size=2, batch_size=2,
-                 max_output_tokens=4, lora_rank=2,
-                 lora_targets=("wq", "in_proj"), sequence_parallel_axis="sp")
-    ids = jnp.ones((2, 8), jnp.int32)
-    with pytest.raises(NotImplementedError, match="sequence_parallel_axis"):
-        agent._resolve_learn_fns(ids, jnp.ones((2, 8), jnp.int32))
-    with pytest.raises(NotImplementedError, match="attach_rollout_fleet"):
-        agent.attach_rollout_fleet(object())
-    from jax.sharding import Mesh
-
-    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2, 1),
-                ("dp", "fsdp", "tp"))
-    with pytest.raises(ValueError, match="state-space layers' leaves"):
-        agent.to_mesh(mesh)

@@ -344,31 +344,9 @@ def test_grpo_trains_with_adapters_on_wq_and_wkv_b(params):
 
 
 def test_what_is_not_carried_over_refuses_by_name(params):
-    with pytest.raises(ValueError, match="paged_verify_step"):
-        generator(speculate=True)
-    gen = generator()
-    with pytest.raises(NotImplementedError, match="submit_prefilled"):
-        gen.submit_prefilled(
-            np.arange(3, 9, dtype=np.int32), k_prompt=None, v_prompt=None,
-            tok0=0, done0=False, key_next=None)
     with pytest.raises(ValueError, match="wkv_b"):
         M.init_lora(jax.random.PRNGKey(0), CFG, 2, ("wq", "wv"))
     with pytest.raises(ValueError, match="FFN projections"):
         M.init_lora(jax.random.PRNGKey(0), CFG, 2, ("wq", "w_up"))
     with pytest.raises(ValueError, match="latent attention"):
         dataclasses.replace(CFG, rope=False)
-
-    agent = make_agent(params, sequence_parallel_axis="sp")
-    ids = jnp.ones((2, 8), jnp.int32)
-    with pytest.raises(NotImplementedError, match="sequence_parallel_axis"):
-        agent._resolve_learn_fns(ids, jnp.ones((2, 8), jnp.int32))
-    with pytest.raises(NotImplementedError, match="attach_rollout_fleet"):
-        agent.attach_rollout_fleet(object())
-    from jax.sharding import Mesh
-
-    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2, 1),
-                ("dp", "fsdp", "tp"))
-    with pytest.raises(ValueError, match="wkv_a, wkv_b"):
-        agent.to_mesh(mesh)
-    with pytest.raises(ValueError, match="latent cache's pool"):
-        generator(mesh=mesh)

@@ -406,20 +406,6 @@ def test_the_adapted_log_probabilities_match_the_reference(params):
 
 
 def test_what_would_lose_the_mamba2_state_refuses_by_name(params):
-    with pytest.raises(ValueError, match="speculative decoding over a Mamba-2"):
-        generator(speculate=True)
-    with pytest.raises(NotImplementedError,
-                       match="submit_prefilled over a Mamba-2"):
-        generator().submit_prefilled(np.arange(3), k_prompt=None,
-                                     v_prompt=None, tok0=0, done0=False,
-                                     key_next=None)
-    agent = make_agent(params)
-    with pytest.raises(NotImplementedError, match="hybrid or Mamba-2"):
-        agent.attach_rollout_fleet(None)
-    from agilerl_tpu.parallel.mesh import make_mesh
-
-    with pytest.raises(ValueError, match="to_mesh over a Mamba-2 stack"):
-        agent.to_mesh(make_mesh(dp=1, fsdp=8, tp=1))
     with pytest.raises(NotImplementedError, match="Mamba-2"):
         M.forward_paged(CFG, params, jnp.zeros((3, 2), jnp.int32),
                         jnp.zeros((3, 2), jnp.int32),

@@ -530,27 +530,6 @@ def test_grpo_sizes_its_rollout_tier_from_a_long_context(params):
 # --------------------------------------------------------------------------- #
 
 
-def test_what_this_stack_cannot_do_refuses_by_name(params):
-    with pytest.raises(ValueError, match="sliding-window layers"):
-        generator(speculate=True)
-    from jax.sharding import Mesh
-
-    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2, 1),
-                ("dp", "fsdp", "tp"))
-    with pytest.raises(ValueError, match="sliding-window layers"):
-        generator(mesh=mesh)
-    with pytest.raises(ValueError, match="sliding-window layers"):
-        serving.BucketedGenerator(CFG, mesh=mesh)
-    with pytest.raises(ValueError, match="sliding-window layers"):
-        make_agent(params).to_mesh(mesh)
-    with pytest.raises(NotImplementedError, match="sliding-window layers"):
-        make_agent(params).attach_rollout_fleet(object())
-    agent = make_agent(params, sequence_parallel_axis="sp")
-    ids = jnp.ones((2, 8), jnp.int32)
-    with pytest.raises(NotImplementedError, match="sliding-window layers"):
-        agent._resolve_learn_fns(ids, jnp.ones((2, 8), jnp.int32))
-
-
 def test_configurations_the_layers_do_not_compute_refuse():
     for change in (dict(window_layout=(0, 1)), dict(rope_layout=[0] * 8),
                    dict(sliding_window=0)):
